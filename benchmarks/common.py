@@ -179,19 +179,20 @@ def run_method_grid(grid: list[dict], backend: str | None = None,
     scenarios sharing (trace x clique-gen hyperparameters) share one
     host schedule, and every group replays as one vmapped device scan
     (``REPRO_SWEEP_BACKEND=numpy`` restores the serial loop; it also
-    engages automatically when JAX is missing or a cost model has no JAX
-    formula).  OPT lower bounds are closed-form and stay host-side.
+    engages when a cost model has no JAX formula, and the backend that
+    ran is printed).  OPT lower bounds are closed-form and stay host-side.
     """
     from repro.core import SweepEngine, SweepPoint
-    from repro.core.engine_jax import HAS_JAX, JAX_COST_MODELS
+    from repro.core.engine_jax import JAX_COST_MODELS
 
     if backend is None:
-        backend = os.environ.get("REPRO_SWEEP_BACKEND", "")
-        backend = backend or ("jax" if HAS_JAX else "numpy")
+        backend = os.environ.get("REPRO_SWEEP_BACKEND", "") or "jax"
     if backend == "jax" and any(
             g.get("cost_model", "table1") not in JAX_COST_MODELS
             for g in grid):
         backend = "numpy"
+    print(f"# run_method_grid: {len(grid)} grid entries on the {backend} "
+          "backend", flush=True)
 
     pts, slots, resolved = [], [], []
     for gi, g in enumerate(grid):

@@ -36,7 +36,7 @@ from repro.core import (
     CacheEnvironment, CostParams, SweepEngine, SweepPoint, list_policies,
     run_policy,
 )
-from repro.core.engine_jax import HAS_JAX, JAX_COST_MODELS
+from repro.core.engine_jax import JAX_COST_MODELS
 from repro.learned import train_policy
 from repro.traces import SynthConfig, synth_trace
 
@@ -101,8 +101,8 @@ def run_grid(n_requests: int, eval_seeds=EVAL_SEEDS,
     assert set(policies) <= {  # every canonical registry policy is ranked
         name for name in list_policies()}, (policies, list_policies())
     params = CostParams(rho=4.0)
-    backend = ("jax" if HAS_JAX
-               and all(m in JAX_COST_MODELS for m in models) else "numpy")
+    backend = ("jax" if all(m in JAX_COST_MODELS for m in models)
+               else "numpy")
 
     pts, keys = [], []
     for cm in models:
@@ -182,20 +182,19 @@ def smoke() -> int:
         print(f"FAIL: trained policy beats none of {rivals}")
         return 1
 
-    if HAS_JAX:
-        from repro.core import get_policy
+    from repro.core import get_policy
 
-        tr = shards[0]
-        t_np = run_policy(
-            get_policy("learned", params=params, t_cg=tcg, learned=lp),
-            tr).costs.total
-        t_jx = run_policy(
-            get_policy("learned", params=params, t_cg=tcg, learned=lp),
-            tr, backend="jax").costs.total
-        print(f"fig11 --smoke: parity numpy={t_np:.9f} jax={t_jx:.9f}")
-        if abs(t_np - t_jx) > 1e-9:
-            print("FAIL: numpy/jax replay of the learned policy disagree")
-            return 1
+    tr = shards[0]
+    t_np = run_policy(
+        get_policy("learned", params=params, t_cg=tcg, learned=lp),
+        tr).costs.total
+    t_jx = run_policy(
+        get_policy("learned", params=params, t_cg=tcg, learned=lp),
+        tr, backend="jax").costs.total
+    print(f"fig11 --smoke: parity numpy={t_np:.9f} jax={t_jx:.9f}")
+    if abs(t_np - t_jx) > 1e-9:
+        print("FAIL: numpy/jax replay of the learned policy disagree")
+        return 1
     print("OK")
     return 0
 

@@ -74,64 +74,54 @@ def main() -> list[tuple]:
     return rows
 
 
-def smoke() -> None:
-    """CI gate: device-CGM partitions == ``cliques_ref`` oracle, chained."""
-    from repro.core import (
-        CacheEnvironment, SweepEngine, SweepPoint, get_policy,
-    )
-    from repro.core import cgm_jax
-    from repro.core import cliques as cliques_mod
+def oracle_partitions(tr, t_cg, theta, gamma, omega, top_frac):
+    """``cliques_ref`` slot maps at every T_CG boundary, walked exactly as
+    the replay engines walk the windows."""
     from repro.core import cliques_ref
     from repro.core.crm import build_window_crm
+
+    times, R = tr.times, tr.n_requests
+    next_cg = float(times[0]) + t_cg
+    win_start = pos = 0
+    prev = prev_crm = None
+    parts = []
+    while pos < R:
+        cut = int(np.searchsorted(times, next_cg, side="left"))
+        if cut <= pos:
+            t = float(times[pos])
+            crm = build_window_crm(
+                tr.items[win_start:pos], tr.n, theta, top_frac=top_frac)
+            prev = cliques_ref.generate_cliques(
+                prev, prev_crm, crm, tr.n, omega, gamma)
+            parts.append(prev.clique_of.copy())
+            prev_crm = crm
+            win_start = pos
+            while next_cg <= t:
+                next_cg += t_cg
+            continue
+        pos = cut
+    return parts
+
+
+def device_partitions(tr, t_cg, combos, top_frac):
+    """One vmapped device-CGM replay over the ``(theta, gamma, omega)``
+    lanes; returns (schedule, final carry, per-step slot maps)."""
+    from repro.core import CacheEnvironment, cgm_jax, get_policy
     from repro.core.engine_jax import JaxReplayEngine
 
-    tr = get_trace("netflix", 4000)
-    t_cg = t_cg_for(tr, CostParams())
-    combos = [(th, g, om) for th in SMOKE_THETAS for g in SMOKE_GAMMAS
-              for om in SMOKE_OMEGAS]
+    def policy(th, g, om):
+        p = get_policy("akpc", params=CostParams(theta=th, gamma=g, omega=om),
+                       t_cg=t_cg, top_frac=top_frac)
+        p.bind(tr.n, tr.m)
+        return p
 
-    def kw(th, g, om):
-        return dict(params=CostParams(theta=th, gamma=g, omega=om),
-                    t_cg=t_cg, top_frac=SMOKE_TOP_FRAC)
-
-    def oracle_walk(theta, gamma, omega):
-        """cliques_ref at every T_CG boundary, the replay engines' walk."""
-        times, R = tr.times, tr.n_requests
-        next_cg = float(times[0]) + t_cg
-        win_start = pos = 0
-        prev = prev_crm = None
-        parts = []
-        while pos < R:
-            cut = int(np.searchsorted(times, next_cg, side="left"))
-            if cut <= pos:
-                t = float(times[pos])
-                crm = build_window_crm(
-                    tr.items[win_start:pos], tr.n, theta,
-                    top_frac=SMOKE_TOP_FRAC)
-                prev = cliques_ref.generate_cliques(
-                    prev, prev_crm, crm, tr.n, omega, gamma)
-                parts.append(prev.clique_of.copy())
-                prev_crm = crm
-                win_start = pos
-                while next_cg <= t:
-                    next_cg += t_cg
-                continue
-            pos = cut
-        return parts
-
-    # -- one vmapped device call over the whole grid -----------------------
-    pol0 = get_policy("akpc", **kw(*combos[0]))
-    pol0.bind(tr.n, tr.m)
+    pol0 = policy(*combos[0])
     env = CacheEnvironment.resolve(None, tr, pol0.params)
     jeng = JaxReplayEngine(tr.n, tr.m, pol0.params, env=env)
-    sched = cgm_jax.build_cgm_schedule(tr, t_cg, uses_sizes=False)
-    nbd = int(sched.boundary_steps.size)
-    assert nbd >= 3, f"need chained windows, got {nbd}"
-    cspecs = []
-    for c in combos:
-        p = get_policy("akpc", **kw(*c))
-        p.bind(tr.n, tr.m)
-        cspecs.append(cgm_jax.cgm_spec(p.config, p.config.params, tr.n))
+    sched = cgm_jax.build_cgm_schedule(
+        tr, t_cg, uses_sizes=False, hot_dims=cgm_jax.policy_hot_dims(pol0))
+    cspecs = [cgm_jax.cgm_spec(p.config, p.config.params, tr.n)
+              for p in (policy(*c) for c in combos)]
     cspec = {k: np.stack([np.asarray(cs[k]) for cs in cspecs])
              for k in cspecs[0]}
     S = len(combos)
@@ -140,14 +130,18 @@ def smoke() -> None:
         uses_sizes=False, item_sizes=None, schedule=sched)
     carry0 = {k: np.stack([v] * S) for k, v in carry1.items()}
     spec = {k: np.stack([v] * S) for k, v in jeng._spec.items()}
-    before = cliques_mod.CGM_CALLS
     final, ofs = cgm_jax.run_cgm_schedule(
         sched, spec, jeng._statics, cspec, carry0, None)
+    return sched, final, ofs
+
+
+def partition_mismatches(tr, t_cg, combos, top_frac, sched, final, ofs):
+    """Lanes whose device partitions differ from the oracle at any
+    chained boundary (empty when every window matches)."""
+    nbd = int(sched.boundary_steps.size)
     failures = []
-    if cliques_mod.CGM_CALLS != before:
-        failures.append("device replay performed host CGM calls")
     for lane, (th, g, om) in enumerate(combos):
-        want = oracle_walk(th, g, om)
+        want = oracle_partitions(tr, t_cg, th, g, om, top_frac)
         if len(want) != nbd:
             failures.append(f"theta={th} gamma={g} omega={om}: "
                             f"{len(want)} oracle windows vs {nbd} device")
@@ -158,6 +152,34 @@ def smoke() -> None:
         if bad or not np.array_equal(final["of"][lane], want[-1]):
             failures.append(f"theta={th} gamma={g} omega={om}: partition "
                             f"mismatch at windows {bad or ['final']}")
+    return failures
+
+
+def smoke() -> None:
+    """CI gate: device-CGM partitions == ``cliques_ref`` oracle, chained."""
+    from repro.core import SweepEngine, SweepPoint
+    from repro.core import cliques as cliques_mod
+
+    tr = get_trace("netflix", 4000)
+    t_cg = t_cg_for(tr, CostParams())
+    combos = [(th, g, om) for th in SMOKE_THETAS for g in SMOKE_GAMMAS
+              for om in SMOKE_OMEGAS]
+
+    def kw(th, g, om):
+        return dict(params=CostParams(theta=th, gamma=g, omega=om),
+                    t_cg=t_cg, top_frac=SMOKE_TOP_FRAC)
+
+    # -- one vmapped device call over the whole grid -----------------------
+    before = cliques_mod.CGM_CALLS
+    sched, final, ofs = device_partitions(tr, t_cg, combos, SMOKE_TOP_FRAC)
+    nbd = int(sched.boundary_steps.size)
+    assert nbd >= 3, f"need chained windows, got {nbd}"
+    S = len(combos)
+    failures = []
+    if cliques_mod.CGM_CALLS != before:
+        failures.append("device replay performed host CGM calls")
+    failures += partition_mismatches(
+        tr, t_cg, combos, SMOKE_TOP_FRAC, sched, final, ofs)
 
     # -- a fig7-style sweep: one schedule, zero host CGM calls -------------
     eng = SweepEngine()

@@ -78,22 +78,18 @@ def _device_cgm_trace(n: int):
         t_max=20.0, bundle_cover=1.0, bundle_zipf=0.7, seed=0))
 
 
-def _time_device_cgm(n: int) -> dict | None:
+def _time_device_cgm(n: int) -> dict:
     """Warm wall time of a fully device-resident windowed replay (CGM
     inside the jit'd scan, DESIGN.md §11) vs the host-CGM jax path on the
     same trace — the PR-6 seam recorded in BENCH_cgm.json.
 
     Warm times (one compile pass first): the steady state every sweep
-    lane pays.  Returns None when jax is unavailable.
+    lane pays.
     """
     import os
 
-    try:
-        from repro.core.engine_jax import HAS_JAX, run_policy_jax
-    except Exception:
-        return None
-    if not HAS_JAX:
-        return None
+    from repro.core.engine_jax import run_policy_jax
+
     tr = _device_cgm_trace(n)
     params = CostParams()
     t_cg = t_cg_for(tr, params)
@@ -181,8 +177,6 @@ def main(smoke: bool = False) -> list[tuple]:
     cgm_items = {}
     for n in DEVICE_CGM_ITEMS:
         row = _time_device_cgm(n)
-        if row is None:
-            break
         cgm_items[n] = row
         rows.append((
             f"bench_cgm/items={n}", int(row["device_seconds"] * 1e6),

@@ -35,19 +35,19 @@ never re-trace.  Smoke also runs the ISSUE-8 mixed-shape gate: a grid
 of four distinct (n, m) points under a ``bucketed`` StateLayout must
 compile once per bucket COHORT, not once per point.
 
-``--mesh`` (devices x points): re-times the warm sweep in subprocesses
-under ``XLA_FLAGS=--xla_force_host_platform_device_count={1,2,4}`` with
-a ``make_sweep_mesh`` scenario mesh, recording the scaling row per
-device count in BENCH_sweep.json (CPU virtual devices — the record is
-the scaling SHAPE, not a speedup claim).
+``--mesh`` (devices x points): re-times the warm sweep in THIS process
+on 1, 2 and 4-device sub-meshes of ``jax.devices()`` (``make_sweep_mesh
+(n_devices=d)``; rows above the host's device count are skipped) and
+records one scaling row per device count in BENCH_sweep.json.  One
+process holds the chip, so no worker process is started.  On CPU give
+the process virtual devices with
+``XLA_FLAGS=--xla_force_host_platform_device_count=4``: that record is
+the scaling SHAPE, not a speedup claim.
 """
 from __future__ import annotations
 
 import argparse
-import json
 import os
-import subprocess
-import sys
 import time
 
 import numpy as np
@@ -136,46 +136,25 @@ def mixed_shape_gate() -> dict:
                        "col_bucket": lay.col_bucket}}
 
 
-def _mesh_worker() -> None:
-    """Subprocess body for --mesh: warm-time the sweep on THIS process's
-    device count under a scenario mesh, print one JSON line."""
+def bench_mesh(trace, n_alphas: int, n_rhos: int) -> list[dict]:
+    """Devices x points scaling rows on 1/2/4-device sub-meshes."""
     import jax
 
     from repro.launch.mesh import make_sweep_mesh
 
-    n = int(os.environ["REPRO_MESH_REQUESTS"])
-    n_alphas = int(os.environ["REPRO_MESH_ALPHAS"])
-    n_rhos = int(os.environ["REPRO_MESH_RHOS"])
-    trace = paper_trace("netflix", n_requests=n, seed=0)
     pts = build_grid(trace, n_alphas, n_rhos)
-    eng = SweepEngine(backend="jax", mesh=make_sweep_mesh())
-    eng.run(pts)                       # compile / cache-hit pass
-    t0 = time.perf_counter()
-    eng.run(pts)
-    warm = time.perf_counter() - t0
-    print(json.dumps({"devices": len(jax.devices()),
-                      "points": len(pts), "warm_seconds": warm}))
-
-
-def bench_mesh(n: int, n_alphas: int, n_rhos: int) -> list[dict]:
-    """Devices x points scaling rows (1, 2, 4 virtual CPU devices)."""
     rows = []
     for d in (1, 2, 4):
-        env = dict(
-            os.environ,
-            XLA_FLAGS=(os.environ.get("XLA_FLAGS", "") +
-                       f" --xla_force_host_platform_device_count={d}"),
-            REPRO_MESH_REQUESTS=str(n), REPRO_MESH_ALPHAS=str(n_alphas),
-            REPRO_MESH_RHOS=str(n_rhos),
-        )
-        out = subprocess.run(
-            [sys.executable, "-m", "benchmarks.sweep_bench",
-             "--mesh-worker"],
-            env=env, capture_output=True, text=True, check=True)
-        row = json.loads(out.stdout.strip().splitlines()[-1])
+        if d > len(jax.devices()):
+            break
+        eng = SweepEngine(backend="jax", mesh=make_sweep_mesh(n_devices=d))
+        eng.run(pts)                       # compile / cache-hit pass
+        t0 = time.perf_counter()
+        eng.run(pts)
+        row = {"devices": d, "points": len(pts),
+               "warm_seconds": time.perf_counter() - t0}
         rows.append(row)
-        print(f"# mesh: {row['devices']} device(s) -> "
-              f"{row['warm_seconds']:.2f}s warm")
+        print(f"# mesh: {d} device(s) -> {row['warm_seconds']:.2f}s warm")
     return rows
 
 
@@ -185,12 +164,7 @@ def main() -> None:
                     help="small CI run: parity + sweep must beat serial")
     ap.add_argument("--mesh", action="store_true",
                     help="record devices x points mesh scaling rows")
-    ap.add_argument("--mesh-worker", action="store_true",
-                    help=argparse.SUPPRESS)
     args, _ = ap.parse_known_args()
-    if args.mesh_worker:
-        _mesh_worker()
-        return
 
     if args.smoke:
         n = int(os.environ.get("REPRO_SWEEP_BENCH_REQUESTS", "60000"))
@@ -257,7 +231,7 @@ def main() -> None:
     if args.smoke:
         payload["mixed_shape"] = mixed_shape_gate()
     if args.mesh:
-        payload["mesh_scaling"] = bench_mesh(n, n_alphas, n_rhos)
+        payload["mesh_scaling"] = bench_mesh(trace, n_alphas, n_rhos)
     save_json("BENCH_sweep", payload)
     if args.smoke:
         assert t_warm < t_serial, (

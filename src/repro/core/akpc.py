@@ -46,11 +46,10 @@ class AKPCConfig:
     # requests per vectorised engine batch; None = engine default, 1 = the
     # historical per-request scalar replay (bit-compatible)
     batch_size: int | None = None
-    # accelerated hooks (Pallas kernel wrappers); None + kernels="auto"
-    # autowires the TPU kernels when a TPU backend is attached
+    # optional accelerated host-CGM hooks (``repro.kernels.ops``); None
+    # keeps the numpy CGM, which is what every backend runs by default
     crm_matmul: Callable | None = None
     pair_edges: Callable | None = None
-    kernels: str = "auto"            # "auto" | "off"
 
 
 @dataclasses.dataclass

@@ -26,7 +26,8 @@ device:
 * Alg. 3 — oversized-clique splits as a LIFO worklist over
   fixed-capacity MEMBER LISTS (``gcap`` ≤ a few × omega, not n), and
   the approximate merge as a ``lax.while_loop`` over the thresholded
-  density matrix in an ``(S_h, S_h)`` act-compacted slot space using
+  union edge-count matrix (order-equivalent to the host's densities)
+  in an ``(S_h, S_h)`` act-compacted slot space using
   the incremental ``X = M A M^T`` patch algebra of PR 3
   (``kernels/merge_step.py`` builds the initial D on TPU);
 * the partition install (``install_partition``) as segment reductions
@@ -53,32 +54,29 @@ could overflow that bound, and the eligibility gate
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
+from ..kernels.merge_step import (
+    merge_density_auto,
+    merge_density_jnp,
+    merge_edge_floor,
+)
 from .cliques import CliquePartition
 from .crm import WindowCRM
 from .engine import CacheState
 from .engine_jax import (
-    HAS_JAX,
     N_ACC,
     NE_TARGET,
     _bucket,
     _rate_hook,
-    _require_jax,
     _transfer_hook,
+    enable_compile_cache,
 )
-
-if HAS_JAX:  # pragma: no branch
-    import functools
-
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import enable_x64
-else:  # pragma: no cover - jax-less containers never import the scan path
-    jax = None
-    import functools
 
 #: device CGM is gated on the PADDED HOT CAPACITY h, not the catalog
 #: size — the (h, h) workspace and (2h, 2h) merge matrices stay cheap
@@ -320,14 +318,14 @@ def pad_cgm_schedule(schedule: CGMSchedule, dims: dict) -> CGMSchedule:
 def cgm_spec(cfg, params, n: int) -> dict:
     """The CGM hyperparameters as runtime (vmappable) scalars.
 
-    theta / gamma enter f32 comparisons on the host path (NEP-50 weak
-    scalars against f32 CRM/density matrices), so both are shipped in
-    the dtype each comparison actually runs in.
+    theta enters an f32 comparison on the host path (NEP-50 weak scalar
+    against the f32 CRM), so it is shipped as f32; gamma's f32 density
+    bar is shipped as the equivalent union edge-count floor.
     """
     omega = int(params.omega) if cfg.enable_split else int(n)
     return {
         "theta": np.float32(params.theta),
-        "gamma32": np.float32(params.gamma),
+        "e_floor": merge_edge_floor(omega, params.gamma),
         "gamma": np.float64(params.gamma),
         "omega": np.int32(omega),
         "omega_f": np.float64(omega),
@@ -351,7 +349,7 @@ def _accumulate_window(carry, x, *, n, m):
     * ``wcnt`` (n+1,) i32 — per-item access counts WITH duplicates
       (the host hot-set bincount does not dedup within a request).
     * ``seed`` (n+1, m) i32 — (item, server) counts WITH duplicates
-      (``window_seed_servers``'s ``np.add.at`` semantics).
+      (the per-occurrence tally of ``window_seed_servers``).
     """
     items = x["items"]                              # (B, d) i32
     B, d = items.shape
@@ -787,11 +785,12 @@ def _approx_merge(of, binary, hot_idx, valid_h, cspec, *, n, h,
     row/col per merge (the PR-3 algebra), with the f32 add order of
     the host (``(X[ai,ai] + X[aj,aj]) + 2.0 * X[ai,aj]``).
     """
-    if h * (h - 1) // 2 >= _F32_EXACT:
+    if h * (h - 1) // 2 >= _F32_EXACT >> 1:
         raise ValueError(
             f"device CGM hot capacity h={h} puts the pairwise edge "
-            f"count h*(h-1)/2 at/above 2**24; the f32 X counters would "
-            "lose exactness — route this trace to the host CGM")
+            f"count h*(h-1)/2 at/above 2**23; the merge orders pairs by "
+            "f32 edge count, which matches the host's f32 density order "
+            "only below that — route this trace to the host CGM")
     scap = 2 * n if full_merge else 2 * h
     slot = jnp.arange(scap, dtype=jnp.int32)
     hot_c = jnp.clip(hot_idx, 0, n - 1)
@@ -856,19 +855,11 @@ def _approx_merge(of, binary, hot_idx, valid_h, cspec, *, n, h,
                 hs[:, None], hs[None, :]].add(A)
 
         X = jax.lax.cond(ne2 > eb_cap, x_dense, x_sparse)[:scap, :scap]
-    e_max = (cspec["omega_f"] * (cspec["omega_f"] - 1.0) / 2.0).astype(
-        jnp.float32)
+    # D holds thresholded union edge counts (``merge_step`` docstring):
+    # the host's density order and gamma bar, with no device division
     eyeS = jnp.eye(scap, dtype=bool)
-    if use_kernels:
-        from ..kernels.merge_step import merge_density_auto
-
-        D = merge_density_auto(X, sizes, cspec["omega"], cspec["gamma32"])
-    else:
-        within = jnp.diag(X) / 2.0
-        e_u = (within[:, None] + within[None, :]) + X
-        okp = ((sizes[:, None] + sizes[None, :]) == cspec["omega"]) & ~eyeS
-        dens = jnp.where(okp, e_u / e_max, -1.0)
-        D = jnp.where(dens >= cspec["gamma32"], dens, -1.0)
+    merge_d = merge_density_auto if use_kernels else merge_density_jnp
+    D = merge_d(X, sizes, cspec["omega"], cspec["e_floor"])
     actp = act[:, None] & act[None, :] & ~eyeS
     D = jnp.where(actp, D, -2.0)
 
@@ -895,14 +886,13 @@ def _approx_merge(of, binary, hot_idx, valid_h, cspec, *, n, h,
         sizes = sizes.at[t].set(gnew)
         alive = alive.at[ai].set(False).at[aj].set(False).at[t].set(True)
         act = act.at[ai].set(False).at[aj].set(False).at[t].set(True)
-        # the new group's density row, host op order:
+        # the new group's edge-count row, host op order:
         # (within[-1] + within[:-1]) + Xn[-1, :-1]
         wt = dg / 2.0
         wl = jnp.diag(X) / 2.0
         e_row = (wt + wl) + X[t, :]
-        okr = (gnew + sizes) == cspec["omega"]
-        dr = jnp.where(okr, e_row / e_max, -1.0)
-        dr = jnp.where(dr >= cspec["gamma32"], dr, -1.0)
+        okr = ((gnew + sizes) == cspec["omega"]) & (e_row >= cspec["e_floor"])
+        dr = jnp.where(okr, e_row, -1.0)
         validc = act & alive & (slot != t)
         dr = jnp.where(validc, dr, -2.0)
         D = D.at[ai, :].set(-2.0).at[:, ai].set(-2.0)
@@ -1211,21 +1201,27 @@ def _cgm_replay_impl(spec, cspec, init, xs, item_sizes, *, kind, charge,
     return jax.lax.scan(step, init, xs)
 
 
-if HAS_JAX:
-    @functools.lru_cache(maxsize=64)
-    def _compiled_cgm_replay(kind, charge, uses_sizes, enable_split,
-                             enable_acm, seed_new, use_kernels, gcap,
-                             full_merge, vmapped):
-        f = functools.partial(
-            _cgm_replay_impl, kind=kind, charge=charge,
-            uses_sizes=uses_sizes, enable_split=enable_split,
-            enable_acm=enable_acm, seed_new=seed_new,
-            use_kernels=use_kernels, gcap=gcap, full_merge=full_merge)
-        if vmapped:
-            # scenarios vmap over spec / cgm spec / carry; the schedule
-            # tensors and item sizes are shared unbatched
-            f = jax.vmap(f, in_axes=(0, 0, 0, None, None))
-        return jax.jit(f)
+@functools.lru_cache(maxsize=64)
+def _compiled_cgm_replay(kind, charge, uses_sizes, enable_split,
+                         enable_acm, seed_new, use_kernels, gcap,
+                         full_merge, vmapped):
+    f = functools.partial(
+        _cgm_replay_impl, kind=kind, charge=charge,
+        uses_sizes=uses_sizes, enable_split=enable_split,
+        enable_acm=enable_acm, seed_new=seed_new,
+        use_kernels=use_kernels, gcap=gcap, full_merge=full_merge)
+    if vmapped:
+        # scenarios vmap over spec / cgm spec / carry; the schedule
+        # tensors and item sizes are shared unbatched
+        f = jax.vmap(f, in_axes=(0, 0, 0, None, None))
+    return jax.jit(f)
+
+
+def kernels_on_backend() -> bool:
+    """Whether the boundary step takes the Mosaic CGM kernels: on a TPU
+    backend, read at call time.  Elsewhere the static forms tuned for
+    XLA (dense one-hot / pair-scatter CRM, edge-scatter X) run instead."""
+    return jax.default_backend() == "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -1353,11 +1349,9 @@ def run_cgm_schedule(schedule, spec, statics, cspec, carry0, item_sizes, *,
     ``spec``/``cspec``/``carry0`` may carry a leading scenario axis (the
     fig7 grid); the schedule and item sizes stay shared unbatched.
     """
-    _require_jax()
+    enable_compile_cache()
     if use_kernels is None:
-        from ..kernels.autowire import default_cgm_hooks
-
-        use_kernels = default_cgm_hooks()[0] is not None
+        use_kernels = kernels_on_backend()
     vmapped = carry0["E"].ndim == 3
     gcap, full_merge = cgm_loop_statics(
         cspec, carry0, enable_split=enable_split, enable_acm=enable_acm)
@@ -1365,7 +1359,7 @@ def run_cgm_schedule(schedule, spec, statics, cspec, carry0, item_sizes, *,
         statics, charge, "vol" in carry0, bool(enable_split),
         bool(enable_acm), bool(seed_new), bool(use_kernels), gcap,
         full_merge, vmapped)
-    with enable_x64():
+    with jax.enable_x64(True):
         spec_j = {k: jnp.asarray(v) for k, v in spec.items()}
         cspec_j = {k: jnp.asarray(v) for k, v in cspec.items()}
         init_j = {k: jnp.asarray(v) for k, v in carry0.items()}
@@ -1474,8 +1468,6 @@ def wants_device_cgm(policy, trace, model) -> bool:
     """
     mode = os.environ.get("REPRO_JAX_CGM", "auto").strip().lower()
     if mode in ("off", "0"):
-        return False
-    if not HAS_JAX:
         return False
     from .akpc import AKPCConfig
     from .policy import AKPCPolicy

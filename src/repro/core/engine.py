@@ -79,10 +79,9 @@ lag fast path is preserved and picked automatically.  Fact 2 is unaffected:
 within one (clique, server) pair dt is constant, so pair expiries stay
 lags/segment-ends.
 
-The per-batch item->clique membership lookup is routed through
-``repro.kernels.packed_lookup.clique_lookup``: the Pallas scalar-prefetch
-gather on TPU backends, a NumPy fancy-index everywhere else (including when
-JAX is not importable at all).
+The per-batch item->clique membership lookup is a NumPy fancy-index on
+every backend: the engine is the plain host reference, and a device
+gather per batch would cost a round trip (and a compile per batch shape).
 """
 from __future__ import annotations
 
@@ -107,7 +106,7 @@ DEFAULT_BATCH_SIZE = 4096
 
 
 def _numpy_clique_lookup(clique_of: np.ndarray, items: np.ndarray) -> np.ndarray:
-    """Fallback membership gather used when the kernels package is absent."""
+    """Item -> clique membership gather (host numpy, every backend)."""
     return np.asarray(clique_of)[np.asarray(items)]
 
 
@@ -370,26 +369,33 @@ def match_partitions(
 
 
 def window_seed_servers(
-    n: int,
     m: int,
     partition: CliquePartition,
     window_items: np.ndarray,
     window_servers: np.ndarray,
 ) -> np.ndarray:
     """(k,) the server that accessed each clique's members most during the
-    window (Alg. 1 line 5 seeding target).  State-free half of the
-    ``install_partition`` seed path."""
-    order = partition.member_order()
-    sizes = partition.sizes().astype(np.int64)
-    starts = np.zeros(partition.k, np.int64)
-    np.cumsum(sizes[:-1], out=starts[1:])
-    seed_counts = np.zeros((n, m), dtype=np.int64)
+    window (Alg. 1 line 5 seeding target); ties, and cliques nobody
+    accessed, take the lowest server index (a row argmax over the dense
+    (k, m) access counts).  State-free half of the ``install_partition``
+    seed path.
+
+    Only the window's (clique, server) pairs are tallied: the dense
+    (n, m) count matrix cost O(n m) per window, seconds per window at a
+    100,000-item catalog."""
     reps = (window_items >= 0).sum(axis=1)
-    srv = np.repeat(window_servers, reps)
+    srv = np.repeat(np.asarray(window_servers, np.int64), reps)
     itm = window_items[window_items >= 0]
-    np.add.at(seed_counts, (itm, srv), 1)
-    seed_sum = np.add.reduceat(seed_counts[order], starts, axis=0)
-    return np.argmax(seed_sum, axis=1)
+    key = partition.clique_of[itm].astype(np.int64) * m + srv
+    uk, cnt = np.unique(key, return_counts=True)
+    c, j = uk // m, uk % m
+    order = np.lexsort((j, -cnt, c))        # per clique: most, then lowest j
+    c, j = c[order], j[order]
+    first = np.ones(c.size, bool)
+    first[1:] = c[1:] != c[:-1]
+    out = np.zeros(partition.k, np.int64)
+    out[c[first]] = j[first]
+    return out
 
 
 class ReplayEngine:
@@ -433,12 +439,7 @@ class ReplayEngine:
         self._item_sizes = env.sizes() if self.model.uses_sizes else None
         self.caching_charge = caching_charge
         self.seed_new_cliques = seed_new_cliques
-        if lookup is None:
-            try:
-                from ..kernels.packed_lookup import clique_lookup as lookup
-            except Exception:           # kernels layer unavailable: pure numpy
-                lookup = _numpy_clique_lookup
-        self._lookup = lookup
+        self._lookup = lookup if lookup is not None else _numpy_clique_lookup
         self._item_keep: np.ndarray | None = None
         self._clique_nk: np.ndarray | None = None
         self.state = CacheState.fresh(CliquePartition.singletons(n), m)
@@ -546,17 +547,25 @@ class ReplayEngine:
 
         changed = ~matched
         if changed.any():
-            # nominal per-item expiry under the old partition
-            item_E = old.E[old_of]                       # (n, m)
             order = partition.member_order()             # grouped by clique
             starts = np.zeros(k, np.int64)
             np.cumsum(new_sizes[:-1], out=starts[1:])
-            min_E = np.minimum.reduceat(item_E[order], starts, axis=0)
-            fresh = np.where(min_E > now, min_E, 0.0)    # (k, m)
-            E[changed] = fresh[changed]
-            row_max = fresh.max(axis=1)
+            # nominal per-item expiry under the old partition, over the
+            # members of changed cliques only (matched rows were copied)
+            rows = np.nonzero(changed)[0]
+            rsz = new_sizes[rows]
+            rstart = np.zeros(rows.size, np.int64)
+            np.cumsum(rsz[:-1], out=rstart[1:])
+            pos = np.repeat(starts[rows] - rstart, rsz) + np.arange(rsz.sum())
+            item_E = old.E[old_of[order[pos]]]           # (members, m)
+            min_E = np.minimum.reduceat(item_E, rstart, axis=0)
+            fresh = np.where(min_E > now, min_E, 0.0)    # (changed, m)
+            E[rows] = fresh
+            row_max = np.zeros(k)
+            row_max[rows] = fresh.max(axis=1)
             present = changed & (row_max > 0)
-            anchor[present] = np.argmax(fresh, axis=1)[present].astype(np.int32)
+            anchor[rows] = np.where(
+                present[rows], np.argmax(fresh, axis=1), -1).astype(np.int32)
 
             need_seed = changed & (row_max <= 0) & (new_sizes > 1)
             if self._item_keep is not None and need_seed.any():
@@ -573,7 +582,7 @@ class ReplayEngine:
             ):
                 # item -> per-server access counts over the window
                 js = window_seed_servers(
-                    self.n, self.m, partition, window_items, window_servers)
+                    self.m, partition, window_items, window_servers)
                 rows = np.nonzero(need_seed)[0]
                 E[rows, js[rows]] = now + self._dt_arr[js[rows]]
                 anchor[rows] = js[rows].astype(np.int32)

@@ -21,16 +21,16 @@ This module splits the replay in two (DESIGN.md §10):
   the schedule's batches inside a single ``jit``, with ``CacheState``
   living on device for the whole trace.  Under per-server dt the anchor
   resolution and the pair-expiry update are segmented running
-  (arg)max scans routed through ``kernels/segment_reduce.py`` (Pallas on
-  accelerators via ``kernels/autowire.py``, pure-jnp fallback on CPU).
+  (arg)max doubling scans (``kernels/segment_reduce.py``) in jnp on
+  every backend: they carry f64 expiries, which Mosaic refuses.
 
 The state trajectory is float-for-float identical to the NumPy engine
 (same f64 ops on the same operands); cost totals differ only by summation
 order inside a batch, which is why parity holds at 1e-9 relative
 (tests/test_sweep.py) on every chunking.
 
-Everything runs under ``jax.experimental.enable_x64`` so the engine's
-float64 semantics survive; the rest of the repo stays on default x32.
+Everything runs under ``jax.enable_x64(True)`` so the engine's float64
+semantics survive; the rest of the repo stays on default x32.
 
 Because the schedule is state-free, ``core/sweep.py`` can share ONE
 schedule across every scenario that prices the same (trace x clique-gen
@@ -54,8 +54,11 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import os
 from typing import Callable
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from .cliques import CliquePartition
@@ -67,6 +70,10 @@ from .cost import (
     Table1CostModel,
     TieredCostModel,
 )
+from ..kernels.segment_reduce import (
+    seg_running_argmax_jnp,
+    seg_running_max_jnp,
+)
 from .engine import (
     CacheState,
     CachingCharge,
@@ -77,61 +84,41 @@ from .engine import (
 )
 from .state_layout import StateLayout
 
-try:  # the accelerator layer stays optional (pure-numpy containers)
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import enable_x64
-
-    HAS_JAX = True
-except Exception:  # pragma: no cover - exercised only in jax-less containers
-    jax = None
-    HAS_JAX = False
-
-
-def _require_jax() -> None:
-    if not HAS_JAX:
-        raise ImportError(
-            "the JAX replay backend needs jax; install jax[cpu] or use "
-            "backend='numpy'")
-
+#: the in-checkout compile-cache directory used when
+#: ``JAX_COMPILATION_CACHE_DIR`` is unset (git-ignored)
+REPO_COMPILE_CACHE = os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), os.pardir, os.pardir,
+    os.pardir, ".jax_cache")
 
 _COMPILE_CACHE_SET = False
 
 
 def enable_compile_cache() -> None:
-    """Point XLA's persistent compilation cache at a stable directory.
+    """Turn on XLA's persistent compilation cache (first device use).
 
-    Compiling the replay scan costs ~1s per cohort shape — on small grids
-    that one compile used to outweigh the whole vmap win (BENCH_sweep.json
-    recorded the 24-point/40k grid at 0.88x serial).  Caching compiled
-    cohorts on disk makes every later process start warm, so sweeps win at
-    every size, not just when the compile amortises over a big grid.
-
-    ``REPRO_JAX_COMPILE_CACHE`` overrides the directory; ``off``/``0``
-    disables.  A ``jax_compilation_cache_dir`` the caller already set
-    always wins.  Idempotent, cheap, safe to call per SweepEngine.
+    Every device path (replay, sweep, live) calls this before it
+    compiles.  Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already
+    reads it and this sets no directory; otherwise the cache goes to
+    one fixed path inside the checkout (``REPO_COMPILE_CACHE``) — the
+    path is part of the cache key, so it must not move between runs.
+    Idempotent.
     """
     global _COMPILE_CACHE_SET
-    if _COMPILE_CACHE_SET or not HAS_JAX:
+    if _COMPILE_CACHE_SET:
         return
     _COMPILE_CACHE_SET = True
-    import os
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        from jax.experimental.compilation_cache import compilation_cache
 
-    env = os.environ.get("REPRO_JAX_COMPILE_CACHE", "")
-    if env.lower() in ("off", "0", "none"):
-        return
-    if jax.config.jax_compilation_cache_dir:
-        return  # caller owns the cache config
-    path = env or os.path.join(
-        os.path.expanduser("~"), ".cache", "repro", "jax")
-    try:
-        jax.config.update("jax_compilation_cache_dir", path)
-        # the scan compiles in ~1s and serialises small; the defaults
-        # (1s floor) would skip borderline cohorts on fast machines
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    except Exception:  # pragma: no cover - ancient jax without the knobs
-        pass
+        jax.config.update("jax_compilation_cache_dir",
+                          os.path.normpath(REPO_COMPILE_CACHE))
+        # a process's first compile decides once whether the cache is
+        # used: make a compile that ran before this point check again
+        compilation_cache.reset_cache()
+    # the scan compiles in ~1s and serialises small; the defaults
+    # (1s floor) would skip borderline cohorts on fast machines
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
 
 # ---------------------------------------------------------------------------
@@ -382,7 +369,7 @@ def build_schedule(
         seed_j = np.zeros(chg.size, np.int32)
         seed_ok = np.zeros(chg.size, bool)
         if seed_new_cliques and w_it is not None and k > 0 and chg.size:
-            js = window_seed_servers(n, m, part, w_it, w_sv)
+            js = window_seed_servers(m, part, w_it, w_sv)
             seed_j = js[chg].astype(np.int32)
             seed_ok = new_sizes[chg] > 1
             if cur_keep is not None:
@@ -750,19 +737,6 @@ def pad_schedule(s, dims: dict):
 N_ACC = 6
 
 
-def _seg_hooks(use_pallas: bool):
-    if use_pallas:
-        from ..kernels.ops import seg_argmax, seg_max
-
-        return seg_max, seg_argmax
-    from ..kernels.segment_reduce import (
-        seg_running_argmax_jnp,
-        seg_running_max_jnp,
-    )
-
-    return seg_running_max_jnp, seg_running_argmax_jnp
-
-
 def _install_step(E, anchor, x, dt):
     """Partition-install state translation (install_partition on device).
 
@@ -807,11 +781,10 @@ def _install_step(E, anchor, x, dt):
 SCAN_TRACES = 0
 
 
-def _replay_impl(spec, init, xs, *, kind, charge, const_dt, use_pallas):
+def _replay_impl(spec, init, xs, *, kind, charge, const_dt):
     """scan body closure; (spec, init) may carry a vmapped scenario axis."""
     global SCAN_TRACES
     SCAN_TRACES += 1
-    seg_max_fn, seg_argmax_fn = _seg_hooks(use_pallas)
     dt = spec["dt"]
 
     def step(carry, x):
@@ -845,7 +818,7 @@ def _replay_impl(spec, init, xs, *, kind, charge, const_dt, use_pallas):
                 x["prev_j"] == j)
         else:
             e_val_s = x["t_s"] + dt[x["j_s"]]
-            v, bidx = seg_argmax_fn(e_val_s, x["first_cs"])
+            v, bidx = seg_running_argmax_jnp(e_val_s, x["first_cs"])
             a0_s = anchor[x["c_s"]]
             Eg = E[x["c_s"], jnp.maximum(a0_s, 0)]     # finite gather
             dep = dep + 0.0 * Eg[0]
@@ -913,7 +886,7 @@ def _replay_impl(spec, init, xs, *, kind, charge, const_dt, use_pallas):
             anchor = anchor.at[jnp.where(upd, ac, K)].set(x["anc_j"])
         else:
             e_cj_s = x["cj_t_s"] + dt[x["cj_j_s"]]
-            vmax = seg_max_fn(e_cj_s, x["first_cjs"])
+            vmax = seg_running_max_jnp(e_cj_s, x["first_cjs"])
             E = E.at[uc, uj].set(vmax[x["pos_u"]] + dep)
             pa = x["pos_a"]
             win = v[pa] >= Ea0_s[pa]
@@ -926,10 +899,9 @@ def _replay_impl(spec, init, xs, *, kind, charge, const_dt, use_pallas):
 
 
 @functools.lru_cache(maxsize=64)
-def _compiled_replay(kind, charge, const_dt, use_pallas, vmapped):
+def _compiled_replay(kind, charge, const_dt, vmapped):
     f = functools.partial(
-        _replay_impl, kind=kind, charge=charge, const_dt=const_dt,
-        use_pallas=use_pallas)
+        _replay_impl, kind=kind, charge=charge, const_dt=const_dt)
     if vmapped == "xs":       # trace-shard axis: a schedule PER lane
         f = jax.vmap(f, in_axes=(0, 0, 0))
     elif vmapped:             # scenario axis: one schedule, many specs
@@ -945,7 +917,6 @@ def run_schedule(
     anchor0: np.ndarray,
     *,
     charge: CachingCharge = "requested",
-    use_pallas: bool | None = None,
     block: bool = True,
     layout: StateLayout | None = None,
 ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
@@ -960,15 +931,10 @@ def run_schedule(
     A row-sharded ``layout`` commits the state rows to its mesh placement
     before the scan, so GSPMD partitions the row gathers/scatters.
     """
-    _require_jax()
-    if use_pallas is None:
-        from ..kernels.autowire import default_segment_hooks
-
-        use_pallas = default_segment_hooks()[0] is not None
+    enable_compile_cache()
     vmapped = E0.ndim == 3
-    fn = _compiled_replay(
-        statics, charge, schedule.const_dt, bool(use_pallas), vmapped)
-    with enable_x64():
+    fn = _compiled_replay(statics, charge, schedule.const_dt, vmapped)
+    with jax.enable_x64(True):
         acc_shape = (E0.shape[0], N_ACC) if vmapped else (N_ACC,)
         if layout is not None and isinstance(E0, np.ndarray):
             # host inputs get the layout's mesh placement here; arrays a
@@ -995,7 +961,6 @@ def run_schedules(
     anchor0: np.ndarray,
     *,
     charge: CachingCharge = "requested",
-    use_pallas: bool | None = None,
     block: bool = True,
     layout: StateLayout | None = None,
 ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
@@ -1008,18 +973,13 @@ def run_schedules(
     All schedules must share padded dims (``pad_schedule``) and
     (n, m, const_dt); ``spec``/``E0``/``anchor0`` carry the leading S axis.
     """
-    _require_jax()
-    if use_pallas is None:
-        from ..kernels.autowire import default_segment_hooks
-
-        use_pallas = default_segment_hooks()[0] is not None
+    enable_compile_cache()
     s0 = schedules[0]
     assert E0.ndim == 3 and E0.shape[0] == len(schedules)
     assert all(s.const_dt == s0.const_dt and schedule_dims(s) ==
                schedule_dims(s0) for s in schedules[1:])
-    fn = _compiled_replay(
-        statics, charge, s0.const_dt, bool(use_pallas), "xs")
-    with enable_x64():
+    fn = _compiled_replay(statics, charge, s0.const_dt, "xs")
+    with jax.enable_x64(True):
         if layout is not None and isinstance(E0, np.ndarray):
             E0, anchor0 = layout.place_state(E0, anchor0)
         init = (
@@ -1104,7 +1064,6 @@ class JaxReplayEngine:
 
     def __init__(self, *args, engine: ReplayEngine | None = None,
                  layout: StateLayout | str | None = None, **kwargs):
-        _require_jax()
         self.engine = engine if engine is not None else ReplayEngine(
             *args, **kwargs)
         self.layout = StateLayout.resolve(layout)

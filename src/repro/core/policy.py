@@ -456,7 +456,6 @@ class AKPCPolicy(BasePolicy):
         batch_size: int | None = None,
         crm_matmul: Callable | None = None,
         pair_edges: Callable | None = None,
-        kernels: str | None = None,
         name: str | None = None,
         env: CacheEnvironment | None = None,
         cost_model: str | CostModel = "table1",
@@ -487,7 +486,6 @@ class AKPCPolicy(BasePolicy):
             "batch_size": batch_size,
             "crm_matmul": crm_matmul,
             "pair_edges": pair_edges,
-            "kernels": kernels,
         }
         cfg = dataclasses.replace(
             cfg, **{k: v for k, v in over.items() if v is not None}
@@ -504,17 +502,6 @@ class AKPCPolicy(BasePolicy):
     def bind(self, n: int, m: int) -> None:
         super().bind(n, m)
         self._prev_crm: WindowCRM | None = None
-        # kernel hooks: explicit config wins; "auto" wires the Pallas TPU
-        # kernels in as defaults whenever a TPU backend is attached
-        cfg = self.config
-        mm, pe = cfg.crm_matmul, cfg.pair_edges
-        if cfg.kernels == "auto" and (mm is None or pe is None):
-            from ..kernels.autowire import default_cgm_hooks
-
-            auto_mm, auto_pe = default_cgm_hooks()
-            mm = mm if mm is not None else auto_mm
-            pe = pe if pe is not None else auto_pe
-        self._crm_matmul, self._pair_edges = mm, pe
 
     # -- Event 1: clique generation on a window of requests ----------------
     def on_window(self, items, servers, now):
@@ -523,7 +510,7 @@ class AKPCPolicy(BasePolicy):
         t0 = _time.perf_counter()
         crm = build_window_crm(
             items, self.n, cfg.params.theta, cfg.top_frac,
-            crm_matmul=self._crm_matmul,
+            crm_matmul=cfg.crm_matmul,
             top_frac_of=cfg.top_frac_of,
         )
         omega = cfg.params.omega if cfg.enable_split else self.n
@@ -534,7 +521,7 @@ class AKPCPolicy(BasePolicy):
             self.n,
             omega,
             cfg.params.gamma,
-            pair_edges=self._pair_edges,
+            pair_edges=cfg.pair_edges,
             enable_split=cfg.enable_split,
             enable_approx_merge=cfg.enable_approx_merge,
         )

@@ -151,14 +151,6 @@ class SweepEngine:
     ):
         if backend not in ("jax", "numpy"):
             raise ValueError(f"unknown sweep backend {backend!r}")
-        if backend == "jax":
-            from . import engine_jax
-
-            if not engine_jax.HAS_JAX:
-                raise ImportError(
-                    "SweepEngine(backend='jax') needs jax; use "
-                    "backend='numpy'")
-            engine_jax.enable_compile_cache()
         self.backend = backend
         self.batch_size = batch_size
         self.mesh = mesh
@@ -630,13 +622,11 @@ class SweepEngine:
                and int(mesh.shape[lay.row_axis]) > 1 else None)
         if sc is None and row is None:
             return spec, E0, a0
-        from jax.experimental import enable_x64
-
         pfx = (sc,) * lead
         sh = NamedSharding(mesh, P(*pfx))
         shE = NamedSharding(mesh, P(*pfx, row, None))
         shA = NamedSharding(mesh, P(*pfx, row))
-        with enable_x64():    # keep f64 spec/state dtypes across the put
+        with jax.enable_x64(True):  # keep f64 spec/state across the put
             spec = {k: jax.device_put(v, sh) for k, v in spec.items()}
             return spec, jax.device_put(E0, shE), jax.device_put(a0, shA)
 
@@ -651,9 +641,8 @@ def sweep_points(
     """One-shot convenience: each grid entry is SweepPoint kwargs.
 
     With ``backend`` unset, picks ``REPRO_SWEEP_BACKEND`` (default jax)
-    and degrades to the serial numpy loop when JAX is unavailable or any
-    point's cost model has no JAX formula (same rule as
-    ``benchmarks.common.run_method_grid``)."""
+    and takes the serial numpy loop when any point's cost model has no
+    JAX formula (same rule as ``benchmarks.common.run_method_grid``)."""
     import os
 
     pts = [SweepPoint(**g) for g in grid]
@@ -662,7 +651,7 @@ def sweep_points(
         if backend == "jax":
             from . import engine_jax
 
-            if not engine_jax.HAS_JAX or not all(
+            if not all(
                     pt.policy_kwargs.get("cost_model", "table1")
                     in engine_jax.JAX_COST_MODELS
                     for pt in pts):

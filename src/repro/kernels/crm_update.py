@@ -9,8 +9,9 @@ i.e. a matmul — the systolic MXU does it at matmul speed with zero atomics.
 The kernel is a transpose-matmul tiled over (n/bm, n/bn) output blocks with a
 k-loop over request blocks; fp32 accumulation lives in a VMEM scratch.
 
-Target: TPU v5e (128x128 MXU tiles).  Validated with interpret=True on CPU
-against ``ref.crm_ref`` (tests/test_kernels.py sweeps shapes/dtypes).
+Target: TPU v5e (128x128 MXU tiles).  The tests check the kernel body in
+interpret mode against ``ref.crm_ref`` (tests/test_kernels.py) and compile
+it for a described v5e chip (tests/test_tpu_compile.py).
 """
 from __future__ import annotations
 

@@ -1,22 +1,28 @@
-"""Pallas TPU kernel: initial merge-density matrix D of the Alg.-3 scan.
+"""Pallas TPU kernel: initial merge matrix D of the Alg.-3 scan.
 
 The device-resident CGM (``core.cgm_jax``) runs the approximate merge as a
-``lax.while_loop`` over a thresholded density matrix
+``lax.while_loop`` over a thresholded merge matrix
 
-    D[i, j] = density(i u j)   if |i| + |j| == omega and density >= gamma
-            = -1.0             otherwise,
+    D[i, j] = e(i u j)   if |i| + |j| == omega and density(i u j) >= gamma
+            = -1.0       otherwise,
 
 patched incrementally (one row/col per merge).  The initial D is the only
 O(S^2) dense build of the loop; this kernel assembles it on the VPU from the
 pair-edge matrix X = M A M^T (``clique_density.py``) and the group sizes:
 
     within[i]  = X[i, i] / 2
-    e(i u j)   = (within[i] + within[j]) + X[i, j]
-    D[i, j]    = e / e_max  thresholded as above.
+    e(i u j)   = (within[i] + within[j]) + X[i, j].
 
-Float32 op order matches ``core.cliques._densities`` exactly (the entries
-are exact small integers in fp32, the quotient is a single rounding), so
-kernel and jnp fallback are bit-identical — the device/host parity bar.
+D holds union edge counts, not densities.  Every eligible pair shares one
+e_max = omega (omega - 1) / 2, so the host's float32 density e / e_max
+orders and ties pairs exactly as e does (below 2^23, which the device
+path guarantees, distinct counts give distinct quotients), and its
+``density >= gamma`` bar is ``e >= e_floor`` for the integer floor
+``merge_edge_floor`` computes on the host.  No division runs on the device: a float32 divide inside this
+kernel on a TPU v5e gave quotients one ulp off numpy's correctly rounded
+ones, and a density one ulp off flips the bar or a tie.
+All entries are exact small integers in f32, so kernel, jnp twin and the
+host reference agree bit for bit.
 """
 from __future__ import annotations
 
@@ -24,11 +30,41 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+
+#: block-index zero as an int32 constant: the kernel is traced inside the
+#: x64 replay scan, where a bare ``0`` in an index map becomes an i64
+#: that Mosaic cannot return next to the i32 program id
+_Z = np.int32(0)
+
+#: f32 integers are exact below this; union edge counts stay under it
+_F32_EXACT = 2.0 ** 24
+
+
+def merge_edge_floor(omega: int, gamma: float) -> np.float32:
+    """Smallest union edge count whose host density passes ``gamma``.
+
+    The host keeps a pair iff ``float32(e) / float32(e_max) >= float32(
+    gamma)`` (``core.cliques._densities``).  A correctly rounded quotient
+    is monotone in e, so that test is ``e >= floor``; the floor is found
+    with the host's own float32 arithmetic around ceil(gamma e_max).
+    """
+    em = np.float32(omega * (omega - 1) / 2.0)
+    g = np.float32(gamma)
+    if em <= 0:
+        # omega <= 1: no pair of non-empty groups has |i| + |j| == omega
+        return np.float32(0.0)
+    e = min(max(np.ceil(float(g) * float(em)), 0.0), _F32_EXACT)
+    while e > 0 and np.float32(e - 1.0) / em >= g:
+        e -= 1.0
+    while e < _F32_EXACT and np.float32(e) / em < g:
+        e += 1.0
+    return np.float32(e)
 
 
 def _merge_density_kernel(
-    x_ref, wrow_ref, wcol_ref, srow_ref, scol_ref, om_ref, gm_ref, em_ref,
+    x_ref, wrow_ref, wcol_ref, srow_ref, scol_ref, om_ref, ef_ref,
     out_ref, *, bm: int,
 ):
     """Grid (Sp/bm,): one row block of D per step, all-pairs elementwise."""
@@ -39,24 +75,23 @@ def _merge_density_kernel(
     si = scol_ref[...]                               # (bm, 1) int32
     sj = srow_ref[...]                               # (1, Sp) int32
     om = om_ref[0, 0]
-    gm = gm_ref[0, 0]
-    em = em_ref[0, 0]
+    ef = ef_ref[0, 0]
     r = i * bm + jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
     c = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
-    okp = ((si + sj) == om) & (r != c)
     e_u = (wi + wj) + x
-    dens = jnp.where(okp, e_u / em, -1.0)
-    out_ref[...] = jnp.where(dens >= gm, dens, -1.0)
+    keep = ((si + sj) == om) & (r != c) & (e_u >= ef)
+    out_ref[...] = jnp.where(keep, e_u, -1.0)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "interpret"))
-def merge_density(X, sizes, omega, gamma32, *, bm: int = 128,
+def merge_density(X, sizes, omega, e_floor, *, bm: int = 128,
                   interpret: bool = False):
     """X (S, S) fp32 pair edges, sizes (S,) int32 -> D (S, S) fp32.
 
-    ``omega`` (int32) and ``gamma32`` (float32) are runtime scalars so a
-    vmapped hyperparameter sweep can trace this once.  Pad rows/cols have
-    size 0 and can never pass the ``|i| + |j| == omega`` gate (omega >= 2).
+    ``omega`` (int32) and ``e_floor`` (float32, ``merge_edge_floor``) are
+    runtime scalars so a vmapped hyperparameter sweep can trace this once.
+    Pad rows/cols have size 0 and can never pass the ``|i| + |j| ==
+    omega`` gate (omega >= 2).
     """
     S = X.shape[0]
     assert X.shape == (S, S) and sizes.shape == (S,)
@@ -66,52 +101,45 @@ def merge_density(X, sizes, omega, gamma32, *, bm: int = 128,
         jnp.diag(X).astype(jnp.float32) / 2.0)
     sz = jnp.zeros(Sp, jnp.int32).at[:S].set(sizes.astype(jnp.int32))
     om = jnp.asarray(omega, jnp.int32).reshape(1, 1)
-    gm = jnp.asarray(gamma32, jnp.float32).reshape(1, 1)
-    om_f = jnp.asarray(omega, jnp.float64)
-    em = (om_f * (om_f - 1.0) / 2.0).astype(jnp.float32).reshape(1, 1)
+    ef = jnp.asarray(e_floor, jnp.float32).reshape(1, 1)
     out = pl.pallas_call(
         functools.partial(_merge_density_kernel, bm=bm),
         grid=(Sp // bm,),
         in_specs=[
-            pl.BlockSpec((bm, Sp), lambda i: (i, 0)),
-            pl.BlockSpec((1, Sp), lambda i: (0, 0)),
-            pl.BlockSpec((bm, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, Sp), lambda i: (0, 0)),
-            pl.BlockSpec((bm, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
+            pl.BlockSpec((bm, Sp), lambda i: (i, _Z)),
+            pl.BlockSpec((1, Sp), lambda i: (_Z, _Z)),
+            pl.BlockSpec((bm, 1), lambda i: (i, _Z)),
+            pl.BlockSpec((1, Sp), lambda i: (_Z, _Z)),
+            pl.BlockSpec((bm, 1), lambda i: (i, _Z)),
+            pl.BlockSpec((1, 1), lambda i: (_Z, _Z)),
+            pl.BlockSpec((1, 1), lambda i: (_Z, _Z)),
         ],
-        out_specs=pl.BlockSpec((bm, Sp), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((bm, Sp), lambda i: (i, _Z)),
         out_shape=jax.ShapeDtypeStruct((Sp, Sp), jnp.float32),
         interpret=interpret,
     )(
         Xp,
         within.reshape(1, Sp), within.reshape(Sp, 1),
         sz.reshape(1, Sp), sz.reshape(Sp, 1),
-        om, gm, em,
+        om, ef,
     )
     return out[:S, :S]
 
 
 @jax.jit
-def merge_density_jnp(X, sizes, omega, gamma32):
-    """Fused-jnp fallback with ``core.cliques._densities`` float32 op
-    order — bit-identical to the Mosaic kernel."""
+def merge_density_jnp(X, sizes, omega, e_floor):
+    """Fused-jnp twin of the Mosaic kernel, bit-identical to it."""
     S = X.shape[0]
     within = jnp.diag(X) / 2.0
     e_u = (within[:, None] + within[None, :]) + X
-    om_f = jnp.asarray(omega, jnp.float64)
-    e_max = (om_f * (om_f - 1.0) / 2.0).astype(jnp.float32)
-    eyeS = jnp.eye(S, dtype=bool)
     okp = ((sizes[:, None] + sizes[None, :])
-           == jnp.asarray(omega, jnp.int32)) & ~eyeS
-    dens = jnp.where(okp, e_u / e_max, -1.0)
-    return jnp.where(dens >= jnp.asarray(gamma32, jnp.float32), dens, -1.0)
+           == jnp.asarray(omega, jnp.int32)) & ~jnp.eye(S, dtype=bool)
+    keep = okp & (e_u >= jnp.asarray(e_floor, jnp.float32))
+    return jnp.where(keep, e_u, -1.0)
 
 
-def merge_density_auto(X, sizes, omega, gamma32, **kw):
-    """Mosaic on TPU, fused jnp elsewhere (replaces interpret mode)."""
+def merge_density_auto(X, sizes, omega, e_floor, **kw):
+    """Mosaic on TPU, fused jnp elsewhere."""
     if jax.default_backend() == "tpu":
-        return merge_density(X, sizes, omega, gamma32, **kw)
-    return merge_density_jnp(X, sizes, omega, gamma32)
+        return merge_density(X, sizes, omega, e_floor, **kw)
+    return merge_density_jnp(X, sizes, omega, e_floor)

@@ -1,23 +1,17 @@
-"""Jit'd public wrappers around the Pallas kernels.
+"""Host-CGM hooks around the CGM matmul kernels.
 
-On a real TPU the CGM matmul hooks compile to Mosaic; on every other
-backend they dispatch to fused-jnp twins (bit-identical — exact fp32
-integer counts — and XLA-native fast, replacing the old interpret-mode
-fallback that executed the Pallas body in Python).  The segment-reduce
-and lookup kernels keep ``INTERPRET`` off-TPU: their scan-shaped bodies
-have no faster jnp twin at the hook seam.
+``AKPCConfig(crm_matmul=..., pair_edges=...)`` takes these explicitly;
+the host CGM never wires them in on its own, so the numpy reference
+stays on the host on every backend.  Each call picks Mosaic on a TPU
+backend and the bit-identical fused-jnp twin elsewhere (exact fp32
+integer counts), reading ``jax.default_backend()`` at call time.
 """
 from __future__ import annotations
 
-import jax
 import numpy as np
 
 from .clique_density import clique_pair_edges_auto
 from .crm_update import crm_update_auto
-from .packed_lookup import packed_lookup, unpacked_lookup
-from .segment_reduce import seg_running_argmax, seg_running_max
-
-INTERPRET = jax.default_backend() != "tpu"
 
 
 def crm_matmul(H):
@@ -30,23 +24,3 @@ def pair_edges(M, A):
     """Accelerated merge-score hook for repro.core.cliques.merge_scores:
     membership (k, h) x binary CRM (h, h) -> (k, k) union edge counts."""
     return np.asarray(clique_pair_edges_auto(M, A))
-
-
-def seg_max(values, starts):
-    """Segmented running max hook for the JAX replay backend
-    (core/engine_jax.py): (L,) values + (L,) segment-start flags."""
-    return seg_running_max(values, starts, interpret=INTERPRET)
-
-
-def seg_argmax(values, starts):
-    """Segmented running (max, latest-argmax) hook for the JAX replay
-    backend's per-server-dt anchor resolution."""
-    return seg_running_argmax(values, starts, interpret=INTERPRET)
-
-
-def gather_packed(table, ids):
-    return packed_lookup(table, ids, interpret=INTERPRET)
-
-
-def gather_unpacked(items, ids):
-    return unpacked_lookup(items, ids, interpret=INTERPRET)

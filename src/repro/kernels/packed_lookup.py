@@ -8,124 +8,41 @@ with one streaming DMA per clique ((omega*d)-row burst), instead of omega
 scattered row gathers — same bytes, 1/omega the DMA descriptors and no
 random-access stalls.
 
-``packed_lookup``  : table (C, omega, d) packed cliques, ids (R,) ->
-                     (R, omega, d); one grid step per request, the block
-                     index map reads the clique id from SCALAR-PREFETCH
-                     (pltpu.PrefetchScalarGridSpec) so the DMA address is
-                     known before the body runs.
-``unpacked_lookup``: the baseline — one grid step per (request, item) with a
-                     row-level index map (omega x the descriptor traffic).
-``clique_lookup``  : the replay engine's per-batch item -> clique-id
-                     membership gather.  Routed through ``packed_lookup``
-                     (table reshaped to (n, 1, 1)) when a TPU backend is
-                     present; plain NumPy fancy-indexing when JAX is absent
-                     or running CPU-only, where a Pallas interpret-mode grid
-                     walk would be strictly slower than the gather it
-                     emulates.
-
-JAX is imported defensively so the pure-NumPy replay path works in
-containers without the accelerator toolchain.
+``packed_lookup``: table (C, omega, d) packed cliques, ids (R,) ->
+(R, omega, d); one grid step per request, the block index map reads the
+clique id from SCALAR-PREFETCH (pltpu.PrefetchScalarGridSpec) so the DMA
+address is known before the body runs.  It is a payload gather only: the
+replay engines map items to cliques with a numpy gather on the host.
 """
 from __future__ import annotations
 
 import functools
 
-import numpy as np
-
-try:  # accelerator layer is optional — see module docstring
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_JAX = True
-except Exception:  # pragma: no cover - exercised only in jax-less containers
-    jax = None
-    _HAS_JAX = False
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel_unavailable(*_a, **_k):
-    raise ImportError(
-        "packed_lookup/unpacked_lookup need JAX with Pallas TPU support; "
-        "use clique_lookup (NumPy fallback) instead"
+def _copy_kernel(ids_ref, table_ref, out_ref):
+    del ids_ref
+    out_ref[...] = table_ref[...]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def packed_lookup(table, ids, *, interpret: bool = False):
+    """table (C, omega, d); ids (R,) int32 -> (R, omega, d)."""
+    C, omega, d = table.shape
+    R = ids.shape[0]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(R,),
+        in_specs=[pl.BlockSpec((1, omega, d), lambda r, ids: (ids[r], 0, 0))],
+        out_specs=pl.BlockSpec((1, omega, d), lambda r, ids: (r, 0, 0)),
     )
-
-
-if _HAS_JAX:
-
-    def _copy_kernel(ids_ref, table_ref, out_ref):
-        del ids_ref
-        out_ref[...] = table_ref[...]
-
-    @functools.partial(jax.jit, static_argnames=("interpret",))
-    def packed_lookup(table, ids, *, interpret: bool = False):
-        """table (C, omega, d); ids (R,) int32 -> (R, omega, d)."""
-        C, omega, d = table.shape
-        R = ids.shape[0]
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(R,),
-            in_specs=[pl.BlockSpec((1, omega, d), lambda r, ids: (ids[r], 0, 0))],
-            out_specs=pl.BlockSpec((1, omega, d), lambda r, ids: (r, 0, 0)),
-        )
-        return pl.pallas_call(
-            _copy_kernel,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((R, omega, d), table.dtype),
-            interpret=interpret,
-        )(ids.astype(jnp.int32), table)
-
-    @functools.partial(jax.jit, static_argnames=("interpret",))
-    def unpacked_lookup(items, ids, *, interpret: bool = False):
-        """items (n, d); ids (R, omega) int32 -> (R, omega, d).
-
-        Baseline: one DMA per (request, item) — omega x the descriptors.
-        """
-        n, d = items.shape
-        R, omega = ids.shape
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(R, omega),
-            in_specs=[pl.BlockSpec((1, d), lambda r, o, ids: (ids[r, o], 0))],
-            out_specs=pl.BlockSpec((1, 1, d), lambda r, o, ids: (r, o, 0)),
-        )
-        return pl.pallas_call(
-            _copy_reshape_kernel,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((R, omega, d), items.dtype),
-            interpret=interpret,
-        )(ids.astype(jnp.int32).reshape(R, omega), items)
-
-    def _copy_reshape_kernel(ids_ref, items_ref, out_ref):
-        del ids_ref
-        out_ref[...] = items_ref[...].reshape(out_ref.shape)
-
-else:  # pragma: no cover - exercised only in jax-less containers
-    packed_lookup = _kernel_unavailable
-    unpacked_lookup = _kernel_unavailable
-
-
-def clique_lookup(
-    clique_of: np.ndarray,
-    items: np.ndarray,
-    *,
-    use_pallas: bool | None = None,
-    interpret: bool = False,
-) -> np.ndarray:
-    """Map item ids to clique ids; -1 padding slots stay -1.
-
-    ``clique_of`` (n,) int; ``items`` any-shape int.  With ``use_pallas``
-    unset, the Pallas scalar-prefetch gather is used iff a TPU backend is
-    active; the NumPy path is taken when JAX is missing or CPU-only.
-    """
-    clique_of = np.asarray(clique_of)
-    items = np.asarray(items)
-    if use_pallas is None:
-        use_pallas = _HAS_JAX and jax.default_backend() == "tpu"
-    if not use_pallas or not _HAS_JAX:
-        return np.where(items < 0, -1, clique_of[np.maximum(items, 0)])
-    flat = items.reshape(-1)
-    table = jnp.asarray(clique_of, jnp.int32).reshape(-1, 1, 1)
-    ids = jnp.maximum(jnp.asarray(flat, jnp.int32), 0)
-    got = np.asarray(packed_lookup(table, ids, interpret=interpret))
-    return np.where(items < 0, -1, got.reshape(items.shape))
+    return pl.pallas_call(
+        _copy_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((R, omega, d), table.dtype),
+        interpret=interpret,
+    )(ids.astype(jnp.int32), table)

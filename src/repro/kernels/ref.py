@@ -22,8 +22,3 @@ def clique_pair_edges_ref(M, A):
 def packed_lookup_ref(table, ids):
     """table (C, omega, d), ids (R,) -> (R, omega, d)."""
     return table[ids]
-
-
-def unpacked_lookup_ref(items, ids):
-    """items (n, d), ids (R, omega) -> (R, omega, d)."""
-    return items[ids]

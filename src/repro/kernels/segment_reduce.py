@@ -1,43 +1,30 @@
-"""Pallas TPU kernels: segmented running max / running argmax.
+"""Segmented running max / running argmax as jnp doubling scans.
 
 The JAX replay backend (``core/engine_jax.py``) keeps two inner segment
 reductions on device (everything else is hoisted into the host-built replay
 schedule, DESIGN.md §10):
 
-* ``seg_running_max``    — inclusive running maximum within each segment of
-  a (clique, server)-sorted event stream; the value at a segment's last
-  position is the pair's post-batch expiry ``max_e (t_e + dt_{j_e})``.
-* ``seg_running_argmax`` — the same scan carrying the LATEST index attaining
-  the maximum (ties -> later event, matching the scalar ``touch`` rule's
-  ``>=`` anchor update); this is the Alg.-6 anchor resolution over a
-  clique-sorted event stream under per-server dt (DESIGN.md §9).
+* ``seg_running_max_jnp``    — inclusive running maximum within each
+  segment of a (clique, server)-sorted event stream; the value at a
+  segment's last position is the pair's post-batch expiry
+  ``max_e (t_e + dt_{j_e})``.
+* ``seg_running_argmax_jnp`` — the same scan carrying the LATEST index
+  attaining the maximum (ties -> later event, matching the scalar
+  ``touch`` rule's ``>=`` anchor update); this is the Alg.-6 anchor
+  resolution over a clique-sorted event stream under per-server dt
+  (DESIGN.md §9).
 
 Both are Hillis-Steele doubling scans: log2(L) rounds of shift + select,
-with segment ids from a cumulative sum over the start flags.  The Pallas
-bodies run the identical rounds on a (1, L) block in VMEM; on non-TPU
-backends they execute with ``interpret=True`` (kernels/ops.py pattern).
-``seg_running_max_jnp`` / ``seg_running_argmax_jnp`` are the pure-jnp
-fallbacks the JAX engine uses when ``kernels/autowire.py`` decides the
-backend does not warrant Pallas.
-
-JAX is imported defensively so the pure-NumPy core keeps working in
-containers without the accelerator toolchain.
+with segment ids from a cumulative sum over the start flags.  They stay
+jnp on every backend: the values are f64 expiries, and Mosaic refuses
+64-bit operands (it also has no lowering for ``cumsum``), so no Pallas
+form of these scans can take them.  ``*_ref`` are the numpy oracles.
 """
 from __future__ import annotations
 
-import functools
-
+import jax
+import jax.numpy as jnp
 import numpy as np
-
-try:  # accelerator layer is optional
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    _HAS_JAX = True
-except Exception:  # pragma: no cover - exercised only in jax-less containers
-    jax = None
-    _HAS_JAX = False
 
 
 def _n_rounds(L: int) -> int:
@@ -48,98 +35,47 @@ def _n_rounds(L: int) -> int:
     return r
 
 
-def _kernel_unavailable(*_a, **_k):
-    raise ImportError(
-        "seg_running_max/seg_running_argmax need JAX; use the numpy oracle "
-        "kernels/ref.py:seg_running_max_ref instead"
-    )
+def _scan_rounds(v, seg, idx, rounds):
+    """Shared doubling rounds on (1, L) arrays; idx may be None."""
+    L = v.shape[-1]
+    d = 1
+    for _ in range(rounds):
+        vs = jnp.concatenate(
+            [jnp.full((1, d), -jnp.inf, v.dtype), v[:, : L - d]], axis=1)
+        ss = jnp.concatenate(
+            [jnp.full((1, d), -1, seg.dtype), seg[:, : L - d]], axis=1)
+        # earlier candidate wins only if STRICTLY greater: ties keep the
+        # LATER index (scalar touch's >= anchor update)
+        take = (ss == seg) & (vs > v)
+        v = jnp.where(take, vs, v)
+        if idx is not None:
+            is_ = jnp.concatenate(
+                [jnp.zeros((1, d), idx.dtype), idx[:, : L - d]], axis=1)
+            idx = jnp.where(take, is_, idx)
+        d <<= 1
+    return v, idx
 
 
-if _HAS_JAX:
+def seg_running_max_jnp(values, starts):
+    """values (L,), starts (L,) bool -> (L,) inclusive per-segment running
+    max.  Segments are contiguous runs beginning where ``starts`` is True
+    (position 0 must start a segment)."""
+    L = values.shape[-1]
+    v = values.reshape(1, L)
+    seg = jnp.cumsum(starts.reshape(1, L).astype(jnp.int32), axis=1)
+    v, _ = _scan_rounds(v, seg, None, _n_rounds(L))
+    return v.reshape(L)
 
-    def _scan_rounds(v, seg, idx, rounds):
-        """Shared doubling rounds on (1, L) arrays; idx may be None."""
-        L = v.shape[-1]
-        d = 1
-        for _ in range(rounds):
-            vs = jnp.concatenate(
-                [jnp.full((1, d), -jnp.inf, v.dtype), v[:, : L - d]], axis=1)
-            ss = jnp.concatenate(
-                [jnp.full((1, d), -1, seg.dtype), seg[:, : L - d]], axis=1)
-            # earlier candidate wins only if STRICTLY greater: ties keep the
-            # LATER index (scalar touch's >= anchor update)
-            take = (ss == seg) & (vs > v)
-            v = jnp.where(take, vs, v)
-            if idx is not None:
-                is_ = jnp.concatenate(
-                    [jnp.zeros((1, d), idx.dtype), idx[:, : L - d]], axis=1)
-                idx = jnp.where(take, is_, idx)
-            d <<= 1
-        return v, idx
 
-    def _segmax_kernel(v_ref, s_ref, out_ref, *, rounds: int):
-        v = v_ref[...]
-        seg = jnp.cumsum(s_ref[...].astype(jnp.int32), axis=1)
-        v, _ = _scan_rounds(v, seg, None, rounds)
-        out_ref[...] = v
-
-    def _segargmax_kernel(v_ref, s_ref, vout_ref, iout_ref, *, rounds: int):
-        v = v_ref[...]
-        seg = jnp.cumsum(s_ref[...].astype(jnp.int32), axis=1)
-        idx = jax.lax.broadcasted_iota(jnp.int32, v.shape, 1)
-        v, idx = _scan_rounds(v, seg, idx, rounds)
-        vout_ref[...] = v
-        iout_ref[...] = idx
-
-    @functools.partial(jax.jit, static_argnames=("interpret",))
-    def seg_running_max(values, starts, *, interpret: bool = False):
-        """values (L,), starts (L,) bool -> (L,) inclusive per-segment
-        running max.  Segments are contiguous runs beginning where
-        ``starts`` is True (position 0 must start a segment)."""
-        L = values.shape[0]
-        out = pl.pallas_call(
-            functools.partial(_segmax_kernel, rounds=_n_rounds(L)),
-            out_shape=jax.ShapeDtypeStruct((1, L), values.dtype),
-            interpret=interpret,
-        )(values.reshape(1, L), starts.reshape(1, L).astype(jnp.int32))
-        return out.reshape(L)
-
-    @functools.partial(jax.jit, static_argnames=("interpret",))
-    def seg_running_argmax(values, starts, *, interpret: bool = False):
-        """values (L,), starts (L,) bool -> ((L,) running max, (L,) int32
-        index of the LATEST position attaining it within the segment)."""
-        L = values.shape[0]
-        v, i = pl.pallas_call(
-            functools.partial(_segargmax_kernel, rounds=_n_rounds(L)),
-            out_shape=(
-                jax.ShapeDtypeStruct((1, L), values.dtype),
-                jax.ShapeDtypeStruct((1, L), jnp.int32),
-            ),
-            interpret=interpret,
-        )(values.reshape(1, L), starts.reshape(1, L).astype(jnp.int32))
-        return v.reshape(L), i.reshape(L)
-
-    def seg_running_max_jnp(values, starts):
-        """Pure-jnp fallback (same rounds, (L,) layout, any float dtype)."""
-        L = values.shape[-1]
-        v = values.reshape(1, L)
-        seg = jnp.cumsum(starts.reshape(1, L).astype(jnp.int32), axis=1)
-        v, _ = _scan_rounds(v, seg, None, _n_rounds(L))
-        return v.reshape(L)
-
-    def seg_running_argmax_jnp(values, starts):
-        L = values.shape[-1]
-        v = values.reshape(1, L)
-        seg = jnp.cumsum(starts.reshape(1, L).astype(jnp.int32), axis=1)
-        idx = jax.lax.broadcasted_iota(jnp.int32, v.shape, 1)
-        v, idx = _scan_rounds(v, seg, idx, _n_rounds(L))
-        return v.reshape(L), idx.reshape(L)
-
-else:  # pragma: no cover - exercised only in jax-less containers
-    seg_running_max = _kernel_unavailable
-    seg_running_argmax = _kernel_unavailable
-    seg_running_max_jnp = _kernel_unavailable
-    seg_running_argmax_jnp = _kernel_unavailable
+def seg_running_argmax_jnp(values, starts):
+    """values (L,), starts (L,) bool -> ((L,) running max, (L,) int32
+    index of the LATEST position attaining it within the segment)."""
+    L = values.shape[-1]
+    v = values.reshape(1, L)
+    seg = jnp.cumsum(starts.reshape(1, L).astype(jnp.int32), axis=1)
+    idx = jax.lax.broadcasted_iota(jnp.int32, v.shape, 1)
+    v, idx = _scan_rounds(v, seg, idx, _n_rounds(L))
+    return v.reshape(L), idx.reshape(L)
 
 
 def seg_running_max_ref(values: np.ndarray, starts: np.ndarray) -> np.ndarray:

@@ -5,16 +5,8 @@ from __future__ import annotations
 import jax
 
 
-def _auto_axis_kwargs(n_axes: int) -> dict:
-    """``axis_types=(AxisType.Auto, ...)`` on JAX versions that have it.
-
-    ``jax.sharding.AxisType`` only exists from jax 0.5; older releases treat
-    every axis as auto already, so omitting the kwarg is equivalent.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+def _auto(n_axes: int) -> dict:
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -22,13 +14,13 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod:  (pod=2, data=16, model=16) = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_auto_axis_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, **_auto(len(axes)))
 
 
 def make_test_mesh(n_devices: int | None = None):
     """Small mesh for CPU tests: (data=2, model=n/2)."""
     n = n_devices or len(jax.devices())
-    kw = _auto_axis_kwargs(2)
+    kw = _auto(2)
     if n == 1:
         return jax.make_mesh((1, 1), ("data", "model"), **kw)
     return jax.make_mesh((2, n // 2), ("data", "model"), **kw)
@@ -43,15 +35,22 @@ def make_sweep_mesh(n_devices: int | None = None, state_rows: int = 1):
     row-sharded StateLayout: the (n+1, m) expiry/anchor rows of every
     lane are distributed over ``state_rows`` devices — catalogs one chip
     can't hold.  ``state_rows`` must divide the device count.
-    On a single-device host this is a trivial mesh and sweeps stay local."""
-    n = n_devices or len(jax.devices())
+    ``n_devices`` takes the first that many of ``jax.devices()`` (a
+    sub-mesh of the host); on a single-device host this is a trivial
+    mesh and sweeps stay local."""
+    devs = jax.devices()
+    n = n_devices or len(devs)
+    if n > len(devs):
+        raise ValueError(f"n_devices={n} exceeds the {len(devs)} devices")
+    devs = devs[:n]
     if state_rows <= 1:
-        return jax.make_mesh((n,), ("scenario",), **_auto_axis_kwargs(1))
+        return jax.make_mesh((n,), ("scenario",), devices=devs, **_auto(1))
     if n % state_rows:
         raise ValueError(
             f"state_rows={state_rows} must divide the device count {n}")
     return jax.make_mesh((n // state_rows, state_rows),
-                         ("scenario", "state_row"), **_auto_axis_kwargs(2))
+                         ("scenario", "state_row"), devices=devs,
+                         **_auto(2))
 
 
 def dp_axes(mesh) -> tuple[str, ...]:

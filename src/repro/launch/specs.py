@@ -146,8 +146,5 @@ def lower_cell(cell: Cell, mesh):
     if cell.out_shardings is not None:
         kw["out_shardings"] = cell.out_shardings
     jitted = jax.jit(cell.step_fn, donate_argnums=cell.donate, **kw)
-    # jax.set_mesh only exists from jax 0.6; older releases use the Mesh
-    # object itself as the ambient-mesh context manager
-    set_mesh = getattr(jax, "set_mesh", None)
-    with set_mesh(mesh) if set_mesh is not None else mesh:
+    with jax.set_mesh(mesh):
         return jitted.lower(*cell.args)

@@ -15,8 +15,8 @@ loss is a plain mean of BCE-with-logits over the batch, and the
 economically irrelevant weight-0 rows (and padding) are simply never
 drawn.
 
-Training math runs under ``enable_x64`` with f64 params (AdamW keeps f32
-moments); the returned :class:`LearnedParams` is numpy f64 throughout
+Training math runs under ``jax.enable_x64(True)`` with f64 params
+(AdamW keeps f32 moments); the returned :class:`LearnedParams` is numpy f64 throughout
 and round-trips through :mod:`repro.checkpoint` via
 :func:`save_learned_params` / :func:`load_learned_params`.
 """
@@ -111,7 +111,7 @@ def train_policy(trace, env: CacheEnvironment | None = None,
     so on degenerate inputs (no windows, or no example with a nonzero
     cost delta) it returns the warm start untouched.
     """
-    from jax.experimental import enable_x64
+    import jax
 
     from ..optim.adamw import AdamWConfig
 
@@ -144,10 +144,8 @@ def train_policy(trace, env: CacheEnvironment | None = None,
         total_steps=cfg.steps, min_lr_frac=cfg.min_lr_frac,
         warmup_floor=cfg.warmup_floor)
     fn = _trainer(n_pad, X.shape[1], cfg.steps, cfg.batch, acfg)
-    with enable_x64():
+    with jax.enable_x64(True):
         w_fin, _losses, _final = fn(lp.w, lp.mu, lp.sd, Xp, yp, wp, idx)
-    import jax
-
     lp.w = jax.tree.map(lambda a: np.asarray(a, np.float64), w_fin)
     return lp
 

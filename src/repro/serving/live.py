@@ -44,6 +44,8 @@ import functools
 import warnings
 from collections import deque
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..core.cost import CostBreakdown
@@ -51,15 +53,6 @@ from ..core.engine import CacheState
 from ..core.policy import RunResult
 from ..core.session import CacheSession
 from ..core import engine_jax as ej
-
-try:  # pragma: no cover - exercised indirectly
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import enable_x64
-
-    HAS_JAX = True
-except Exception:  # pragma: no cover
-    HAS_JAX = False
 
 # buffer donation is an optimization; backends that cannot donate (some
 # CPU configurations) fall back to copying and warn — harmless here
@@ -84,7 +77,7 @@ class _Chunk:
 
 
 @functools.lru_cache(maxsize=None)
-def _compiled_live_step(statics, charge, const_dt, use_pallas):
+def _compiled_live_step(statics, charge, const_dt):
     """jit'd scan step with a DONATED carry.
 
     Returns ``((E, anchor, acc), probe)``: the carry buffers are donated
@@ -92,8 +85,7 @@ def _compiled_live_step(statics, charge, const_dt, use_pallas):
     on the small non-donated ``probe`` scalar instead.
     """
     base = functools.partial(
-        ej._replay_impl, kind=statics, charge=charge, const_dt=const_dt,
-        use_pallas=use_pallas)
+        ej._replay_impl, kind=statics, charge=charge, const_dt=const_dt)
 
     def step(spec, carry, xs):
         E, anchor, acc = base(spec, carry, xs)
@@ -185,8 +177,7 @@ class LiveServingEngine:
     def __init__(self, policy, n, m, *, env=None, batch_size=None,
                  chunk_size=32768, ring=4, headroom=2.0, cgm="auto",
                  layout=None):
-        if not HAS_JAX:  # pragma: no cover
-            raise ImportError("LiveServingEngine requires jax")
+        ej.enable_compile_cache()
         self.session = CacheSession(
             policy, n, m, env=env, batch_size=batch_size, layout=layout)
         #: device state geometry (dense / bucketed / row_sharded)
@@ -199,9 +190,6 @@ class LiveServingEngine:
         self.chunk_size = max(1, int(chunk_size))
         self.ring = max(1, int(ring))
         self.headroom = float(headroom)
-        from ..kernels.autowire import default_segment_hooks
-
-        self._use_pallas = default_segment_hooks()[0] is not None
         self._part = self.session.partition
         self._carry = None          # (E, anchor, acc) device arrays
         self._spec_j = None         # device copy of the scenario spec
@@ -427,7 +415,7 @@ class LiveServingEngine:
         self._base_req = (c.n_requests, c.n_item_requests)
         self._host_nreq = 0
         self._host_nitem = 0
-        with enable_x64():
+        with jax.enable_x64(True):
             E0, a0 = self.layout.place_state(E0, a0)
             self._carry = (
                 jnp.asarray(E0, jnp.float64),
@@ -469,8 +457,7 @@ class LiveServingEngine:
         if self._cgm_carry is not None:
             return
         from ..core.cgm_jax import (
-            cgm_loop_statics, cgm_spec, init_cgm_carry)
-        from ..kernels.autowire import default_cgm_hooks
+            cgm_loop_statics, cgm_spec, init_cgm_carry, kernels_on_backend)
 
         eng = self.session.engine
         pol = self.policy
@@ -495,12 +482,12 @@ class LiveServingEngine:
         self._cgm_flags = (
             uses_sizes, bool(cfg.enable_split),
             bool(cfg.enable_approx_merge), bool(eng.seed_new_cliques),
-            default_cgm_hooks()[0] is not None)
+            kernels_on_backend())
         cspec = cgm_spec(cfg, cfg.params, self.n)
         self._cgm_statics = cgm_loop_statics(
             cspec, carry0, enable_split=cfg.enable_split,
             enable_acm=cfg.enable_approx_merge)
-        with enable_x64():
+        with jax.enable_x64(True):
             self._cgm_carry = {
                 k: jnp.asarray(v) for k, v in carry0.items()}
             self._spec_j = {
@@ -536,7 +523,7 @@ class LiveServingEngine:
             wbuf = np.full((wcap, dbuf), -1, np.int32)
             wbuf[:ow, :od] = c["wbuf"]
             c["wbuf"] = wbuf
-        with enable_x64():
+        with jax.enable_x64(True):
             self._cgm_carry = {k: jnp.asarray(v) for k, v in c.items()}
 
     def _dispatch_cgm(self, items, servers, times) -> None:
@@ -605,7 +592,7 @@ class LiveServingEngine:
             self._jeng._statics, eng.caching_charge, *self._cgm_flags,
             *self._cgm_statics)
         before = cgm_jax.SCAN_TRACES
-        with enable_x64():
+        with jax.enable_x64(True):
             xs_j = {k: jnp.asarray(v) for k, v in sched.xs.items()}
             self._cgm_carry, ofs = fn(
                 self._spec_j, self._cspec_j, self._cgm_carry, xs_j,
@@ -665,10 +652,9 @@ class LiveServingEngine:
             self._fix_dims(dims)
         sched = ej.pad_schedule(sched, self._dims)
         fn = _compiled_live_step(
-            self._jeng._statics, eng.caching_charge, sched.const_dt,
-            self._use_pallas)
+            self._jeng._statics, eng.caching_charge, sched.const_dt)
         before = ej.SCAN_TRACES
-        with enable_x64():
+        with jax.enable_x64(True):
             xs_j = {k: jnp.asarray(v) for k, v in sched.xs.items()}
             self._carry, probe = fn(self._spec_j, self._carry, xs_j)
         self.compiles += ej.SCAN_TRACES - before

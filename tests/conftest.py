@@ -1,14 +1,3 @@
-import pathlib
-import sys
-
-try:                # real hypothesis, if installed (requirements-dev.txt)
-    import hypothesis  # noqa: F401
-except ImportError:  # offline container: deterministic seeded-sweep shim
-    sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent))
-    import _hypothesis_compat
-
-    sys.modules["hypothesis"] = _hypothesis_compat
-
 import numpy as np
 import pytest
 

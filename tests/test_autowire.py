@@ -1,92 +1,50 @@
-"""Kernel autowiring decision table + segment-reduction kernel parity."""
+"""Kernel choice rule + segment-reduction parity.
+
+The Mosaic kernels engage on a TPU backend only, read at call time; the
+host CGM and the numpy engine (the plain references) never touch them.
+The per-server-dt segment scans run as jnp on every backend.
+"""
 import numpy as np
 import pytest
 
-from repro.kernels.autowire import (
-    default_cgm_hooks,
-    default_segment_hooks,
-    kernels_enabled,
-)
+import jax
+import jax.numpy as jnp
 
-jax = pytest.importorskip("jax")
-import jax.numpy as jnp  # noqa: E402
-
-from repro.kernels.segment_reduce import (  # noqa: E402
-    seg_running_argmax,
+from repro.core.cgm_jax import kernels_on_backend
+from repro.kernels.segment_reduce import (
     seg_running_argmax_jnp,
     seg_running_argmax_ref,
-    seg_running_max,
     seg_running_max_jnp,
     seg_running_max_ref,
 )
 
 
-# ---------------------------------------------------------------------------
-# decision table: REPRO_KERNELS env override x backend
-# ---------------------------------------------------------------------------
-@pytest.mark.parametrize("env,backend,expect", [
-    # auto: engage on any live non-CPU accelerator, GPU included
-    ("", "tpu", True),
-    ("", "gpu", True),
-    ("", "cuda", True),
-    ("", "cpu", False),
-    ("", None, False),              # jax missing/broken
-    ("auto", "tpu", True),
-    ("auto", "cpu", False),
-    # force: engage everywhere (interpret mode on CPU)
-    ("force", "cpu", True),
-    ("on", None, True),
-    ("1", "cpu", True),
-    ("always", "gpu", True),
-    # off: never engage
-    ("off", "tpu", False),
-    ("0", "gpu", False),
-    ("never", "tpu", False),
-    # case/whitespace robustness
-    (" FORCE ", "cpu", True),
-    ("OFF", "tpu", False),
-])
-def test_kernels_enabled_decision_table(env, backend, expect):
-    assert kernels_enabled(backend, env=env) is expect
+@pytest.mark.parametrize("backend,expect", [
+    ("tpu", True), ("cpu", False), ("gpu", False)])
+def test_kernel_choice_reads_backend_at_call_time(monkeypatch, backend,
+                                                  expect):
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert kernels_on_backend() is expect
 
 
-def test_env_variable_is_read(monkeypatch):
-    monkeypatch.setenv("REPRO_KERNELS", "force")
-    assert kernels_enabled("cpu") is True
-    monkeypatch.setenv("REPRO_KERNELS", "off")
-    assert kernels_enabled("tpu") is False
-    monkeypatch.delenv("REPRO_KERNELS")
-    assert kernels_enabled("cpu") is False
+def test_host_reference_stays_on_host_under_tpu_backend(monkeypatch):
+    """The numpy replay (engine gather + host CGM) must not reach for a
+    kernel even when the backend reports a TPU: each ``*_auto`` would
+    then try to build a Mosaic kernel, which fails on this host."""
+    from repro.core import CostParams, get_policy, run_policy
+    from repro.traces import paper_trace
 
-
-def test_default_hooks_follow_decision(monkeypatch):
-    """On this CPU container, auto -> numpy/jnp oracles; force -> Pallas."""
-    monkeypatch.setenv("REPRO_KERNELS", "auto")
-    assert default_cgm_hooks() == (None, None)
-    assert default_segment_hooks() == (None, None)
-    monkeypatch.setenv("REPRO_KERNELS", "force")
-    mm, pe = default_cgm_hooks()
-    sm, sa = default_segment_hooks()
-    assert callable(mm) and callable(pe)
-    assert callable(sm) and callable(sa)
-
-
-def test_forced_hooks_are_usable(monkeypatch):
-    """Forced (interpret-mode) hooks must still compute correctly."""
-    monkeypatch.setenv("REPRO_KERNELS", "force")
-    sm, sa = default_segment_hooks()
-    v = np.array([3.0, 1.0, 2.0, 5.0, 4.0], np.float32)
-    s = np.array([1, 0, 0, 1, 0], bool)
-    got = np.asarray(sm(jnp.asarray(v), jnp.asarray(s)))
-    np.testing.assert_allclose(got, seg_running_max_ref(v, s))
-    mv, mi = sa(jnp.asarray(v), jnp.asarray(s))
-    rv, ri = seg_running_argmax_ref(v, s)
-    np.testing.assert_allclose(np.asarray(mv), rv)
-    assert np.array_equal(np.asarray(mi), ri)
+    tr = paper_trace("netflix", n_requests=3000, seed=5)
+    want = run_policy(get_policy("akpc", params=CostParams(), t_cg=0.3,
+                                 top_frac=1.0), tr).costs.total
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    got = run_policy(get_policy("akpc", params=CostParams(), t_cg=0.3,
+                                top_frac=1.0), tr).costs.total
+    assert got == want
 
 
 # ---------------------------------------------------------------------------
-# segment kernels: Pallas interpret mode == jnp fallback == numpy oracle
+# segment scans: jnp doubling scan == numpy oracle
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("L,p_start,seed", [
     (1, 1.0, 0), (2, 0.5, 1), (17, 0.3, 2), (64, 0.1, 3),
@@ -99,10 +57,7 @@ def test_segment_running_max_parity(L, p_start, seed):
     s[0] = True
     want = seg_running_max_ref(v, s)
     got_jnp = np.asarray(seg_running_max_jnp(jnp.asarray(v), jnp.asarray(s)))
-    got_pl = np.asarray(
-        seg_running_max(jnp.asarray(v), jnp.asarray(s), interpret=True))
     np.testing.assert_allclose(got_jnp, want.astype(got_jnp.dtype), rtol=0)
-    np.testing.assert_allclose(got_pl, want.astype(got_pl.dtype), rtol=0)
 
 
 @pytest.mark.parametrize("L,p_start,seed", [
@@ -116,12 +71,8 @@ def test_segment_running_argmax_parity(L, p_start, seed):
     s[0] = True
     want_v, want_i = seg_running_argmax_ref(v, s)
     gv, gi = seg_running_argmax_jnp(jnp.asarray(v), jnp.asarray(s))
-    pv, pi = seg_running_argmax(jnp.asarray(v), jnp.asarray(s),
-                                interpret=True)
     np.testing.assert_allclose(np.asarray(gv), want_v)
     assert np.array_equal(np.asarray(gi), want_i)
-    np.testing.assert_allclose(np.asarray(pv), want_v)
-    assert np.array_equal(np.asarray(pi), want_i)
 
 
 def test_segment_argmax_tie_breaks_latest():

@@ -234,13 +234,16 @@ def test_wants_device_cgm_gating(trace, monkeypatch):
 
 
 def test_merge_density_kernel_matches_jnp_interpret():
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
-    from repro.kernels.merge_step import merge_density
+    from repro.core.cliques import _densities
+    from repro.kernels.merge_step import (
+        merge_density, merge_density_jnp, merge_edge_floor,
+    )
 
     rng = np.random.default_rng(0)
-    with enable_x64():
+    with jax.enable_x64(True):
         for S, omega, gamma in [(16, 4, 0.5), (120, 6, 0.8), (257, 3, 0.34)]:
             B = rng.integers(0, 4, (S, S)).astype(np.float32)
             X = B + B.T
@@ -248,16 +251,32 @@ def test_merge_density_kernel_matches_jnp_interpret():
             sizes = rng.integers(0, omega, S).astype(np.int32)
             Xj, sj = jnp.asarray(X), jnp.asarray(sizes)
             om = jnp.asarray(omega, jnp.int32)
-            gm = jnp.asarray(gamma, jnp.float32)
-            D_k = np.asarray(merge_density(Xj, sj, om, gm, interpret=True))
-            within = jnp.diag(Xj) / 2.0
-            e_u = (within[:, None] + within[None, :]) + Xj
-            okp = ((sj[:, None] + sj[None, :]) == om) & ~jnp.eye(S, dtype=bool)
-            om_f = jnp.asarray(omega, jnp.float64)
-            e_max = (om_f * (om_f - 1.0) / 2.0).astype(jnp.float32)
-            dens = jnp.where(okp, e_u / e_max, -1.0)
-            D_r = np.asarray(jnp.where(dens >= gm, dens, -1.0))
+            ef = jnp.asarray(merge_edge_floor(omega, gamma))
+            D_k = np.asarray(merge_density(Xj, sj, om, ef, interpret=True))
+            D_j = np.asarray(merge_density_jnp(Xj, sj, om, ef))
+            # the host's thresholded densities, carried as edge counts
+            within = np.diag(X) / 2.0
+            e_u = (within[:, None] + within[None, :]) + X
+            dens = _densities(X, sizes, omega)
+            D_r = np.where(dens >= gamma, e_u, -1.0).astype(np.float32)
             assert np.array_equal(D_k, D_r), (S, omega, gamma)
+            assert np.array_equal(D_j, D_r), (S, omega, gamma)
+
+
+@pytest.mark.parametrize("omega", [2, 3, 4, 5, 6, 8, 13, 40])
+def test_merge_edge_floor_is_the_host_density_bar(omega):
+    """e >= merge_edge_floor(omega, gamma) iff the host's f32 density
+    e / e_max passes gamma, for every count up to e_max."""
+    from repro.kernels.merge_step import merge_edge_floor
+
+    em = np.float32(omega * (omega - 1) / 2.0)
+    e = np.arange(int(em) + 1, dtype=np.float32)
+    gammas = [0.0, 1e-9, 0.1, 1 / 3, 0.5, 0.6, 0.7, 2 / 3, 0.8, 0.9,
+              (omega - 2) / omega, (omega - 1) / omega, 1.0, 1.2]
+    gammas += [float(k) / float(em) for k in range(int(em) + 1)]
+    for g in gammas:
+        host = (e / em) >= np.float32(g)
+        assert np.array_equal(e >= merge_edge_floor(omega, g), host), g
 
 
 def test_device_cgm_with_kernels_interpret(trace):

@@ -79,6 +79,33 @@ def test_partition_translation_preserves_presence():
     assert len(out.misses) == 1
 
 
+def test_window_seed_servers_matches_dense_argmax():
+    """The pair tally picks what a row argmax over the dense (k, m)
+    member-access counts picks: duplicates count per occurrence, ties
+    and unaccessed cliques take the lowest server."""
+    from repro.core.engine import window_seed_servers
+
+    rng = np.random.default_rng(0)
+    n, m = 40, 7
+    for trial in range(20):
+        sizes = rng.integers(1, 4, n)
+        cuts = np.cumsum(sizes)
+        perm = rng.permutation(n)
+        groups = [tuple(int(x) for x in g)
+                  for g in np.split(perm, cuts[cuts < n]) if len(g)]
+        part = CliquePartition.from_cliques(n, groups)
+        R = int(rng.integers(0, 30))
+        items = rng.integers(-1, n // 2, (R, 3)).astype(np.int32)
+        servers = rng.integers(0, 3, R).astype(np.int32)
+        dense = np.zeros((part.k, m), np.int64)
+        for row, j in zip(items, servers):
+            for it in row[row >= 0]:
+                dense[part.clique_of[it], j] += 1
+        want = np.argmax(dense, axis=1)
+        got = window_seed_servers(m, part, items, servers)
+        assert np.array_equal(got, want), trial
+
+
 def test_seeding_new_cliques():
     eng = _engine()
     w_items = np.array([[0, 1, -1]], np.int32)

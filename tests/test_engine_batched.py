@@ -11,9 +11,8 @@ import math
 import numpy as np
 import pytest
 
-from repro.core import CliquePartition, CostParams, ReplayEngine
+from repro.core import CostParams, ReplayEngine
 from repro.core.baselines import greedy_pair_matching
-from repro.kernels.packed_lookup import clique_lookup
 from repro.traces import SynthConfig, Trace, batch_tensors, synth_trace
 
 INT_FIELDS = ("n_requests", "n_item_requests", "n_misses", "n_hits",
@@ -133,15 +132,6 @@ def test_batch_tensors_padding_roundtrip():
     assert eng_t.costs.n_requests == eng_r.costs.n_requests + pad
     eng_t.costs.n_requests -= pad
     assert_same_costs(eng_r.costs, eng_t.costs)
-
-
-def test_clique_lookup_pallas_interpret_matches_numpy():
-    part = CliquePartition.from_cliques(12, [(0, 1, 2), (5, 6)])
-    items = np.array([[0, 5, 11, -1], [2, 6, -1, -1]], dtype=np.int32)
-    want = clique_lookup(part.clique_of, items, use_pallas=False)
-    got = clique_lookup(part.clique_of, items, use_pallas=True, interpret=True)
-    assert (want == np.asarray(got)).all()
-    assert (want[items < 0] == -1).all()
 
 
 @pytest.mark.slow

@@ -7,7 +7,7 @@ from hypothesis import given, settings, strategies as st
 from repro.kernels import ref
 from repro.kernels.clique_density import clique_pair_edges
 from repro.kernels.crm_update import crm_update
-from repro.kernels.packed_lookup import packed_lookup, unpacked_lookup
+from repro.kernels.packed_lookup import packed_lookup
 
 
 @pytest.mark.parametrize("B,n", [(7, 5), (64, 60), (200, 130), (300, 257)])
@@ -49,15 +49,6 @@ def test_packed_lookup_sweep(R, C, omega, d, dtype):
     ids = rng.integers(0, C, R).astype(np.int32)
     got = packed_lookup(jnp.asarray(table), jnp.asarray(ids), interpret=True)
     want = ref.packed_lookup_ref(jnp.asarray(table), jnp.asarray(ids))
-    assert np.array_equal(np.asarray(got), np.asarray(want))
-
-
-def test_unpacked_lookup():
-    rng = np.random.default_rng(3)
-    items = rng.normal(size=(40, 8)).astype(np.float32)
-    ids = rng.integers(0, 40, (6, 5)).astype(np.int32)
-    got = unpacked_lookup(jnp.asarray(items), jnp.asarray(ids), interpret=True)
-    want = ref.unpacked_lookup_ref(jnp.asarray(items), jnp.asarray(ids))
     assert np.array_equal(np.asarray(got), np.asarray(want))
 
 

@@ -94,7 +94,7 @@ def trained(trace):
 # featurizer: numpy / jnp twins, schema freeze
 # ---------------------------------------------------------------------------
 def test_features_np_jnp_parity():
-    from jax.experimental import enable_x64
+    import jax
 
     rng = np.random.default_rng(0)
     n, dt, t_cg = 40, 4.0, 12.0
@@ -106,7 +106,7 @@ def test_features_np_jnp_parity():
     sizes = np.exp(rng.normal(0, 0.5, n))
     csz = rng.integers(1, 5, n).astype(np.float64)
     x_np = features_np(counts, co_deg, stats, sizes, csz, 30.0, dt, t_cg)
-    with enable_x64():
+    with jax.enable_x64(True):
         x_j = np.asarray(features_jnp(
             counts, co_deg, stats, sizes, csz, 30.0, dt, t_cg))
     assert x_np.shape == (n, len(FEATURE_NAMES))
@@ -114,13 +114,13 @@ def test_features_np_jnp_parity():
 
 
 def test_forward_np_jnp_parity():
-    from jax.experimental import enable_x64
+    import jax
 
     rng = np.random.default_rng(1)
     lp = init_params(seed=7)
     X = rng.normal(0, 1, (50, lp.n_features))
     s_np = forward_np(lp, X)
-    with enable_x64():
+    with jax.enable_x64(True):
         s_j = np.asarray(forward_jnp(lp.w, lp.mu, lp.sd, X))
     np.testing.assert_allclose(s_j, s_np, rtol=1e-12, atol=1e-12)
 

@@ -79,13 +79,17 @@ def _assert_costs(ref, got):
 
 
 def _pad_and_check(sched, spec, statics, boost):
-    """Padding up by ``boost`` must not change E/anchor/acc at all."""
+    """Padding up by ``boost`` must not change the state at all, nor the
+    integer accumulator slots; the float cost slots (transfer, caching,
+    rent) are sums whose reduction order XLA may pick per shape, so they
+    are held to the repo's 1e-9 relative bar."""
     E, anchor, acc, _ = _replay(sched, spec, statics)
     dims = {k: v + boost for k, v in ej.schedule_dims(sched).items()}
     padded = ej.pad_schedule(sched, dims)
     assert ej.schedule_dims(padded) == dims
     Ep, ap, accp, _ = _replay(padded, spec, statics)
-    np.testing.assert_array_equal(acc, accp)
+    np.testing.assert_array_equal(acc[3:], accp[3:])
+    np.testing.assert_allclose(accp[:3], acc[:3], rtol=1e-9, atol=0)
     np.testing.assert_array_equal(E, Ep)
     np.testing.assert_array_equal(anchor, ap)
 

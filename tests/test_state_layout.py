@@ -283,7 +283,7 @@ def test_make_sweep_mesh_state_row_axis():
 
 @needs_4_devices
 def test_row_sharded_state_spans_devices():
-    from jax.experimental import enable_x64
+    import jax
 
     from repro.launch.mesh import make_sweep_mesh
 
@@ -291,7 +291,7 @@ def test_row_sharded_state_spans_devices():
     lay = StateLayout(kind="row_sharded", mesh=mesh)
     assert lay.row_shards == 4
     E0, a0 = ej.fresh_state_arrays(63, 10, lay)
-    with enable_x64():
+    with jax.enable_x64(True):
         Ed, ad = lay.place_state(E0, a0)
     assert len(Ed.sharding.device_set) == 4
     assert len(ad.sharding.device_set) == 4
