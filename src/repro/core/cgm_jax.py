@@ -973,43 +973,49 @@ def _cgm_boundary(carry, now, cspec, dt, item_sizes, *, n, m, h, wcap,
     carry slots.  All boundary tensors are (h, h) / (scap, scap) —
     nothing n^2 is ever materialised.
     """
-    hot_idx, valid_h, lut, raw, norm, binary = _window_crm_device(
-        carry, cspec, n=n, h=h, wcap=wcap, use_kernels=use_kernels)
+    with jax.named_scope("crm"):
+        hot_idx, valid_h, lut, raw, norm, binary = _window_crm_device(
+            carry, cspec, n=n, h=h, wcap=wcap, use_kernels=use_kernels)
     W = norm.astype(jnp.float64)
 
     # -- Alg. 4 edge diff vs the previous window, per compact space:
     # removed edges live in the PREV hot space, added edges in the
     # CURRENT one; both index maps ascend in item id, so row-major
     # nonzero order IS the host's lexicographic global edge order
-    p_idx = carry["p_idx"]                           # (h,) prev hot -> item
-    pbin = carry["pbin"]
-    lut_prev = jnp.full(n + 1, -1, jnp.int32).at[p_idx].set(
-        jnp.arange(h, dtype=jnp.int32)).at[n].set(-1)
-    ci = lut_prev[hot_idx]                           # cur slot -> prev slot
-    pc = lut[p_idx]                                  # prev slot -> cur slot
-    pcv = pc >= 0
-    pcc = jnp.maximum(pc, 0)
-    cur_in_prev = binary[pcc][:, pcc] & pcv[:, None] & pcv[None, :]
-    civ = ci >= 0
-    cic = jnp.maximum(ci, 0)
-    prev_in_cur = pbin[cic][:, cic] & civ[:, None] & civ[None, :]
-    triu_h = jnp.triu(jnp.ones((h, h), bool), k=1)
-    remM = pbin & ~cur_in_prev & triu_h
-    addM = binary & ~prev_in_cur & triu_h
-    of = carry["of"]
-    gsize = carry["cnt"][:n].astype(jnp.int32)
-    of, gsize = _adjust_partition(
-        of, gsize, binary, W, hot_idx, valid_h, lut,
-        addM, remM, p_idx, cspec, n=n, h=h, gcap=gcap)
+    with jax.named_scope("adjust"):
+        p_idx = carry["p_idx"]                       # (h,) prev hot -> item
+        pbin = carry["pbin"]
+        lut_prev = jnp.full(n + 1, -1, jnp.int32).at[p_idx].set(
+            jnp.arange(h, dtype=jnp.int32)).at[n].set(-1)
+        ci = lut_prev[hot_idx]                       # cur slot -> prev slot
+        pc = lut[p_idx]                              # prev slot -> cur slot
+        pcv = pc >= 0
+        pcc = jnp.maximum(pc, 0)
+        cur_in_prev = binary[pcc][:, pcc] & pcv[:, None] & pcv[None, :]
+        civ = ci >= 0
+        cic = jnp.maximum(ci, 0)
+        prev_in_cur = pbin[cic][:, cic] & civ[:, None] & civ[None, :]
+        triu_h = jnp.triu(jnp.ones((h, h), bool), k=1)
+        remM = pbin & ~cur_in_prev & triu_h
+        addM = binary & ~prev_in_cur & triu_h
+        of = carry["of"]
+        gsize = carry["cnt"][:n].astype(jnp.int32)
+        of, gsize = _adjust_partition(
+            of, gsize, binary, W, hot_idx, valid_h, lut,
+            addM, remM, p_idx, cspec, n=n, h=h, gcap=gcap)
     if enable_split:
-        of = _split_oversized(of, gsize, W, lut, cspec, n=n, h=h, gcap=gcap)
+        with jax.named_scope("split"):
+            of = _split_oversized(
+                of, gsize, W, lut, cspec, n=n, h=h, gcap=gcap)
     if enable_acm:
-        of = _approx_merge(
-            of, binary, hot_idx, valid_h, cspec, n=n, h=h,
-            use_kernels=use_kernels, full_merge=full_merge)
+        with jax.named_scope("merge"):
+            of = _approx_merge(
+                of, binary, hot_idx, valid_h, cspec, n=n, h=h,
+                use_kernels=use_kernels, full_merge=full_merge)
 
-    E_new, a_new, cnt_new = _install_partition_device(
-        carry, of, now, dt, n=n, seed_new=seed_new)
+    with jax.named_scope("install"):
+        E_new, a_new, cnt_new = _install_partition_device(
+            carry, of, now, dt, n=n, seed_new=seed_new)
     out = dict(
         carry, E=E_new, anchor=a_new, of=of, cnt=cnt_new,
         wlen=jnp.zeros((), jnp.int32),
@@ -1182,20 +1188,23 @@ def _cgm_replay_impl(spec, cspec, init, xs, item_sizes, *, kind, charge,
         # starts a new T_CG period evaluates the window accumulated by
         # the preceding steps (``x["cg"]`` comes from the shared xs, so
         # under vmap the predicate stays unbatched and cond stays cond)
-        carry = jax.lax.cond(
-            x["cg"],
-            lambda c: _cgm_boundary(
-                c, x["now"], cspec, dt, item_sizes, n=n, m=m, h=h,
-                wcap=wcap, uses_sizes=uses_sizes,
-                enable_split=enable_split, enable_acm=enable_acm,
-                seed_new=seed_new, use_kernels=use_kernels, gcap=gcap,
-                full_merge=full_merge),
-            lambda c: c,
-            carry)
-        carry = _accumulate_window(carry, x, n=n, m=m)
-        carry = _event_step(
-            carry, x, spec, kind=kind, charge=charge,
-            uses_sizes=uses_sizes, item_sizes=item_sizes, n=n, m=m)
+        with jax.named_scope("cgm_boundary"):
+            carry = jax.lax.cond(
+                x["cg"],
+                lambda c: _cgm_boundary(
+                    c, x["now"], cspec, dt, item_sizes, n=n, m=m, h=h,
+                    wcap=wcap, uses_sizes=uses_sizes,
+                    enable_split=enable_split, enable_acm=enable_acm,
+                    seed_new=seed_new, use_kernels=use_kernels, gcap=gcap,
+                    full_merge=full_merge),
+                lambda c: c,
+                carry)
+        with jax.named_scope("window_accumulate"):
+            carry = _accumulate_window(carry, x, n=n, m=m)
+        with jax.named_scope("event_step"):
+            carry = _event_step(
+                carry, x, spec, kind=kind, charge=charge,
+                uses_sizes=uses_sizes, item_sizes=item_sizes, n=n, m=m)
         return carry, carry["of"]
 
     return jax.lax.scan(step, init, xs)
@@ -1406,7 +1415,7 @@ def policy_hot_dims(policy) -> list:
 
 
 def replay_cgm(jeng, policy, trace, *, t_cg, batch_size=None, next_cg0=None,
-               win_prefix=None, progress=None):
+               win_prefix=None):
     """Device-resident AKPC replay: one host->device transfer, zero host
     clique-generation calls.  Drop-in for ``JaxReplayEngine.replay`` when
     ``wants_device_cgm`` approves the (policy, model, trace) triple."""
@@ -1435,8 +1444,6 @@ def replay_cgm(jeng, policy, trace, *, t_cg, batch_size=None, next_cg0=None,
         enable_split=cfg.enable_split,
         enable_acm=cfg.enable_approx_merge,
         seed_new=eng.seed_new_cliques)
-    if progress is not None:
-        progress(trace.n_requests)
     nbd = int(schedule.boundary_steps.size)
     part = (eng.state.partition if nbd == 0
             else partition_from_of(trace.n, final["of"]))

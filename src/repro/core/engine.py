@@ -833,7 +833,6 @@ class ReplayEngine:
         clique_generator: Callable[[np.ndarray, np.ndarray, float], CliquePartition | None]
         | None = None,
         t_cg: float | None = None,
-        progress: Callable[[int], None] | None = None,
         batch_size: int | None = None,
     ) -> CostBreakdown:
         """Replay a full trace in T_CG-boundary-aligned batches.
@@ -865,7 +864,6 @@ class ReplayEngine:
         next_cg = float(times[0]) + t_cg if t_cg is not None else np.inf
         win_start = 0
         pos = 0
-        next_prog = 0                 # throttle progress to every 64Ki reqs
         while pos < R:
             cut = R
             if use_cg:
@@ -887,7 +885,4 @@ class ReplayEngine:
             stop = min(pos + bs, cut)
             self.handle_batch(items[pos:stop], servers[pos:stop], times[pos:stop])
             pos = stop
-            if progress is not None and pos >= next_prog:
-                progress(pos)
-                next_prog = (pos | 0xFFFF) + 1
         return self.costs

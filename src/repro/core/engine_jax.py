@@ -263,7 +263,6 @@ def build_schedule(
     next_cg0: float | None = None,
     win_prefix: tuple[np.ndarray, np.ndarray] | None = None,
     lookup: Callable | None = None,
-    progress: Callable[[int], None] | None = None,
     layout: StateLayout | str | None = None,
 ) -> ReplaySchedule:
     """Walk the trace exactly as ``ReplayEngine.replay`` does and emit the
@@ -441,7 +440,6 @@ def build_schedule(
     win_start = 0
     boundary_hit = False
     pos = 0
-    next_prog = 0
     while pos < R:
         cut = R
         if use_cg:
@@ -499,9 +497,6 @@ def build_schedule(
             stop = min(pos + bs, cut)
             _emit(pos, stop)
             pos = stop
-        if progress is not None and pos >= next_prog:
-            progress(pos)
-            next_prog = (pos | 0xFFFF) + 1
     if pending_install is not None:         # trailing Event 1, no requests
         _emit(0, 0)
 
@@ -789,17 +784,21 @@ def _replay_impl(spec, init, xs, *, kind, charge, const_dt):
 
     def step(carry, x):
         E, anchor, acc = carry
-        K = E.shape[0] - 1
         # lax.cond, not where: the predicate comes from the UNBATCHED xs
         # (shared across vmap lanes), so non-install steps skip the
         # delta-translation entirely
-        E, anchor = jax.lax.cond(
-            x["inst"],
-            lambda Ea: _install_step(Ea[0], Ea[1], x, dt),
-            lambda Ea: Ea,
-            (E, anchor),
-        )
+        with jax.named_scope("install"):
+            E, anchor = jax.lax.cond(
+                x["inst"],
+                lambda Ea: _install_step(Ea[0], Ea[1], x, dt),
+                lambda Ea: Ea,
+                (E, anchor),
+            )
+        with jax.named_scope("event_step"):
+            return event_step(E, anchor, acc, x), None
 
+    def event_step(E, anchor, acc, x):
+        K = E.shape[0] - 1
         cl, j, t, val = x["ev_c"], x["ev_j"], x["ev_t"], x["val"]
         dt_e = dt[0] if const_dt else dt[j]
         E_before = jnp.where(
@@ -893,7 +892,7 @@ def _replay_impl(spec, init, xs, *, kind, charge, const_dt):
             final_anchor = jnp.where(
                 win, x["j_s"][bidx[pa]], a0_s[pa]).astype(jnp.int32)
             anchor = anchor.at[ac].set(final_anchor)
-        return (E, anchor, acc), None
+        return E, anchor, acc
 
     return jax.lax.scan(step, init, xs)[0]
 
@@ -1099,7 +1098,6 @@ class JaxReplayEngine:
         trace,
         clique_generator=None,
         t_cg: float | None = None,
-        progress: Callable[[int], None] | None = None,
         batch_size: int | None = None,
         *,
         next_cg0: float | None = None,
@@ -1128,13 +1126,13 @@ class JaxReplayEngine:
                     return replay_cgm(
                         self, pol, trace, t_cg=t_cg,
                         batch_size=batch_size, next_cg0=next_cg0,
-                        win_prefix=win_prefix, progress=progress)
+                        win_prefix=win_prefix)
         schedule = build_schedule(
             eng.state.partition, trace, clique_generator, t_cg,
             model=eng.model, env=eng.env, batch_size=batch_size,
             seed_new_cliques=eng.seed_new_cliques,
             next_cg0=next_cg0, win_prefix=win_prefix, lookup=eng._lookup,
-            progress=progress, layout=self.layout,
+            layout=self.layout,
         )
         # shape-stability ratchet: pad every chunk's tensors up to the
         # largest dims this engine has seen, so a streamed session (ragged
@@ -1162,8 +1160,7 @@ class JaxReplayEngine:
         return eng.costs
 
 
-def run_policy_jax(policy, trace, *, batch_size=None, progress=None,
-                   layout=None):
+def run_policy_jax(policy, trace, *, batch_size=None, layout=None):
     """Offline driver on the JAX backend — ``run_policy(backend="jax")``.
 
     Mirrors :func:`repro.core.policy.run_policy` step for step (policy
@@ -1200,7 +1197,7 @@ def run_policy_jax(policy, trace, *, batch_size=None, progress=None,
     bs = batch_size if batch_size is not None else getattr(
         policy, "batch_size", None)
     eng.replay(trace, clique_generator=gen, t_cg=policy.t_cg,
-               progress=progress, batch_size=bs)
+               batch_size=bs)
     return RunResult(
         policy=policy.name,
         costs=eng.costs,

@@ -42,6 +42,7 @@ from typing import Any, Callable, Protocol, runtime_checkable
 
 import numpy as np
 
+from .. import obs
 from .akpc import AKPCConfig
 from .cliques import CliquePartition, generate_cliques
 from .cost import CacheEnvironment, CostBreakdown, CostModel, CostParams
@@ -508,23 +509,25 @@ class AKPCPolicy(BasePolicy):
         del servers, now
         cfg = self.config
         t0 = _time.perf_counter()
-        crm = build_window_crm(
-            items, self.n, cfg.params.theta, cfg.top_frac,
-            crm_matmul=cfg.crm_matmul,
-            top_frac_of=cfg.top_frac_of,
-        )
-        omega = cfg.params.omega if cfg.enable_split else self.n
-        part = generate_cliques(
-            self._partition,
-            self._prev_crm,
-            crm,
-            self.n,
-            omega,
-            cfg.params.gamma,
-            pair_edges=cfg.pair_edges,
-            enable_split=cfg.enable_split,
-            enable_approx_merge=cfg.enable_approx_merge,
-        )
+        with obs.span("cgm.window") as sp:
+            crm = build_window_crm(
+                items, self.n, cfg.params.theta, cfg.top_frac,
+                crm_matmul=cfg.crm_matmul,
+                top_frac_of=cfg.top_frac_of,
+            )
+            sp.set_metadata(hot=crm.n_hot)
+            omega = cfg.params.omega if cfg.enable_split else self.n
+            part = generate_cliques(
+                self._partition,
+                self._prev_crm,
+                crm,
+                self.n,
+                omega,
+                cfg.params.gamma,
+                pair_edges=cfg.pair_edges,
+                enable_split=cfg.enable_split,
+                enable_approx_merge=cfg.enable_approx_merge,
+            )
         self._prev_crm = crm
         self._record(part, _time.perf_counter() - t0)
         return part
@@ -595,7 +598,6 @@ def run_policy(
     trace,
     *,
     batch_size: int | None = None,
-    progress: Callable[[int], None] | None = None,
     backend: str = "numpy",
 ) -> RunResult:
     """Replay a full trace under ``policy`` and return the unified result.
@@ -613,8 +615,7 @@ def run_policy(
     if backend == "jax":
         from .engine_jax import run_policy_jax
 
-        return run_policy_jax(
-            policy, trace, batch_size=batch_size, progress=progress)
+        return run_policy_jax(policy, trace, batch_size=batch_size)
     if backend != "numpy":
         raise ValueError(f"unknown replay backend {backend!r}")
     if isinstance(policy, str):
@@ -640,10 +641,7 @@ def run_policy(
         eng.install_partition(part0, now=0.0)
     gen = policy.on_window if policy.t_cg is not None else None
     bs = batch_size if batch_size is not None else getattr(policy, "batch_size", None)
-    eng.replay(
-        trace, clique_generator=gen, t_cg=policy.t_cg, progress=progress,
-        batch_size=bs,
-    )
+    eng.replay(trace, clique_generator=gen, t_cg=policy.t_cg, batch_size=bs)
     return RunResult(
         policy=policy.name,
         costs=eng.costs,

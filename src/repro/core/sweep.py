@@ -29,10 +29,11 @@ from __future__ import annotations
 
 import dataclasses
 import time as _time
-from typing import Any, Callable, Sequence
+from typing import Any, Sequence
 
 import numpy as np
 
+from .. import obs
 from .cost import CacheEnvironment, get_cost_model
 from .policy import RunResult, get_policy, run_policy
 from .state_layout import StateLayout
@@ -125,6 +126,25 @@ def _merge_shard_results(subs: list) -> RunResult:
 _COHORT_DIMS: dict[tuple, dict] = {}
 
 
+def _pad_cohort(ej, ckey, recs: list) -> None:
+    """Pad the schedules of ``recs`` to their cohort's ratcheted dims."""
+    dims_list = [ej.schedule_dims(r["schedule"]) for r in recs]
+    dims = {k: max(d[k] for d in dims_list) for k in dims_list[0]}
+    cached = _COHORT_DIMS.get(ckey)
+    if cached is not None:
+        dims = {k: max(dims[k], cached[k]) for k in dims}
+    _COHORT_DIMS[ckey] = dims
+    for r, d0 in zip(recs, dims_list):
+        if d0 != dims:   # shared shapes: skip the pad entirely
+            r["schedule"] = ej.pad_schedule(r["schedule"], dims)
+
+
+def _nbytes(*trees) -> int:
+    """Bytes of the arrays in ``trees`` (arrays, or dicts of arrays)."""
+    return sum(int(a.nbytes) for t in trees
+               for a in (t.values() if isinstance(t, dict) else (t,)))
+
+
 def _cgm_key(policy) -> tuple:
     """The clique-generation-relevant knobs of a registry policy."""
     p = policy.params
@@ -162,26 +182,16 @@ class SweepEngine:
             # make_sweep_mesh(..., state_rows=) two-axis form)
             layout = dataclasses.replace(layout, mesh=mesh)
         self.layout = layout
-        #: wall seconds of the most recent :meth:`run` (schedules + device)
-        self.last_wall = 0.0
         #: schedule-dedup stats of the most recent run
         self.last_n_schedules = 0
 
     # ------------------------------------------------------------------
-    def run(
-        self,
-        points: Sequence[SweepPoint],
-        progress: Callable[[str], None] | None = None,
-    ) -> list[RunResult]:
-        t0 = _time.perf_counter()
-        if self.backend == "numpy":
-            out = [self._run_numpy(pt) for pt in points]
-            self.last_wall = _time.perf_counter() - t0
-            self.last_n_schedules = len(points)
-            return out
-        out = self._run_jax(points, progress)
-        self.last_wall = _time.perf_counter() - t0
-        return out
+    def run(self, points: Sequence[SweepPoint]) -> list[RunResult]:
+        with obs.span("sweep.call", points=len(points)) as sp:
+            if self.backend == "numpy":
+                self.last_n_schedules = len(points)
+                return [self._run_numpy(pt) for pt in points]
+            return self._run_jax(points, sp)
 
     def _run_numpy(self, pt: SweepPoint) -> RunResult:
         shards = _shards_of(pt.trace)
@@ -196,12 +206,336 @@ class SweepEngine:
             batch_size=pt.batch_size or self.batch_size)
 
     # ------------------------------------------------------------------
-    def _run_jax(self, points, progress) -> list[RunResult]:
+    def _run_jax(self, points, call_span) -> list[RunResult]:
+        from . import cgm_jax
         from . import engine_jax as ej
         from .cliques import CliquePartition
         from .cost import CostBreakdown
+        from .engine import CacheState
 
-        # -- prepare points + share keys (no schedule builds yet) -----------
+        with obs.span("sweep.prepare", points=len(points)):
+            prepared, dev_groups, groups, sh_groups = self._prepare(points)
+
+        # -- build every distinct schedule on host --------------------------
+        schedules: dict = {}
+        for (skey, statics, charge), idxs in groups.items():
+            g0 = prepared[idxs[0]]
+            if skey in schedules:
+                continue
+            policy = g0["policy"]
+            with obs.span("sweep.schedule") as sp:
+                part0 = (policy.initial_partition(g0["pt"].trace)
+                         if hasattr(policy, "initial_partition") else None)
+                if part0 is None:
+                    part0 = CliquePartition.singletons(g0["pt"].trace.n)
+                gen = policy.on_window if policy.t_cg is not None else None
+                schedule = ej.build_schedule(
+                    part0, g0["pt"].trace, gen, policy.t_cg,
+                    model=g0["model"], env=g0["env"], batch_size=g0["bs"],
+                    seed_new_cliques=g0["seed"], layout=self.layout,
+                )
+                sp.set_metadata(steps=schedule.nb, events=schedule.ne)
+            schedules[skey] = {
+                "schedule": schedule,
+                "n_windows": getattr(policy, "n_windows", 0),
+                "cg_seconds": getattr(policy, "cg_seconds", 0.0),
+                "size_history": list(getattr(policy, "size_history", [])),
+                "clique_sizes": schedule.final_partition.sizes(),
+            }
+
+        # -- trace-shard groups: one schedule PER SHARD, stacked batched ----
+        # lanes = scenarios x shards of one vmapped call (run_schedules);
+        # per-shard costs are merged per scenario at collection time.
+        sh_pending = []
+        n_shard_schedules = 0
+        for (skey, statics, charge), idxs in sh_groups.items():
+            g0 = prepared[idxs[0]]
+            policy = g0["policy"]
+            shards = g0["shards"]
+            gen = policy.on_window if policy.t_cg is not None else None
+            recs = []
+            for tr in shards:
+                with obs.span("sweep.schedule") as sp:
+                    policy.bind(tr.n, tr.m)   # fresh CGM state per shard
+                    part0 = (policy.initial_partition(tr)
+                             if hasattr(policy, "initial_partition")
+                             else None)
+                    if part0 is None:
+                        part0 = CliquePartition.singletons(tr.n)
+                    schedule = ej.build_schedule(
+                        part0, tr, gen, policy.t_cg,
+                        model=g0["model"], env=g0["env"],
+                        batch_size=g0["bs"], seed_new_cliques=g0["seed"],
+                        layout=self.layout)
+                    sp.set_metadata(steps=schedule.nb, events=schedule.ne)
+                recs.append({
+                    "schedule": schedule,
+                    "n_windows": getattr(policy, "n_windows", 0),
+                    "cg_seconds": getattr(policy, "cg_seconds", 0.0),
+                    "size_history":
+                        list(getattr(policy, "size_history", [])),
+                    "clique_sizes": schedule.final_partition.sizes(),
+                })
+            n_shard_schedules += len(recs)
+            S_sh = len(recs)
+            with obs.span("sweep.stage", lanes=len(idxs) * S_sh) as sp:
+                s0 = recs[0]["schedule"]
+                ckey = (s0.state_rows, s0.state_cols, s0.const_dt,
+                        s0.uses_sizes, "xs")
+                _pad_cohort(ej, ckey, recs)
+                lanes = [recs[j]["schedule"]
+                         for _ in idxs for j in range(S_sh)]
+                spec = {
+                    k: np.stack([prepared[i]["spec"][k]
+                                 for i in idxs for _ in range(S_sh)])
+                    for k in g0["spec"]
+                }
+                L = len(lanes)
+                E0 = np.zeros((L, s0.state_rows, s0.state_cols), np.float64)
+                a0 = np.full((L, s0.state_rows), -1, np.int32)
+                sp.set_metadata(bytes=_nbytes(
+                    spec, E0, a0, *(s.xs for s in lanes)))
+                if self.mesh is not None:
+                    spec, E0, a0 = self._shard(spec, E0, a0, L)
+                t0 = _time.perf_counter()
+                _, _, acc = ej.run_schedules(
+                    lanes, spec, statics, E0, a0, charge=charge,
+                    block=False, layout=self.layout)
+            sh_pending.append((idxs, recs, acc, t0))
+
+        # -- dispatch device-CGM groups first (non-blocking) ----------------
+        dev_pending = []
+        for idxs in dev_groups.values():
+            g0 = prepared[idxs[0]]
+            trace = g0["pt"].trace
+            n, m_srv = trace.n, trace.m
+            cfg0 = g0["policy"].config
+            uses_sizes = bool(g0["model"].uses_sizes)
+            item_sizes = g0["env"].sizes() if uses_sizes else None
+            hot_dims = [cgm_jax.policy_hot_dims(prepared[i]["policy"])[0]
+                        for i in idxs]
+            with obs.span("sweep.schedule") as sp:
+                sched = cgm_jax.build_cgm_schedule(
+                    trace, cfg0.t_cg, uses_sizes=uses_sizes,
+                    batch_size=g0["bs"], hot_dims=hot_dims)
+                sp.set_metadata(steps=sched.nb, events=sched.B * sched.d)
+            S = len(idxs)
+            with obs.span("sweep.stage", lanes=S) as sp:
+                # compact-workspace cohort: repeated sweep calls over the
+                # same catalog ratchet (nb, B, d, h, W) through
+                # _COHORT_DIMS so the CGM scan compiles once per cohort,
+                # not once per call shape
+                ckey_cgm = ("cgm", n, m_srv, sched.uses_sizes)
+                dims = ej.schedule_dims(sched)
+                cached = _COHORT_DIMS.get(ckey_cgm)
+                if cached is not None:
+                    dims = {k: max(dims[k], cached[k]) for k in dims}
+                _COHORT_DIMS[ckey_cgm] = dims
+                sched = ej.pad_schedule(sched, dims)
+                carry1 = cgm_jax.init_cgm_carry(
+                    CacheState.fresh(CliquePartition.singletons(n), m_srv),
+                    None, None, n=n, m=m_srv, uses_sizes=uses_sizes,
+                    item_sizes=item_sizes, layout=self.layout,
+                    schedule=sched)
+                spec = {
+                    k: np.stack([prepared[i]["spec"][k] for i in idxs])
+                    for k in g0["spec"]
+                }
+                cspecs = [
+                    cgm_jax.cgm_spec(prepared[i]["policy"].config,
+                                     prepared[i]["policy"].config.params, n)
+                    for i in idxs
+                ]
+                cspec = {k: np.stack([np.asarray(c[k]) for c in cspecs])
+                         for k in cspecs[0]}
+                carry0 = {k: np.stack([v] * S) for k, v in carry1.items()}
+                sp.set_metadata(bytes=_nbytes(spec, cspec, carry0, sched.xs))
+                t0g = _time.perf_counter()
+                final, ofs = cgm_jax.run_cgm_schedule(
+                    sched, spec, g0["statics"], cspec, carry0, item_sizes,
+                    charge=g0["charge"], enable_split=cfg0.enable_split,
+                    enable_acm=cfg0.enable_approx_merge,
+                    seed_new=g0["seed"], block=False)
+            dev_pending.append((idxs, sched, final, ofs, t0g))
+
+        # -- align schedule shapes so each (n, m, path) cohort compiles the
+        # device scan exactly once, then dispatch every group WITHOUT
+        # blocking (XLA chews in the background, results collected below)
+        pending = []
+        with obs.span("sweep.stage") as sp:
+            staged = 0
+            cohorts: dict = {}
+            for rec in schedules.values():
+                s = rec["schedule"]
+                # cohorts key on the STATE geometry, not the raw (n, m):
+                # under a bucketed layout, points whose shapes round to the
+                # same bucket land in one cohort and share one compiled scan
+                cohorts.setdefault(
+                    (s.state_rows, s.state_cols, s.const_dt, s.uses_sizes),
+                    []).append(rec)
+            for ckey, recs in cohorts.items():
+                _pad_cohort(ej, ckey, recs)
+
+            # groups sharing (padded state geometry, statics, charge) stack
+            # as lanes of ONE run_schedules call, so a mixed-shape sweep
+            # compiles once per bucket COHORT — not once per (schedule,
+            # group-width) combination.  Single-group cohorts keep the
+            # run_schedule path: one shared schedule vmapped over S specs,
+            # no per-lane xs copies.
+            cohort_groups: dict = {}
+            for (skey, statics, charge), idxs in groups.items():
+                s = schedules[skey]["schedule"]
+                # the xs key SET is part of the compiled scan's signature
+                # (e.g. TTL's "nokeep" mask): only schedules carrying the
+                # same event tensors can share one lane-stacked call
+                cohort_groups.setdefault(
+                    ((s.state_rows, s.state_cols, s.const_dt, s.uses_sizes),
+                     frozenset(s.xs), statics, charge),
+                    []).append((skey, idxs))
+
+            for (ckey, _xs_keys, statics, charge), members in \
+                    cohort_groups.items():
+                g0 = prepared[members[0][1][0]]
+                if len(members) == 1:
+                    skey, idxs = members[0]
+                    rec = schedules[skey]
+                    schedule = rec["schedule"]
+                    S = len(idxs)
+                    spec = {
+                        k: np.stack([prepared[i]["spec"][k] for i in idxs])
+                        for k in g0["spec"]
+                    }
+                    E0 = np.zeros(
+                        (S, schedule.state_rows, schedule.state_cols),
+                        np.float64)
+                    a0 = np.full((S, schedule.state_rows), -1, np.int32)
+                    if S == 1:       # no vmap lane for a singleton group
+                        spec = {k: v[0] for k, v in spec.items()}
+                        E0, a0 = E0[0], a0[0]
+                    staged += _nbytes(spec, E0, a0, schedule.xs)
+                    if self.mesh is not None:
+                        spec, E0, a0 = self._shard(spec, E0, a0, S)
+                    t0 = _time.perf_counter()
+                    _, _, acc = ej.run_schedule(
+                        schedule, spec, statics, E0, a0, charge=charge,
+                        block=False, layout=self.layout)
+                    pending.append((idxs, [rec] * S, acc, t0))
+                    continue
+                lane_idx, lanes, lane_recs = [], [], []
+                for skey, idxs in members:
+                    rec = schedules[skey]
+                    for i in idxs:
+                        lane_idx.append(i)
+                        lanes.append(rec["schedule"])
+                        lane_recs.append(rec)
+                spec = {
+                    k: np.stack([prepared[i]["spec"][k] for i in lane_idx])
+                    for k in g0["spec"]
+                }
+                L = len(lanes)
+                s0 = lanes[0]
+                E0 = np.zeros((L, s0.state_rows, s0.state_cols), np.float64)
+                a0 = np.full((L, s0.state_rows), -1, np.int32)
+                staged += _nbytes(spec, E0, a0, *(s.xs for s in lanes))
+                if self.mesh is not None:
+                    spec, E0, a0 = self._shard(spec, E0, a0, L)
+                t0 = _time.perf_counter()
+                _, _, acc = ej.run_schedules(
+                    lanes, spec, statics, E0, a0, charge=charge,
+                    block=False, layout=self.layout)
+                pending.append((lane_idx, lane_recs, acc, t0))
+            sp.set_metadata(lanes=sum(len(p[0]) for p in pending),
+                            bytes=staged)
+        self.last_n_schedules = (len(schedules) + len(dev_pending)
+                                 + n_shard_schedules)
+        call_span.set_metadata(
+            schedules=self.last_n_schedules,
+            lanes=len(prepared),
+            groups=len(dev_pending) + len(sh_pending) + len(pending))
+
+        # -- collect (blocks on the device results) -------------------------
+        with obs.span("sweep.collect"):
+            results: list[RunResult | None] = [None] * len(prepared)
+            for idxs, sched, final, ofs, t0g in dev_pending:
+                with obs.span("sweep.wait"):
+                    final = {k: np.asarray(v) for k, v in final.items()}
+                    ofs = np.asarray(ofs)
+                wall = _time.perf_counter() - t0g
+                nbd = int(sched.boundary_steps.size)
+                for lane, i in enumerate(idxs):
+                    pr = prepared[i]
+                    costs = CostBreakdown(model=pr["statics"][0])
+                    ej.apply_acc(costs, sched, final["acc"][lane])
+                    part = cgm_jax.partition_from_of(
+                        sched.n, final["of"][lane])
+                    hist = []
+                    for b in sched.boundary_steps:
+                        sz = np.bincount(ofs[lane, int(b)]).astype(np.int64)
+                        hist.append(sz[sz > 1])
+                    results[i] = RunResult(
+                        policy=pr["policy"].name,
+                        costs=costs,
+                        clique_sizes=part.sizes(),
+                        size_history=hist,
+                        n_windows=nbd,
+                        cg_seconds=0.0,
+                        wall_seconds=wall / len(idxs),
+                        config=getattr(pr["policy"], "config", None),
+                    )
+            for idxs, recs, acc, t0 in sh_pending:
+                with obs.span("sweep.wait"):
+                    acc = np.asarray(acc)
+                wall = _time.perf_counter() - t0
+                S_sh = len(recs)
+                for li, i in enumerate(idxs):
+                    pr = prepared[i]
+                    merged = CostBreakdown(model=pr["statics"][0])
+                    totals = []
+                    for j, rec in enumerate(recs):
+                        cb = CostBreakdown(model=pr["statics"][0])
+                        ej.apply_acc(
+                            cb, rec["schedule"], acc[li * S_sh + j])
+                        totals.append(cb.total)
+                        merged.merge(cb)
+                    results[i] = RunResult(
+                        policy=pr["policy"].name,
+                        costs=merged,
+                        clique_sizes=recs[0]["clique_sizes"],
+                        size_history=list(recs[0]["size_history"]),
+                        n_windows=recs[0]["n_windows"],
+                        cg_seconds=sum(r["cg_seconds"] for r in recs),
+                        wall_seconds=wall / len(idxs),
+                        config=getattr(pr["policy"], "config", None),
+                        shard_stats=_shard_stats(totals),
+                    )
+            for idxs, lane_recs, acc, t0 in pending:
+                with obs.span("sweep.wait"):
+                    acc = np.atleast_2d(np.asarray(acc))
+                wall = _time.perf_counter() - t0
+                for lane, i in enumerate(idxs):
+                    pr = prepared[i]
+                    rec = lane_recs[lane]
+                    costs = CostBreakdown(model=pr["statics"][0])
+                    ej.apply_acc(costs, rec["schedule"], acc[lane])
+                    results[i] = RunResult(
+                        policy=pr["policy"].name,
+                        costs=costs,
+                        clique_sizes=rec["clique_sizes"],
+                        size_history=list(rec["size_history"]),
+                        n_windows=rec["n_windows"],
+                        cg_seconds=rec["cg_seconds"],
+                        wall_seconds=wall / len(idxs),
+                        config=getattr(pr["policy"], "config", None),
+                    )
+        return results  # type: ignore[return-value]
+
+    def _prepare(self, points):
+        """Per-point policy, environment and cost spec, and the groups the
+        points fall into: device-CGM super-groups, host-schedule groups
+        and trace-shard groups (index lists into the prepared points)."""
+        from . import cgm_jax
+        from . import engine_jax as ej
+
         prepared = []
         for pt in points:
             shards = _shards_of(pt.trace)
@@ -246,8 +580,6 @@ class SweepEngine:
         # and vmap the clique generation itself — zero host CGM calls.
         # A group needs >= 2 distinct CGM keys to beat the host path
         # (with one key the host builds one shared schedule anyway).
-        from . import cgm_jax
-
         dev_groups: dict = {}
         for i, pr in enumerate(prepared):
             pt, policy = pr["pt"], pr["policy"]
@@ -280,325 +612,7 @@ class SweepEngine:
             dst = sh_groups if pr["shards"] is not None else groups
             dst.setdefault((pr["skey"], pr["statics"], pr["charge"]),
                            []).append(i)
-
-        # -- build every distinct schedule on host --------------------------
-        schedules: dict = {}
-        for (skey, statics, charge), idxs in groups.items():
-            g0 = prepared[idxs[0]]
-            if skey in schedules:
-                continue
-            policy = g0["policy"]
-            part0 = (policy.initial_partition(g0["pt"].trace)
-                     if hasattr(policy, "initial_partition") else None)
-            if part0 is None:
-                part0 = CliquePartition.singletons(g0["pt"].trace.n)
-            gen = policy.on_window if policy.t_cg is not None else None
-            schedule = ej.build_schedule(
-                part0, g0["pt"].trace, gen, policy.t_cg,
-                model=g0["model"], env=g0["env"], batch_size=g0["bs"],
-                seed_new_cliques=g0["seed"], layout=self.layout,
-            )
-            schedules[skey] = {
-                "schedule": schedule,
-                "n_windows": getattr(policy, "n_windows", 0),
-                "cg_seconds": getattr(policy, "cg_seconds", 0.0),
-                "size_history": list(getattr(policy, "size_history", [])),
-                "clique_sizes": schedule.final_partition.sizes(),
-            }
-            if progress is not None:
-                progress(f"schedule built: {g0['pt'].policy} "
-                         f"({schedule.nb} steps x {schedule.ne} events)")
-
-        # -- align schedule shapes so each (n, m, path) cohort compiles the
-        # device scan exactly once, then dispatch every group WITHOUT
-        # blocking (XLA chews in the background, results collected below)
-        cohorts: dict = {}
-        for rec in schedules.values():
-            s = rec["schedule"]
-            # cohorts key on the STATE geometry, not the raw (n, m): under
-            # a bucketed layout, points whose shapes round to the same
-            # bucket land in one cohort and share one compiled scan
-            cohorts.setdefault(
-                (s.state_rows, s.state_cols, s.const_dt, s.uses_sizes),
-                []).append(rec)
-        for ckey, recs in cohorts.items():
-            dims_list = [ej.schedule_dims(r["schedule"]) for r in recs]
-            dims = {k: max(d[k] for d in dims_list) for k in dims_list[0]}
-            cached = _COHORT_DIMS.get(ckey)
-            if cached is not None:
-                dims = {k: max(dims[k], cached[k]) for k in dims}
-            _COHORT_DIMS[ckey] = dims
-            for r, d0 in zip(recs, dims_list):
-                if d0 != dims:   # shared shapes: skip the pad entirely
-                    r["schedule"] = ej.pad_schedule(r["schedule"], dims)
-
-        # -- trace-shard groups: one schedule PER SHARD, stacked batched ----
-        # lanes = scenarios x shards of one vmapped call (run_schedules);
-        # per-shard costs are merged per scenario at collection time.
-        sh_pending = []
-        n_shard_schedules = 0
-        for (skey, statics, charge), idxs in sh_groups.items():
-            g0 = prepared[idxs[0]]
-            policy = g0["policy"]
-            shards = g0["shards"]
-            gen = policy.on_window if policy.t_cg is not None else None
-            recs = []
-            for tr in shards:
-                policy.bind(tr.n, tr.m)       # fresh CGM state per shard
-                part0 = (policy.initial_partition(tr)
-                         if hasattr(policy, "initial_partition") else None)
-                if part0 is None:
-                    part0 = CliquePartition.singletons(tr.n)
-                schedule = ej.build_schedule(
-                    part0, tr, gen, policy.t_cg,
-                    model=g0["model"], env=g0["env"], batch_size=g0["bs"],
-                    seed_new_cliques=g0["seed"], layout=self.layout)
-                recs.append({
-                    "schedule": schedule,
-                    "n_windows": getattr(policy, "n_windows", 0),
-                    "cg_seconds": getattr(policy, "cg_seconds", 0.0),
-                    "size_history":
-                        list(getattr(policy, "size_history", [])),
-                    "clique_sizes": schedule.final_partition.sizes(),
-                })
-            n_shard_schedules += len(recs)
-            s0 = recs[0]["schedule"]
-            ckey = (s0.state_rows, s0.state_cols, s0.const_dt,
-                    s0.uses_sizes, "xs")
-            dims_list = [ej.schedule_dims(r["schedule"]) for r in recs]
-            dims = {k: max(d[k] for d in dims_list) for k in dims_list[0]}
-            cached = _COHORT_DIMS.get(ckey)
-            if cached is not None:
-                dims = {k: max(dims[k], cached[k]) for k in dims}
-            _COHORT_DIMS[ckey] = dims
-            for r, d0 in zip(recs, dims_list):
-                if d0 != dims:
-                    r["schedule"] = ej.pad_schedule(r["schedule"], dims)
-            S_sh = len(recs)
-            lanes = [recs[j]["schedule"]
-                     for _ in idxs for j in range(S_sh)]
-            spec = {
-                k: np.stack([prepared[i]["spec"][k]
-                             for i in idxs for _ in range(S_sh)])
-                for k in g0["spec"]
-            }
-            L = len(lanes)
-            E0 = np.zeros((L, s0.state_rows, s0.state_cols), np.float64)
-            a0 = np.full((L, s0.state_rows), -1, np.int32)
-            if self.mesh is not None:
-                spec, E0, a0 = self._shard(spec, E0, a0, L)
-            t0 = _time.perf_counter()
-            _, _, acc = ej.run_schedules(
-                lanes, spec, statics, E0, a0, charge=charge, block=False,
-                layout=self.layout)
-            sh_pending.append((idxs, recs, acc, t0))
-            if progress is not None:
-                progress(f"shard group of {len(idxs)} scenario(s) x "
-                         f"{S_sh} shard(s) dispatched")
-
-        # -- dispatch device-CGM groups first (non-blocking) ----------------
-        dev_pending = []
-        for idxs in dev_groups.values():
-            g0 = prepared[idxs[0]]
-            trace = g0["pt"].trace
-            n, m_srv = trace.n, trace.m
-            cfg0 = g0["policy"].config
-            uses_sizes = bool(g0["model"].uses_sizes)
-            item_sizes = g0["env"].sizes() if uses_sizes else None
-            hot_dims = [cgm_jax.policy_hot_dims(prepared[i]["policy"])[0]
-                        for i in idxs]
-            sched = cgm_jax.build_cgm_schedule(
-                trace, cfg0.t_cg, uses_sizes=uses_sizes,
-                batch_size=g0["bs"], hot_dims=hot_dims)
-            # compact-workspace cohort: repeated sweep calls over the same
-            # catalog ratchet (nb, B, d, h, W) through _COHORT_DIMS so the
-            # CGM scan compiles once per cohort, not once per call shape
-            ckey_cgm = ("cgm", n, m_srv, sched.uses_sizes)
-            dims = ej.schedule_dims(sched)
-            cached = _COHORT_DIMS.get(ckey_cgm)
-            if cached is not None:
-                dims = {k: max(dims[k], cached[k]) for k in dims}
-            _COHORT_DIMS[ckey_cgm] = dims
-            sched = ej.pad_schedule(sched, dims)
-            from .engine import CacheState
-
-            carry1 = cgm_jax.init_cgm_carry(
-                CacheState.fresh(CliquePartition.singletons(n), m_srv),
-                None, None, n=n, m=m_srv, uses_sizes=uses_sizes,
-                item_sizes=item_sizes, layout=self.layout, schedule=sched)
-            S = len(idxs)
-            spec = {
-                k: np.stack([prepared[i]["spec"][k] for i in idxs])
-                for k in g0["spec"]
-            }
-            cspecs = [
-                cgm_jax.cgm_spec(prepared[i]["policy"].config,
-                                 prepared[i]["policy"].config.params, n)
-                for i in idxs
-            ]
-            cspec = {k: np.stack([np.asarray(c[k]) for c in cspecs])
-                     for k in cspecs[0]}
-            carry0 = {k: np.stack([v] * S) for k, v in carry1.items()}
-            t0g = _time.perf_counter()
-            final, ofs = cgm_jax.run_cgm_schedule(
-                sched, spec, g0["statics"], cspec, carry0, item_sizes,
-                charge=g0["charge"], enable_split=cfg0.enable_split,
-                enable_acm=cfg0.enable_approx_merge, seed_new=g0["seed"],
-                block=False)
-            dev_pending.append((idxs, sched, final, ofs, t0g))
-            if progress is not None:
-                progress(f"device-CGM group of {S} scenario(s) dispatched "
-                         f"({sched.nb} steps, {sched.boundary_steps.size} "
-                         "windows on device)")
-
-        # groups sharing (padded state geometry, statics, charge) stack as
-        # lanes of ONE run_schedules call, so a mixed-shape sweep compiles
-        # once per bucket COHORT — not once per (schedule, group-width)
-        # combination.  Single-group cohorts keep the run_schedule path:
-        # one shared schedule vmapped over S specs, no per-lane xs copies.
-        cohort_groups: dict = {}
-        for (skey, statics, charge), idxs in groups.items():
-            s = schedules[skey]["schedule"]
-            # the xs key SET is part of the compiled scan's signature
-            # (e.g. TTL's "nokeep" mask): only schedules carrying the
-            # same event tensors can share one lane-stacked call
-            cohort_groups.setdefault(
-                ((s.state_rows, s.state_cols, s.const_dt, s.uses_sizes),
-                 frozenset(s.xs), statics, charge),
-                []).append((skey, idxs))
-
-        pending = []
-        for (ckey, _xs_keys, statics, charge), members in \
-                cohort_groups.items():
-            g0 = prepared[members[0][1][0]]
-            if len(members) == 1:
-                skey, idxs = members[0]
-                rec = schedules[skey]
-                schedule = rec["schedule"]
-                S = len(idxs)
-                spec = {
-                    k: np.stack([prepared[i]["spec"][k] for i in idxs])
-                    for k in g0["spec"]
-                }
-                E0 = np.zeros(
-                    (S, schedule.state_rows, schedule.state_cols),
-                    np.float64)
-                a0 = np.full((S, schedule.state_rows), -1, np.int32)
-                if S == 1:       # no vmap lane for a singleton group
-                    spec = {k: v[0] for k, v in spec.items()}
-                    E0, a0 = E0[0], a0[0]
-                if self.mesh is not None:
-                    spec, E0, a0 = self._shard(spec, E0, a0, S)
-                t0 = _time.perf_counter()
-                _, _, acc = ej.run_schedule(
-                    schedule, spec, statics, E0, a0, charge=charge,
-                    block=False, layout=self.layout)
-                pending.append((idxs, [rec] * S, acc, t0))
-                continue
-            lane_idx, lanes, lane_recs = [], [], []
-            for skey, idxs in members:
-                rec = schedules[skey]
-                for i in idxs:
-                    lane_idx.append(i)
-                    lanes.append(rec["schedule"])
-                    lane_recs.append(rec)
-            spec = {
-                k: np.stack([prepared[i]["spec"][k] for i in lane_idx])
-                for k in g0["spec"]
-            }
-            L = len(lanes)
-            s0 = lanes[0]
-            E0 = np.zeros((L, s0.state_rows, s0.state_cols), np.float64)
-            a0 = np.full((L, s0.state_rows), -1, np.int32)
-            if self.mesh is not None:
-                spec, E0, a0 = self._shard(spec, E0, a0, L)
-            t0 = _time.perf_counter()
-            _, _, acc = ej.run_schedules(
-                lanes, spec, statics, E0, a0, charge=charge, block=False,
-                layout=self.layout)
-            pending.append((lane_idx, lane_recs, acc, t0))
-        self.last_n_schedules = (len(schedules) + len(dev_pending)
-                                 + n_shard_schedules)
-
-        # -- collect (blocks on the device results) -------------------------
-        results: list[RunResult | None] = [None] * len(prepared)
-        for idxs, sched, final, ofs, t0g in dev_pending:
-            final = {k: np.asarray(v) for k, v in final.items()}
-            ofs = np.asarray(ofs)
-            wall = _time.perf_counter() - t0g
-            nbd = int(sched.boundary_steps.size)
-            if progress is not None:
-                progress(f"device-CGM group of {len(idxs)} scenario(s) "
-                         f"replayed in {wall:.2f}s")
-            for lane, i in enumerate(idxs):
-                pr = prepared[i]
-                costs = CostBreakdown(model=pr["statics"][0])
-                ej.apply_acc(costs, sched, final["acc"][lane])
-                part = cgm_jax.partition_from_of(
-                    sched.n, final["of"][lane])
-                hist = []
-                for b in sched.boundary_steps:
-                    sz = np.bincount(ofs[lane, int(b)]).astype(np.int64)
-                    hist.append(sz[sz > 1])
-                results[i] = RunResult(
-                    policy=pr["policy"].name,
-                    costs=costs,
-                    clique_sizes=part.sizes(),
-                    size_history=hist,
-                    n_windows=nbd,
-                    cg_seconds=0.0,
-                    wall_seconds=wall / len(idxs),
-                    config=getattr(pr["policy"], "config", None),
-                )
-        for idxs, recs, acc, t0 in sh_pending:
-            acc = np.asarray(acc)
-            wall = _time.perf_counter() - t0
-            S_sh = len(recs)
-            if progress is not None:
-                progress(f"shard group of {len(idxs)} scenario(s) x "
-                         f"{S_sh} shard(s) replayed in {wall:.2f}s")
-            for li, i in enumerate(idxs):
-                pr = prepared[i]
-                merged = CostBreakdown(model=pr["statics"][0])
-                totals = []
-                for j, rec in enumerate(recs):
-                    cb = CostBreakdown(model=pr["statics"][0])
-                    ej.apply_acc(cb, rec["schedule"], acc[li * S_sh + j])
-                    totals.append(cb.total)
-                    merged.merge(cb)
-                results[i] = RunResult(
-                    policy=pr["policy"].name,
-                    costs=merged,
-                    clique_sizes=recs[0]["clique_sizes"],
-                    size_history=list(recs[0]["size_history"]),
-                    n_windows=recs[0]["n_windows"],
-                    cg_seconds=sum(r["cg_seconds"] for r in recs),
-                    wall_seconds=wall / len(idxs),
-                    config=getattr(pr["policy"], "config", None),
-                    shard_stats=_shard_stats(totals),
-                )
-        for idxs, lane_recs, acc, t0 in pending:
-            acc = np.atleast_2d(np.asarray(acc))
-            wall = _time.perf_counter() - t0
-            if progress is not None:
-                progress(f"group of {len(idxs)} scenario(s) replayed "
-                         f"in {wall:.2f}s")
-            for lane, i in enumerate(idxs):
-                pr = prepared[i]
-                rec = lane_recs[lane]
-                costs = CostBreakdown(model=pr["statics"][0])
-                ej.apply_acc(costs, rec["schedule"], acc[lane])
-                results[i] = RunResult(
-                    policy=pr["policy"].name,
-                    costs=costs,
-                    clique_sizes=rec["clique_sizes"],
-                    size_history=list(rec["size_history"]),
-                    n_windows=rec["n_windows"],
-                    cg_seconds=rec["cg_seconds"],
-                    wall_seconds=wall / len(idxs),
-                    config=getattr(pr["policy"], "config", None),
-                )
-        return results  # type: ignore[return-value]
+        return prepared, dev_groups, groups, sh_groups
 
     # ------------------------------------------------------------------
     def _shard(self, spec, E0, a0, S):

@@ -48,6 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..core.cost import CostBreakdown
 from ..core.engine import CacheState
 from ..core.policy import RunResult
@@ -135,16 +136,25 @@ class ServeFuture:
         self._upto = upto
 
     def done(self) -> bool:
-        """True once every request of this submit has finished on device."""
+        """True once the chunk holding this submit's last request is ready
+        on the device; False while that request is still buffered."""
         e = self._eng
-        return e._dispatched_total >= self._upto and not e._probes
+        if e._dispatched_total < self._upto:
+            return False
+        if self._upto <= e._ready_total:
+            return True
+        for end, probe in zip(e._probe_ends, e._probes):
+            if self._upto <= end:
+                return probe.is_ready()
+        return True
 
     def result(self) -> CostBreakdown:
         e = self._eng
         if e._dispatched_total < self._upto:
             e._flush()
-        e._block()
-        return e._sync_costs()
+        with obs.span("live.sync", call="result"):
+            e._block()
+            return e._sync_costs()
 
 
 class LiveServingEngine:
@@ -194,9 +204,15 @@ class LiveServingEngine:
         self._carry = None          # (E, anchor, acc) device arrays
         self._spec_j = None         # device copy of the scenario spec
         self._probes: deque = deque()
+        #: request total at the end of each in-flight chunk (with _probes)
+        self._probe_ends: deque = deque()
+        #: requests of every chunk known to be ready on the device
+        self._ready_total = 0
         self._dims: dict | None = None
         #: fresh scan traces (= XLA compiles) triggered by this engine
         self.compiles = 0
+        self._n = dict(chunks=0, requests=0, ring_waits=0, ring_wait_s=0.0,
+                       h2d_bytes=0, carry_grows=0)
         self._pend: list[tuple] = []     # (items, servers, times) buffers
         self._pend_n = 0
         self._submitted_total = 0
@@ -254,10 +270,20 @@ class LiveServingEngine:
         return self._pend_n
 
     @property
+    def stats(self) -> dict:
+        """Counters over this engine's life: ``chunks`` dispatched and the
+        ``requests`` in them, ``ring_waits`` (dispatches that blocked on
+        the oldest in-flight chunk) and their ``ring_wait_s``,
+        ``h2d_bytes`` of event tensors put on the device, ``carry_grows``
+        of the CGM carry, and ``compiles``."""
+        return dict(self._n, compiles=self.compiles)
+
+    @property
     def costs(self) -> CostBreakdown:
         """Mid-stream costs of every COMPLETED chunk (blocks the ring;
         the < chunk_size buffered requests are priced at :meth:`drain`)."""
-        return self._sync_costs()
+        with obs.span("live.sync", call="costs"):
+            return self._sync_costs()
 
     # -- streaming ---------------------------------------------------------
     def submit(self, items, servers, times) -> ServeFuture:
@@ -306,8 +332,9 @@ class LiveServingEngine:
         ring, and sync state + costs into the wrapped numpy session."""
         t0 = _time.perf_counter()
         self._flush()
-        self._block()
-        self._sync_state()
+        with obs.span("live.sync", call="drain"):
+            self._block()
+            self._sync_state()
         self.session._wall += _time.perf_counter() - t0
         return self.session.engine.costs
 
@@ -319,8 +346,9 @@ class LiveServingEngine:
         the processed prefix stays chunk-aligned on resume — required
         for bitwise-identical continuation).  ``drain()`` first if the
         snapshot must be loadable by a plain ``CacheSession``."""
-        self._block()
-        self._sync_state()
+        with obs.span("live.sync", call="snapshot"):
+            self._block()
+            self._sync_state()
         snap = self.session.snapshot()
         items, servers, times = self._pend_concat()
         snap["live"] = {
@@ -335,6 +363,8 @@ class LiveServingEngine:
         ``CacheSession``; resumes bit-identically.  Outstanding futures
         from before the restore are invalidated."""
         self._probes.clear()
+        self._probe_ends.clear()
+        self._ready_total = 0
         self._carry = None          # re-seed from the restored state
         self._cgm_carry = None
         self._ofs = []
@@ -533,76 +563,81 @@ class LiveServingEngine:
         sess = self.session
         eng = sess.engine
         R = times.shape[0]
-        if sess._next_cg is None:
-            sess._next_cg = float(times[0]) + sess._t_cg
-        # the open window's rows already live in the device buffer; the
-        # chunk schedule's head-window capacity must account for them
-        pre_rows = pre_slots = 0
-        for w_it, _w_sv in sess._win:
-            r = int(w_it.shape[0])
-            wd = int(w_it.shape[1]) if w_it.ndim == 2 else 1
-            pre_rows += r
-            pre_slots += r * wd
-        sched = cgm_jax.build_cgm_schedule(
-            _Chunk(items, servers, times, self.n, self.m), sess._t_cg,
-            uses_sizes=bool(eng.model.uses_sizes), next_cg0=sess._next_cg,
-            hot_dims=cgm_jax.policy_hot_dims(self.policy),
-            prefix_rows=pre_rows, prefix_slots=pre_slots)
-        dims = ej.schedule_dims(sched)
-        if self._cgm_dims is None or any(
-                dims[k] > self._cgm_dims[k] for k in dims):
-            grown = {"nb": ej._bucket(int(dims["nb"] * 2), 4, 4),
-                     "B": ej._bucket(int(dims["B"] * 2), 32, 32),
-                     "d": dims["d"],
-                     "h": min(self.n,
-                              ej._bucket(int(dims["h"] * 2), 32, 32)),
-                     "W": ej._bucket(int(dims["W"] * 2), 64, 64)}
-            self._cgm_dims = (grown if self._cgm_dims is None else {
-                k: max(self._cgm_dims[k], grown[k]) for k in grown})
-        sched = ej.pad_schedule(sched, self._cgm_dims)
-        # growing B re-derives wcap; fold it back into the ratchet
-        self._cgm_dims["W"] = max(self._cgm_dims["W"], sched.wcap)
-        # carry creation reads the PRE-chunk open window (sess._win)
-        self._ensure_cgm_carry(sched)
-        cw, cd = (int(x) for x in self._cgm_carry["wbuf"].shape)
-        ch = int(self._cgm_carry["p_idx"].shape[0])
-        if ch < sched.h or cw < sched.wcap or cd < sched.d:
-            self._grow_cgm_carry(sched.h, sched.wcap, sched.d)
-        elif ch > sched.h:
-            # a restored previous-window CRM bumped the carry's h past
-            # the schedule's; ratchet the dims so they stay aligned
-            self._cgm_dims["h"] = max(self._cgm_dims["h"], ch)
-        if sched.next_cg is not None:
-            sess._next_cg = sched.next_cg
-        if sched.boundary_hit:
-            sess._win = []
-            self._cgm_bound = True
-        if sched.win_start < R:
-            sess._win.append((
-                np.array(items[sched.win_start:], dtype=np.int32,
-                         copy=True),
-                np.array(servers[sched.win_start:], dtype=np.int32,
-                         copy=True),
-            ))
-        sess._last_t = float(times[-1])
-        self._host_nreq += sched.n_requests
-        self._host_nitem += sched.n_item_requests
-        self._dispatched_total += R
-        fn = _compiled_cgm_live_step(
-            self._jeng._statics, eng.caching_charge, *self._cgm_flags,
-            *self._cgm_statics)
-        before = cgm_jax.SCAN_TRACES
-        with jax.enable_x64(True):
-            xs_j = {k: jnp.asarray(v) for k, v in sched.xs.items()}
-            self._cgm_carry, ofs = fn(
-                self._spec_j, self._cspec_j, self._cgm_carry, xs_j,
-                self._sz_j)
-        self.compiles += cgm_jax.SCAN_TRACES - before
+        chunk = self._n["chunks"]
+        with obs.span("live.pack", chunk=chunk, requests=R) as sp:
+            if sess._next_cg is None:
+                sess._next_cg = float(times[0]) + sess._t_cg
+            # the open window's rows already live in the device buffer; the
+            # chunk schedule's head-window capacity must account for them
+            pre_rows = pre_slots = 0
+            for w_it, _w_sv in sess._win:
+                r = int(w_it.shape[0])
+                wd = int(w_it.shape[1]) if w_it.ndim == 2 else 1
+                pre_rows += r
+                pre_slots += r * wd
+            sched = cgm_jax.build_cgm_schedule(
+                _Chunk(items, servers, times, self.n, self.m), sess._t_cg,
+                uses_sizes=bool(eng.model.uses_sizes),
+                next_cg0=sess._next_cg,
+                hot_dims=cgm_jax.policy_hot_dims(self.policy),
+                prefix_rows=pre_rows, prefix_slots=pre_slots)
+            sp.set_metadata(windows=int(sched.boundary_steps.size))
+            dims = ej.schedule_dims(sched)
+            if self._cgm_dims is None or any(
+                    dims[k] > self._cgm_dims[k] for k in dims):
+                grown = {"nb": ej._bucket(int(dims["nb"] * 2), 4, 4),
+                         "B": ej._bucket(int(dims["B"] * 2), 32, 32),
+                         "d": dims["d"],
+                         "h": min(self.n,
+                                  ej._bucket(int(dims["h"] * 2), 32, 32)),
+                         "W": ej._bucket(int(dims["W"] * 2), 64, 64)}
+                self._cgm_dims = (grown if self._cgm_dims is None else {
+                    k: max(self._cgm_dims[k], grown[k]) for k in grown})
+            sched = ej.pad_schedule(sched, self._cgm_dims)
+            # growing B re-derives wcap; fold it back into the ratchet
+            self._cgm_dims["W"] = max(self._cgm_dims["W"], sched.wcap)
+            # carry creation reads the PRE-chunk open window (sess._win)
+            self._ensure_cgm_carry(sched)
+            cw, cd = (int(x) for x in self._cgm_carry["wbuf"].shape)
+            ch = int(self._cgm_carry["p_idx"].shape[0])
+            if ch < sched.h or cw < sched.wcap or cd < sched.d:
+                with obs.span("live.grow", chunk=chunk, requests=R):
+                    self._grow_cgm_carry(sched.h, sched.wcap, sched.d)
+                self._n["carry_grows"] += 1
+            elif ch > sched.h:
+                # a restored previous-window CRM bumped the carry's h past
+                # the schedule's; ratchet the dims so they stay aligned
+                self._cgm_dims["h"] = max(self._cgm_dims["h"], ch)
+            if sched.next_cg is not None:
+                sess._next_cg = sched.next_cg
+            if sched.boundary_hit:
+                sess._win = []
+                self._cgm_bound = True
+            if sched.win_start < R:
+                sess._win.append((
+                    np.array(items[sched.win_start:], dtype=np.int32,
+                             copy=True),
+                    np.array(servers[sched.win_start:], dtype=np.int32,
+                             copy=True),
+                ))
+            sess._last_t = float(times[-1])
+            self._host_nreq += sched.n_requests
+            self._host_nitem += sched.n_item_requests
+            self._dispatched_total += R
+        xs_j = self._put(sched.xs, chunk, R)
+        with obs.span("live.launch", chunk=chunk, requests=R):
+            fn = _compiled_cgm_live_step(
+                self._jeng._statics, eng.caching_charge, *self._cgm_flags,
+                *self._cgm_statics)
+            before = cgm_jax.SCAN_TRACES
+            with jax.enable_x64(True):
+                self._cgm_carry, ofs = fn(
+                    self._spec_j, self._cspec_j, self._cgm_carry, xs_j,
+                    self._sz_j)
+            self.compiles += cgm_jax.SCAN_TRACES - before
         self._acc_dirty = True
         self._ofs.append((sched.boundary_steps, ofs))
-        self._probes.append(ofs)
-        while len(self._probes) > self.ring:    # backpressure
-            self._probes.popleft().block_until_ready()
+        self._enqueue(ofs, chunk, R)
 
     def _dispatch(self, items, servers, times) -> None:
         """Pack one chunk's event tensors and launch it on the ring."""
@@ -613,59 +648,91 @@ class LiveServingEngine:
         sess = self.session
         eng = sess.engine
         R = times.shape[0]
+        chunk = self._n["chunks"]
         windowed = sess._t_cg is not None
-        if windowed and sess._next_cg is None:
-            sess._next_cg = float(times[0]) + sess._t_cg
-        sched = ej.build_schedule(
-            self._part, _Chunk(items, servers, times),
-            sess.policy.on_window if windowed else None,
-            sess._t_cg,
-            model=eng.model, env=eng.env,
-            seed_new_cliques=eng.seed_new_cliques,
-            next_cg0=sess._next_cg if windowed else None,
-            win_prefix=(sess._window_arrays()
-                        if windowed and sess._win else None),
-            lookup=eng._lookup,
-            layout=self.layout,
-        )
-        # T_CG window bookkeeping — identical to CacheSession._feed_trace_jax
-        if windowed:
-            if sched.next_cg is not None:
-                sess._next_cg = sched.next_cg
-            if sched.boundary_hit:
-                sess._win = []
-            if sched.win_start < R:
-                sess._win.append((
-                    np.array(items[sched.win_start:], dtype=np.int32,
-                             copy=True),
-                    np.array(servers[sched.win_start:], dtype=np.int32,
-                             copy=True),
-                ))
-        sess._last_t = float(times[-1])
-        self._part = sched.final_partition
-        self._host_nreq += sched.n_requests
-        self._host_nitem += sched.n_item_requests
-        self._dispatched_total += R
-        dims = ej.schedule_dims(sched)
-        if self._dims is None or any(
-                dims[k] > self._dims[k] for k in dims):
-            self._fix_dims(dims)
-        sched = ej.pad_schedule(sched, self._dims)
-        fn = _compiled_live_step(
-            self._jeng._statics, eng.caching_charge, sched.const_dt)
-        before = ej.SCAN_TRACES
-        with jax.enable_x64(True):
-            xs_j = {k: jnp.asarray(v) for k, v in sched.xs.items()}
-            self._carry, probe = fn(self._spec_j, self._carry, xs_j)
-        self.compiles += ej.SCAN_TRACES - before
+        with obs.span("live.pack", chunk=chunk, requests=R) as sp:
+            if windowed and sess._next_cg is None:
+                sess._next_cg = float(times[0]) + sess._t_cg
+            sched = ej.build_schedule(
+                self._part, _Chunk(items, servers, times),
+                sess.policy.on_window if windowed else None,
+                sess._t_cg,
+                model=eng.model, env=eng.env,
+                seed_new_cliques=eng.seed_new_cliques,
+                next_cg0=sess._next_cg if windowed else None,
+                win_prefix=(sess._window_arrays()
+                            if windowed and sess._win else None),
+                lookup=eng._lookup,
+                layout=self.layout,
+            )
+            sp.set_metadata(windows=int(sched.xs["inst"].sum()))
+            # T_CG window bookkeeping — identical to
+            # CacheSession._feed_trace_jax
+            if windowed:
+                if sched.next_cg is not None:
+                    sess._next_cg = sched.next_cg
+                if sched.boundary_hit:
+                    sess._win = []
+                if sched.win_start < R:
+                    sess._win.append((
+                        np.array(items[sched.win_start:], dtype=np.int32,
+                                 copy=True),
+                        np.array(servers[sched.win_start:], dtype=np.int32,
+                                 copy=True),
+                    ))
+            sess._last_t = float(times[-1])
+            self._part = sched.final_partition
+            self._host_nreq += sched.n_requests
+            self._host_nitem += sched.n_item_requests
+            self._dispatched_total += R
+            dims = ej.schedule_dims(sched)
+            if self._dims is None or any(
+                    dims[k] > self._dims[k] for k in dims):
+                self._fix_dims(dims)
+            sched = ej.pad_schedule(sched, self._dims)
+        xs_j = self._put(sched.xs, chunk, R)
+        with obs.span("live.launch", chunk=chunk, requests=R):
+            fn = _compiled_live_step(
+                self._jeng._statics, eng.caching_charge, sched.const_dt)
+            before = ej.SCAN_TRACES
+            with jax.enable_x64(True):
+                self._carry, probe = fn(self._spec_j, self._carry, xs_j)
+            self.compiles += ej.SCAN_TRACES - before
         self._acc_dirty = True
+        self._enqueue(probe, chunk, R)
+
+    def _put(self, xs: dict, chunk: int, R: int) -> dict:
+        """The chunk's event tensors, host -> device."""
+        nbytes = sum(int(v.nbytes) for v in xs.values())
+        with obs.span("live.put", chunk=chunk, requests=R, bytes=nbytes):
+            with jax.enable_x64(True):
+                xs_j = {key: jnp.asarray(v) for key, v in xs.items()}
+        self._n["h2d_bytes"] += nbytes
+        return xs_j
+
+    def _enqueue(self, probe, chunk: int, R: int) -> None:
+        """Put the chunk on the ring; past ``ring`` chunks in flight,
+        block on the oldest (backpressure)."""
         self._probes.append(probe)
-        while len(self._probes) > self.ring:    # backpressure
-            self._probes.popleft().block_until_ready()
+        self._probe_ends.append(self._dispatched_total)
+        self._n["chunks"] += 1
+        self._n["requests"] += R
+        while len(self._probes) > self.ring:
+            t0 = _time.perf_counter()
+            with obs.span("live.ring_wait", chunk=chunk, requests=R,
+                          waits_on=chunk + 1 - len(self._probes)):
+                self._pop_ready()
+            self._n["ring_waits"] += 1
+            self._n["ring_wait_s"] += _time.perf_counter() - t0
+
+    def _pop_ready(self) -> None:
+        """Wait for the oldest in-flight chunk and take it off the ring."""
+        self._probes.popleft().block_until_ready()
+        self._ready_total = self._probe_ends.popleft()
 
     def _block(self) -> None:
         while self._probes:
-            self._probes.popleft().block_until_ready()
+            self._pop_ready()
 
     def _sync_costs(self) -> CostBreakdown:
         """Assign the device accumulator into the session's breakdown."""
