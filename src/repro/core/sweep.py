@@ -10,7 +10,10 @@ point serially.  :class:`SweepEngine` makes the SCENARIO the batch axis:
    size) share ONE host-built :class:`~repro.core.engine_jax.ReplaySchedule`
    — an alpha sweep runs clique generation once, not once per alpha,
    because the partition trajectory is a pure function of the trace and
-   the CGM knobs (never of prices or cache state, DESIGN.md §10);
+   the CGM knobs (never of prices or cache state, DESIGN.md §10).  The
+   trace part of that key is the trace's CONTENT (:func:`_trace_key`), so
+   points that each wrap the same log in their own ``Trace`` share too;
+   sharing lasts one ``run`` call (no schedule outlives it);
 3. scenarios sharing a schedule are stacked along a leading axis (cost
    spec + initial state) and replayed by ONE ``jax.vmap``'d call of the
    compiled scan, with the schedule's event tensors shared UNBATCHED
@@ -28,6 +31,7 @@ shards the scenario axis of each stacked group over a device mesh
 from __future__ import annotations
 
 import dataclasses
+import hashlib
 import time as _time
 from typing import Any, Sequence
 
@@ -145,6 +149,33 @@ def _nbytes(*trees) -> int:
                for a in (t.values() if isinstance(t, dict) else (t,)))
 
 
+def _digest(arrays, memo: dict) -> str:
+    """SHA-256 of the bytes of ``arrays`` (``None`` entries skipped),
+    memoised on the arrays' identities: ``memo`` lives for one
+    ``SweepEngine.run`` call, whose points keep every keyed array alive,
+    so an identity cannot be reused within it."""
+    ids = tuple(id(a) for a in arrays)
+    digest = memo.get(ids)
+    if digest is None:
+        h = hashlib.sha256()
+        for a in arrays:
+            if a is not None:
+                h.update(np.ascontiguousarray(a))
+        digest = memo[ids] = h.hexdigest()
+    return digest
+
+
+def _trace_key(trace, memo: dict) -> tuple:
+    """The content of ``trace`` as a schedule-sharing key: equal for equal
+    logs, whether or not they are one ``Trace`` object or share arrays.
+    Traces over the same arrays cost one digest per call (``memo``)."""
+    arrays = (trace.times, trace.servers, trace.items,
+              getattr(trace, "sizes", None))
+    return (trace.n, trace.m, trace.n_requests, trace.d_max,
+            tuple(None if a is None else a.dtype.str for a in arrays),
+            _digest(arrays, memo))
+
+
 def _cgm_key(policy) -> tuple:
     """The clique-generation-relevant knobs of a registry policy."""
     p = policy.params
@@ -217,11 +248,16 @@ class SweepEngine:
             prepared, dev_groups, groups, sh_groups = self._prepare(points)
 
         # -- build every distinct schedule on host --------------------------
+        # ``shared`` counts the points served by a schedule built for an
+        # earlier point of this call
         schedules: dict = {}
+        shared = 0
         for (skey, statics, charge), idxs in groups.items():
             g0 = prepared[idxs[0]]
             if skey in schedules:
+                shared += len(idxs)
                 continue
+            shared += len(idxs) - 1
             policy = g0["policy"]
             with obs.span("sweep.schedule") as sp:
                 part0 = (policy.initial_partition(g0["pt"].trace)
@@ -277,6 +313,7 @@ class SweepEngine:
                     "clique_sizes": schedule.final_partition.sizes(),
                 })
             n_shard_schedules += len(recs)
+            shared += len(idxs) - 1
             S_sh = len(recs)
             with obs.span("sweep.stage", lanes=len(idxs) * S_sh) as sp:
                 s0 = recs[0]["schedule"]
@@ -320,6 +357,7 @@ class SweepEngine:
                     batch_size=g0["bs"], hot_dims=hot_dims)
                 sp.set_metadata(steps=sched.nb, events=sched.B * sched.d)
             S = len(idxs)
+            shared += S - 1
             with obs.span("sweep.stage", lanes=S) as sp:
                 # compact-workspace cohort: repeated sweep calls over the
                 # same catalog ratchet (nb, B, d, h, W) through
@@ -450,6 +488,7 @@ class SweepEngine:
                                  + n_shard_schedules)
         call_span.set_metadata(
             schedules=self.last_n_schedules,
+            shared=shared,
             lanes=len(prepared),
             groups=len(dev_pending) + len(sh_pending) + len(pending))
 
@@ -537,6 +576,7 @@ class SweepEngine:
         from . import engine_jax as ej
 
         prepared = []
+        memo: dict = {}                  # array digests of this call
         for pt in points:
             shards = _shards_of(pt.trace)
             tr0 = shards[0] if shards is not None else pt.trace
@@ -557,17 +597,17 @@ class SweepEngine:
             bs = pt.batch_size or self.batch_size
             seed = getattr(policy, "seed_new_cliques", True)
             sizes_fp = (None if not model.uses_sizes
-                        else (id(env.item_sizes)
+                        else (_digest((env.item_sizes,), memo)
                               if env.item_sizes is not None else "unit"))
             if pt.policy in SHAREABLE_POLICIES:
-                tid = (tuple(id(tr) for tr in shards)
-                       if shards is not None else id(pt.trace))
+                tid = (tuple(_trace_key(tr, memo) for tr in shards)
+                       if shards is not None else _trace_key(pt.trace, memo))
                 skey = (tid, pt.policy, _cgm_key(policy), bs,
                         const_dt, model.uses_sizes, sizes_fp, seed)
             else:
-                skey = object()          # never shared
+                tid, skey = None, object()          # never shared
             prepared.append({
-                "pt": pt, "policy": policy, "spec": spec,
+                "pt": pt, "policy": policy, "spec": spec, "tid": tid,
                 "statics": statics, "skey": skey, "sizes_fp": sizes_fp,
                 "model": model, "env": env, "bs": bs, "seed": seed,
                 "shards": shards,
@@ -594,7 +634,7 @@ class SweepEngine:
                     or not cgm_jax.wants_device_cgm(
                         policy, pt.trace, pr["model"])):
                 continue
-            dkey = (id(pt.trace), cfg.t_cg, pr["bs"], pr["statics"],
+            dkey = (pr["tid"], cfg.t_cg, pr["bs"], pr["statics"],
                     pr["charge"], pr["model"].uses_sizes, pr["sizes_fp"],
                     pr["seed"], cfg.enable_split, cfg.enable_approx_merge)
             dev_groups.setdefault(dkey, []).append(i)

@@ -34,7 +34,7 @@ from repro.core import cliques_ref as oracle
 from repro.core import cgm_jax
 from repro.core.crm import build_window_crm
 from repro.core.engine_jax import JaxReplayEngine, run_policy_jax
-from repro.traces import SynthConfig, synth_trace
+from repro.traces import SynthConfig, Trace, synth_trace
 
 N_ITEMS = 48
 T_CG = 0.73
@@ -163,6 +163,25 @@ def test_fig7_sweep_zero_host_cgm_calls(trace):
         for f in ("transfer", "caching", "keepalive_rent", "total"):
             assert np.isclose(ref.costs.as_dict()[f], got.costs.as_dict()[f],
                               rtol=1e-9, atol=1e-9), f
+
+
+def test_fig7_sweep_own_traces_form_one_device_group(trace):
+    """A theta/gamma grid whose points each hold their own ``Trace`` over
+    one log still forms one device-CGM group with one schedule."""
+    pts = [SweepPoint("akpc", Trace(times=trace.times, servers=trace.servers,
+                                    items=trace.items, n=trace.n,
+                                    m=trace.m), _kw(th, g, 4))
+           for th in THETAS for g in GAMMAS]
+    eng = SweepEngine()
+    before = cliques_mod.CGM_CALLS
+    res = eng.run(pts)
+    assert cliques_mod.CGM_CALLS == before          # zero host CGM calls
+    assert eng.last_n_schedules == 1
+    for pt, got in zip(pts[:2], res[:2]):
+        ref = run_policy(get_policy(pt.policy, **pt.policy_kwargs), trace)
+        assert np.array_equal(got.clique_sizes, ref.clique_sizes)
+        assert np.isclose(ref.costs.total, got.costs.total,
+                          rtol=1e-9, atol=1e-9)
 
 
 def test_replay_routes_device_and_counter_flat(trace):

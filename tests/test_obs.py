@@ -8,7 +8,8 @@ Contracts under test, each on a real CPU profiler trace:
   oldest chunk; ``LiveServingEngine.stats`` agrees with the spans;
 * sweep — one ``akpc.sweep.call`` holds the prepare, schedule, stage and
   collect phases, and host clique generation (``akpc.cgm.window``) sits
-  inside the schedule build that asked for it;
+  inside the schedule build that asked for it; points whose traces are
+  equal in content share one schedule (``shared`` on the call span);
 * ``ServeFuture.done()`` follows the chunk that holds the submit's last
   request, not the whole ring;
 * the fused CGM step and the replay step carry their ``jax.named_scope``
@@ -147,27 +148,43 @@ def test_future_done_follows_its_chunk():
 # ---------------------------------------------------------------------------
 # sweep
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("shared_trace", [True, False])
-def test_sweep_spans(tmp_path, shared_trace):
+def _second_trace(tr, how):
+    """The second point's trace: ``tr`` itself, a fresh ``Trace`` over its
+    arrays, one over copies of them, or a copy with one request moved to
+    another server."""
+    if how == "same_object":
+        return tr
+    if how == "fresh_trace":
+        return Trace(times=tr.times, servers=tr.servers, items=tr.items,
+                     n=tr.n, m=tr.m)
+    servers = tr.servers.copy()
+    if how == "one_request_changed":
+        servers[400] = (servers[400] + 1) % tr.m
+    return Trace(times=tr.times.copy(), servers=servers,
+                 items=tr.items.copy(), n=tr.n, m=tr.m)
+
+
+@pytest.mark.parametrize("how, n_sched", [
+    ("same_object", 1), ("fresh_trace", 1), ("copied_arrays", 1),
+    ("one_request_changed", 2)])
+def test_sweep_spans(tmp_path, how, n_sched):
+    """Schedules are shared by trace content: equal logs share one,
+    a log that differs in one request gets its own."""
     tr = _trace(n_requests=800)
 
-    def point(alpha):
-        t = tr if shared_trace else Trace(
-            times=tr.times, servers=tr.servers, items=tr.items, n=tr.n,
-            m=tr.m)
+    def point(t, alpha):
         return SweepPoint("akpc", t, dict(
             params=CostParams(alpha=alpha), t_cg=T_CG, top_frac=1.0))
 
     eng = SweepEngine()
     with _recording(tmp_path):
-        res = eng.run([point(0.5), point(0.9)])
+        res = eng.run([point(tr, 0.5), point(_second_trace(tr, how), 0.9)])
     assert len(res) == 2
-    n_sched = 1 if shared_trace else 2
     assert eng.last_n_schedules == n_sched
     sp = _by_name(obs.read(str(tmp_path)))
     (call,) = sp["sweep.call"]
-    assert call[2] == {"points": 2, "schedules": n_sched, "lanes": 2,
-                       "groups": 1}
+    assert call[2] == {"points": 2, "schedules": n_sched,
+                       "shared": 2 - n_sched, "lanes": 2, "groups": 1}
     (prep,) = sp["sweep.prepare"]
     assert prep[2] == {"points": 2}
     scheds = sp["sweep.schedule"]

@@ -33,9 +33,10 @@ from repro.core import (
     run_policy,
     sweep_points,
 )
+from repro.core import sweep as sweep_mod
 from repro.core.cost import CostModel, register_cost_model
 from repro.core.engine_jax import run_policy_jax
-from repro.traces import SynthConfig, synth_trace
+from repro.traces import SynthConfig, Trace, synth_trace
 
 PARAMS = CostParams()
 T_CG = 0.73            # never divides the batch grid: windows split batches
@@ -225,6 +226,107 @@ def test_sweep_does_not_share_across_cgm_axes(trace):
     for pt, got in zip(pts, res):
         ref = run_policy(get_policy(pt.policy, **pt.policy_kwargs), trace)
         assert_same_costs(ref.costs, got.costs)
+
+
+def _copy_trace(tr, **changes):
+    """A fresh ``Trace`` over copies of ``tr``'s arrays, with ``changes``."""
+    fields = dict(times=tr.times.copy(), servers=tr.servers.copy(),
+                  items=tr.items.copy(), n=tr.n, m=tr.m,
+                  sizes=None if tr.sizes is None else tr.sizes.copy())
+    fields.update(changes)
+    return Trace(**fields)
+
+
+def assert_same_run(ref, got):
+    """Costs at 1e-9, counters, windows and partition exact."""
+    assert_same_costs(ref.costs, got.costs)
+    assert got.n_windows == ref.n_windows
+    assert np.array_equal(got.clique_sizes, ref.clique_sizes)
+
+
+def test_sweep_shares_schedules_across_equal_logs(trace):
+    """Points that each hold their own ``Trace`` over equal logs share one
+    schedule (sharing keys on content), and every lane still matches its
+    serial replay."""
+    pts = [
+        SweepPoint("akpc", _copy_trace(trace),
+                   dict(params=CostParams(alpha=a, rho=r), t_cg=T_CG,
+                        top_frac=TOP_FRAC))
+        for r in (0.5, 2.0) for a in (0.6, 0.8, 1.0)
+    ]
+    eng = SweepEngine()
+    res = eng.run(pts)
+    assert eng.last_n_schedules == 1
+    for pt, got in zip(pts, res):
+        ref = run_policy(get_policy(pt.policy, **pt.policy_kwargs), pt.trace)
+        assert_same_run(ref, got)
+    assert len({round(r.total, 6) for r in res}) == len(pts)
+
+
+def _one_change(tr, field):
+    """``tr`` with one request's time or server changed, or one item's
+    size."""
+    k = tr.n_requests // 2
+    if field == "time":
+        times = tr.times.copy()
+        times[k] = 0.5 * (times[k - 1] + times[k])
+        assert times[k] != tr.times[k]
+        return _copy_trace(tr, times=times)
+    if field == "server":
+        servers = tr.servers.copy()
+        servers[k] = (servers[k] + 1) % tr.m
+        return _copy_trace(tr, servers=servers)
+    sizes = tr.sizes.copy()
+    sizes[int(tr.items[k, 0])] *= 2.0
+    return _copy_trace(tr, sizes=sizes)
+
+
+@pytest.mark.parametrize("field", ["time", "server", "sizes"])
+def test_sweep_does_not_share_across_unequal_logs(trace, sized_trace, field):
+    """Logs that differ in one time, one server or the item sizes get a
+    schedule each (no false sharing), and each matches its serial
+    replay."""
+    base = sized_trace if field == "sizes" else trace
+    kw = dict(params=PARAMS, t_cg=T_CG, top_frac=TOP_FRAC)
+    if field == "sizes":
+        kw.update(cost_model="heterogeneous")
+    pts = [SweepPoint("akpc", tr, kw)
+           for tr in (base, _one_change(base, field))]
+    eng = SweepEngine()
+    res = eng.run(pts)
+    assert eng.last_n_schedules == 2
+    for pt, got in zip(pts, res):
+        ref = run_policy(get_policy(pt.policy, **pt.policy_kwargs), pt.trace)
+        assert_same_run(ref, got)
+
+
+def test_sweep_digests_each_array_set_once(trace, monkeypatch):
+    """A call hashes each distinct set of trace arrays once, however many
+    ``Trace`` objects wrap it."""
+    calls = []
+    real = sweep_mod.hashlib
+
+    class Counting:
+        @staticmethod
+        def sha256():
+            calls.append(1)
+            return real.sha256()
+
+    monkeypatch.setattr(sweep_mod, "hashlib", Counting)
+    copy = _copy_trace(trace)
+    pts = [
+        SweepPoint("akpc", Trace(times=tr.times, servers=tr.servers,
+                                 items=tr.items, n=tr.n, m=tr.m),
+                   dict(params=CostParams(alpha=a), t_cg=T_CG,
+                        top_frac=TOP_FRAC))
+        for tr in (trace, copy) for a in (0.6, 0.8, 1.0)
+    ]
+    eng = SweepEngine()
+    eng.run(pts)
+    assert len(calls) == 2                  # two array sets, six traces
+    assert eng.last_n_schedules == 1        # equal content: one schedule
+    eng.run(pts)
+    assert len(calls) == 4                  # the memo lasts one call
 
 
 def test_sweep_numpy_backend_and_convenience(trace):
