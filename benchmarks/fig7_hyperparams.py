@@ -130,7 +130,7 @@ def device_partitions(tr, t_cg, combos, top_frac):
         uses_sizes=False, item_sizes=None, schedule=sched)
     carry0 = {k: np.stack([v] * S) for k, v in carry1.items()}
     spec = {k: np.stack([v] * S) for k, v in jeng._spec.items()}
-    final, ofs = cgm_jax.run_cgm_schedule(
+    final, ofs, _ = cgm_jax.run_cgm_schedule(
         sched, spec, jeng._statics, cspec, carry0, None)
     return sched, final, ofs
 
@@ -290,7 +290,7 @@ def _perf_breakdown() -> dict:
                               cg=np.zeros_like(schedule.xs["cg"])))
 
         def run(s):
-            final, ofs = cgm_jax.run_cgm_schedule(
+            final, ofs, _ = cgm_jax.run_cgm_schedule(
                 s, jeng._spec, jeng._statics, cspec, carry0, None)
             return np.asarray(final["of"]), np.asarray(ofs)
 

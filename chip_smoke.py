@@ -192,21 +192,10 @@ def phase_cgm_sweep() -> dict:
         return dict(params=CostParams(theta=th, gamma=g, omega=om),
                     t_cg=t_cg, top_frac=SMOKE_TOP_FRAC)
 
-    def on_device(c):
-        pol = get_policy("akpc", **kw(*c))
-        pol.bind(tr.n, tr.m)
-        return cgm_jax.wants_device_cgm(pol, tr, get_cost_model(
-            "table1", CacheEnvironment.resolve(None, tr, pol.params)))
-
-    # lanes outside the merge-pruning regime (gamma at or below the
-    # (omega - 2) / omega density bar) need an (2n, 2n) merge space and
-    # route to the host CGM above n = 256; the grid keeps the others
-    n_grid = len(combos)
-    combos = [c for c in combos if on_device(c)]
     calls0 = cliques_mod.CGM_CALLS
     eng = SweepEngine(backend="jax")
     res = eng.run([SweepPoint("akpc", tr, kw(*c)) for c in combos])
-    if cliques_mod.CGM_CALLS != calls0:
+    if cliques_mod.CGM_CALLS != calls0 or eng.stats["cgm_host_points"]:
         raise AssertionError("the sweep ran the host CGM")
     worst = 0.0
     for c, r in zip(combos, res):
@@ -221,7 +210,7 @@ def phase_cgm_sweep() -> dict:
         raise AssertionError("; ".join(bad))
     return {"route": "device CGM in the vmapped sweep scan",
             "kernels": "mosaic", "points": len(combos),
-            "grid_points_on_host_cgm": n_grid - len(combos),
+            "merge_space": eng.stats["cgm_merge_space"],
             "schedules": eng.last_n_schedules, "h": int(sched.h),
             "windows": int(sched.boundary_steps.size),
             "partitions": "equal to cliques_ref at every window",

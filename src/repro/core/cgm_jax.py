@@ -82,6 +82,8 @@ from .engine_jax import (
 #: size — the (h, h) workspace and (2h, 2h) merge matrices stay cheap
 #: and the f32 edge counters stay exact for any h below this bound
 MAX_DEVICE_CGM_HOT = 2048
+#: the hot capacity h is padded up to a multiple of this many slots
+HOT_BUCKET = 32
 #: f32 exactness bound for the CRM / X integer counters
 _F32_EXACT = 1 << 24
 
@@ -100,7 +102,7 @@ def hot_capacity(n: int, max_slots: int, hot_dims) -> int:
         base = n if of_catalog else min(n, int(max_slots))
         need = max(need, min(n, int(max_slots),
                              max(1, int(round(base * float(frac))))))
-    return min(n, _bucket(need, 32, 32))
+    return min(n, _bucket(need, HOT_BUCKET, HOT_BUCKET))
 
 
 def _max_window_requests(trace, t_cg: float) -> int:
@@ -779,11 +781,15 @@ def _approx_merge(of, binary, hot_idx, valid_h, cspec, *, n, h,
     every iteration, so the row-major first-argmax over D breaks ties
     identically.  Under the pruning regime (omega > 2 and gamma above
     the density bar) at most h groups can be act, so ``scap = 2h``;
-    lanes that can fall outside it (the w/o-CS ablation) compile with
-    ``full_merge`` -> ``scap = 2n``.  D uses the sentinel -2.0 for
-    dead / non-act / diagonal entries; X is patched incrementally, one
-    row/col per merge (the PR-3 algebra), with the f32 add order of
-    the host (``(X[ai,ai] + X[aj,aj]) + 2.0 * X[ai,aj]``).
+    outside it (omega = 2, gamma at or below the bar, the w/o-CS
+    ablation) every group is act, so such lanes compile with
+    ``full_merge`` -> ``scap = 2n``, which routing keeps within the
+    bound the pruned space has (``wants_device_cgm``).
+    D uses the sentinel -2.0 for dead / non-act / diagonal entries; X
+    is patched incrementally, one row/col per merge (the pair's summed rows),
+    with the f32 add order of the host (``(X[ai,ai] + X[aj,aj]) + 2.0 *
+    X[ai,aj]``).  Returns the new slot map and the number of merges the
+    loop made.
     """
     if h * (h - 1) // 2 >= _F32_EXACT >> 1:
         raise ValueError(
@@ -900,7 +906,7 @@ def _approx_merge(of, binary, hot_idx, valid_h, cspec, *, n, h,
         D = D.at[t, :].set(dr).at[:, t].set(dr).at[t, t].set(-2.0)
         return (X, D, of2, sizes, act, alive, t + 1, n_act - 1)
 
-    _, _, of2, _, _, alive, _, _ = jax.lax.while_loop(
+    _, _, of2, _, _, alive, tail, _ = jax.lax.while_loop(
         cond, body, (X, D, of2, sizes, act, alive, tail0, n_act0))
 
     # host output order: cand-universe groups first (act survivors and
@@ -916,7 +922,7 @@ def _approx_merge(of, binary, hot_idx, valid_h, cspec, *, n, h,
         is_rest, (n + scap) + jnp.arange(n, dtype=jnp.int32),
         jnp.arange(n, dtype=jnp.int32))
     keys = jnp.concatenate([key_m, key_p])           # (scap + n,)
-    return _dense_rank(keys[of2])
+    return _dense_rank(keys[of2]), tail - tail0
 
 
 def _install_partition_device(carry, of_new, now, dt, *, n, seed_new):
@@ -971,7 +977,8 @@ def _cgm_boundary(carry, now, cspec, dt, item_sizes, *, n, m, h, wcap,
     engine's ``install_partition``, then resets the window counters and
     rolls the compact binary CRM + hot index map into the prev-CRM
     carry slots.  All boundary tensors are (h, h) / (scap, scap) —
-    nothing n^2 is ever materialised.
+    nothing n^2 is ever materialised.  Returns the new carry and the
+    approximate merge's iteration count (0 without the merge).
     """
     with jax.named_scope("crm"):
         hot_idx, valid_h, lut, raw, norm, binary = _window_crm_device(
@@ -1007,9 +1014,10 @@ def _cgm_boundary(carry, now, cspec, dt, item_sizes, *, n, m, h, wcap,
         with jax.named_scope("split"):
             of = _split_oversized(
                 of, gsize, W, lut, cspec, n=n, h=h, gcap=gcap)
+    merge_iters = jnp.zeros((), jnp.int32)
     if enable_acm:
         with jax.named_scope("merge"):
-            of = _approx_merge(
+            of, merge_iters = _approx_merge(
                 of, binary, hot_idx, valid_h, cspec, n=n, h=h,
                 use_kernels=use_kernels, full_merge=full_merge)
 
@@ -1025,7 +1033,7 @@ def _cgm_boundary(carry, now, cspec, dt, item_sizes, *, n, m, h, wcap,
     )
     if uses_sizes:
         out["vol"] = jnp.zeros(n + 1, jnp.float64).at[of].add(item_sizes)
-    return out
+    return out, merge_iters
 
 
 # ---------------------------------------------------------------------------
@@ -1189,7 +1197,7 @@ def _cgm_replay_impl(spec, cspec, init, xs, item_sizes, *, kind, charge,
         # the preceding steps (``x["cg"]`` comes from the shared xs, so
         # under vmap the predicate stays unbatched and cond stays cond)
         with jax.named_scope("cgm_boundary"):
-            carry = jax.lax.cond(
+            carry, merge_iters = jax.lax.cond(
                 x["cg"],
                 lambda c: _cgm_boundary(
                     c, x["now"], cspec, dt, item_sizes, n=n, m=m, h=h,
@@ -1197,7 +1205,7 @@ def _cgm_replay_impl(spec, cspec, init, xs, item_sizes, *, kind, charge,
                     enable_split=enable_split, enable_acm=enable_acm,
                     seed_new=seed_new, use_kernels=use_kernels, gcap=gcap,
                     full_merge=full_merge),
-                lambda c: c,
+                lambda c: (c, jnp.zeros((), jnp.int32)),
                 carry)
         with jax.named_scope("window_accumulate"):
             carry = _accumulate_window(carry, x, n=n, m=m)
@@ -1205,7 +1213,8 @@ def _cgm_replay_impl(spec, cspec, init, xs, item_sizes, *, kind, charge,
             carry = _event_step(
                 carry, x, spec, kind=kind, charge=charge,
                 uses_sizes=uses_sizes, item_sizes=item_sizes, n=n, m=m)
-        return carry, carry["of"]
+        # per step: the slot map and the boundary's merge iterations
+        return carry, (carry["of"], merge_iters)
 
     return jax.lax.scan(step, init, xs)
 
@@ -1268,7 +1277,7 @@ def init_cgm_carry(state, prev_crm, win_prefix, *, n, m, uses_sizes,
     dbuf = 1 if dbuf is None else int(dbuf)
     prev_nh = int(prev_crm.hot_items.size) if prev_crm is not None else 0
     if prev_nh:
-        h = min(n, max(h, _bucket(prev_nh, 32, 32)))
+        h = min(n, max(h, _bucket(prev_nh, HOT_BUCKET, HOT_BUCKET)))
 
     E0, a0 = state_to_device(state, n)
     of0 = np.asarray(state.partition.clique_of, np.int32)
@@ -1326,6 +1335,16 @@ def init_cgm_carry(state, prev_crm, win_prefix, *, n, m, uses_sizes,
     return carry
 
 
+def lane_prunes(cspec) -> np.ndarray:
+    """Per lane of ``cspec``: whether its approximate merge prunes to
+    groups with a live hot member (omega > 2 and gamma above the
+    (omega - 2) / omega density bar), which bounds its act space by h."""
+    om = np.atleast_1d(np.asarray(cspec["omega"], np.int64))
+    gam = np.atleast_1d(np.asarray(cspec["gamma"], np.float64))
+    omf = om.astype(np.float64)
+    return (om > 2) & (gam > (omf - 2.0) / omf)
+
+
 def cgm_loop_statics(cspec, carry0, *, enable_split, enable_acm):
     """The two compile-time loop capacities derived from runtime spec.
 
@@ -1340,10 +1359,7 @@ def cgm_loop_statics(cspec, carry0, *, enable_split, enable_acm):
       the act space must hold all n groups (scap = 2n) instead of 2h.
     """
     om = np.atleast_1d(np.asarray(cspec["omega"], np.int64))
-    gam = np.atleast_1d(np.asarray(cspec["gamma"], np.float64))
-    omf = om.astype(np.float64)
-    prune = (om > 2) & (gam > (omf - 2.0) / omf)
-    full_merge = bool(enable_acm) and not bool(prune.all())
+    full_merge = bool(enable_acm) and not bool(lane_prunes(cspec).all())
     cnt_max = int(np.asarray(carry0["cnt"]).max())
     gcap = _bucket(max(int(om.max()), cnt_max, 2), 8, 8)
     del enable_split
@@ -1353,7 +1369,9 @@ def cgm_loop_statics(cspec, carry0, *, enable_split, enable_acm):
 def run_cgm_schedule(schedule, spec, statics, cspec, carry0, item_sizes, *,
                      charge="requested", enable_split=True, enable_acm=True,
                      seed_new=True, use_kernels=None, block=True):
-    """Execute one CGM schedule; returns (final_carry, per-step slot maps).
+    """Execute one CGM schedule; returns the final carry, the per-step
+    slot maps and the per-step approximate-merge iteration counts (0 off
+    the boundary steps).
 
     ``spec``/``cspec``/``carry0`` may carry a leading scenario axis (the
     fig7 grid); the schedule and item sizes stay shared unbatched.
@@ -1377,10 +1395,11 @@ def run_cgm_schedule(schedule, spec, statics, cspec, carry0, item_sizes, *,
             jnp.asarray(item_sizes, jnp.float64)
             if item_sizes is not None
             else jnp.ones(schedule.n, jnp.float64))
-        final, ofs = fn(spec_j, cspec_j, init_j, xs_j, sz_j)
+        final, (ofs, merge_iters) = fn(spec_j, cspec_j, init_j, xs_j, sz_j)
         if not block:
-            return final, ofs
-        return {k: np.asarray(v) for k, v in final.items()}, np.asarray(ofs)
+            return final, ofs, merge_iters
+        return ({k: np.asarray(v) for k, v in final.items()},
+                np.asarray(ofs), np.asarray(merge_iters))
 
 
 def partition_from_of(n: int, of: np.ndarray) -> CliquePartition:
@@ -1438,7 +1457,7 @@ def replay_cgm(jeng, policy, trace, *, t_cg, batch_size=None, next_cg0=None,
         eng.state, getattr(policy, "_prev_crm", None), win_prefix,
         n=trace.n, m=trace.m, uses_sizes=uses_sizes, item_sizes=item_sizes,
         layout=getattr(jeng, "layout", None), schedule=schedule)
-    final, ofs = run_cgm_schedule(
+    final, ofs, _ = run_cgm_schedule(
         schedule, jeng._spec, jeng._statics, cspec, carry0, item_sizes,
         charge=eng.caching_charge,
         enable_split=cfg.enable_split,
@@ -1468,10 +1487,12 @@ def wants_device_cgm(policy, trace, model) -> bool:
     — the boundary workspace is sized by the padded hot capacity ``h``
     (window working set x ``top_frac``), so auto admits any catalog
     whose ``h`` stays under ``MAX_DEVICE_CGM_HOT`` and whose window
-    request counts keep the f32 co-occurrence counters exact.  Lanes
-    that run the approximate merge OUTSIDE the pruning regime (the
-    w/o-CS ablation) still need a (2n, 2n) merge space, so those stay
-    small-catalog only.
+    request counts keep the f32 co-occurrence counters exact.  A lane
+    that runs the approximate merge OUTSIDE the pruning regime (omega =
+    2, gamma at or below (omega - 2) / omega, the w/o-CS ablation) needs
+    a (2n, 2n) merge space instead of (2h, 2h), so auto admits it while
+    n stays under ``MAX_DEVICE_CGM_HOT``: its 2n then stays within the
+    bound the pruned space 2h already has.
     """
     mode = os.environ.get("REPRO_JAX_CGM", "auto").strip().lower()
     if mode in ("off", "0"):
@@ -1503,9 +1524,7 @@ def wants_device_cgm(policy, trace, model) -> bool:
     if hot_capacity(trace.n, smax, policy_hot_dims(policy)) \
             > MAX_DEVICE_CGM_HOT:
         return False
-    if cfg.enable_approx_merge:
-        omega = int(cfg.params.omega) if cfg.enable_split else int(trace.n)
-        prune = omega > 2 and float(cfg.params.gamma) > (omega - 2) / omega
-        if not prune and trace.n > 256:
-            return False
+    if cfg.enable_approx_merge and trace.n > MAX_DEVICE_CGM_HOT \
+            and not lane_prunes(cgm_spec(cfg, cfg.params, trace.n))[0]:
+        return False
     return True

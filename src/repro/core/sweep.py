@@ -143,6 +143,16 @@ def _pad_cohort(ej, ckey, recs: list) -> None:
             r["schedule"] = ej.pad_schedule(r["schedule"], dims)
 
 
+def _coarse(x: int) -> int:
+    """``x`` rounded up to a sixteenth of its power of two (at most 6.25%
+    more).  A device-CGM group's window buffer ``wcap`` tracks the busiest
+    window of its log and so moves by a few rows from log to log, while
+    the fused scan at a 2,000-item catalog takes minutes to compile: at
+    this grain the logs of one deployment share one compiled shape."""
+    step = 1 << max(0, x.bit_length() - 5)
+    return -(-x // step) * step
+
+
 def _nbytes(*trees) -> int:
     """Bytes of the arrays in ``trees`` (arrays, or dicts of arrays)."""
     return sum(int(a.nbytes) for t in trees
@@ -174,6 +184,37 @@ def _trace_key(trace, memo: dict) -> tuple:
     return (trace.n, trace.m, trace.n_requests, trace.d_max,
             tuple(None if a is None else a.dtype.str for a in arrays),
             _digest(arrays, memo))
+
+
+def _walks_cgm(policy) -> bool:
+    """An AKPC-family policy that generates cliques at window boundaries."""
+    return (getattr(policy, "config", None) is not None
+            and policy.t_cg is not None)
+
+
+def _merge_groups(cspecs: list, n: int, h: int, enable_acm: bool) -> list:
+    """Split one device-CGM group's lanes by the merge space they
+    compile: ``[(positions into cspecs, scap)]``.  A lane that merges
+    outside the pruning regime needs ``scap = 2n``; a pruning lane needs
+    2h.  Where h is within one hot-capacity bucket of n, every lane stays
+    in one program at 2n; otherwise the non-pruning lanes run as a group
+    of their own and the pruning lanes keep 2h."""
+    from . import cgm_jax
+
+    prunes = cgm_jax.lane_prunes(
+        {k: np.stack([np.asarray(c[k]) for c in cspecs]) for k in cspecs[0]})
+    lanes = np.arange(len(cspecs))
+    if not enable_acm or prunes.all():
+        return [(lanes.tolist(), 2 * h)]
+    if not prunes.any() or n - h <= cgm_jax.HOT_BUCKET:
+        return [(lanes.tolist(), 2 * n)]
+    return [(lanes[prunes].tolist(), 2 * h),
+            (lanes[~prunes].tolist(), 2 * n)]
+
+
+#: what ``SweepEngine.stats`` counts of each call's device-CGM lanes
+CGM_STATS = ("cgm_lanes", "cgm_host_points", "cgm_hot", "cgm_merge_space",
+             "cgm_boundaries", "cgm_window_rows", "cgm_merge_iters")
 
 
 def _cgm_key(policy) -> tuple:
@@ -215,9 +256,17 @@ class SweepEngine:
         self.layout = layout
         #: schedule-dedup stats of the most recent run
         self.last_n_schedules = 0
+        #: the most recent call's clique generation, by route: points run
+        #: as device-CGM lanes and points of a clique-generating policy
+        #: left to a host walk; of the device lanes, the largest padded
+        #: hot capacity h, merge space ``scap`` and window buffer
+        #: ``wcap``, boundaries x lanes, and approximate-merge iterations
+        #: summed over lanes and boundaries (a device counter)
+        self.stats = dict.fromkeys(CGM_STATS, 0)
 
     # ------------------------------------------------------------------
     def run(self, points: Sequence[SweepPoint]) -> list[RunResult]:
+        self.stats = dict.fromkeys(CGM_STATS, 0)
         with obs.span("sweep.call", points=len(points)) as sp:
             if self.backend == "numpy":
                 self.last_n_schedules = len(points)
@@ -226,6 +275,8 @@ class SweepEngine:
 
     def _run_numpy(self, pt: SweepPoint) -> RunResult:
         shards = _shards_of(pt.trace)
+        policy = get_policy(pt.policy, **pt.policy_kwargs)
+        self.stats["cgm_host_points"] += _walks_cgm(policy)
         if shards is not None:
             return _merge_shard_results([
                 run_policy(
@@ -233,8 +284,7 @@ class SweepEngine:
                     batch_size=pt.batch_size or self.batch_size)
                 for tr in shards])
         return run_policy(
-            get_policy(pt.policy, **pt.policy_kwargs), pt.trace,
-            batch_size=pt.batch_size or self.batch_size)
+            policy, pt.trace, batch_size=pt.batch_size or self.batch_size)
 
     # ------------------------------------------------------------------
     def _run_jax(self, points, call_span) -> list[RunResult]:
@@ -246,6 +296,11 @@ class SweepEngine:
 
         with obs.span("sweep.prepare", points=len(points)):
             prepared, dev_groups, groups, sh_groups = self._prepare(points)
+        stats = self.stats
+        stats["cgm_host_points"] = sum(
+            _walks_cgm(prepared[i]["policy"])
+            for idxs in (*groups.values(), *sh_groups.values())
+            for i in idxs)
 
         # -- build every distinct schedule on host --------------------------
         # ``shared`` counts the points served by a schedule built for an
@@ -341,6 +396,8 @@ class SweepEngine:
             sh_pending.append((idxs, recs, acc, t0))
 
         # -- dispatch device-CGM groups first (non-blocking) ----------------
+        # one schedule per group; the lanes dispatch as one program, or
+        # two where the group's merge spaces differ (_merge_groups)
         dev_pending = []
         for idxs in dev_groups.values():
             g0 = prepared[idxs[0]]
@@ -351,50 +408,70 @@ class SweepEngine:
             item_sizes = g0["env"].sizes() if uses_sizes else None
             hot_dims = [cgm_jax.policy_hot_dims(prepared[i]["policy"])[0]
                         for i in idxs]
+            cspecs = [
+                cgm_jax.cgm_spec(prepared[i]["policy"].config,
+                                 prepared[i]["policy"].config.params, n)
+                for i in idxs
+            ]
             with obs.span("sweep.schedule") as sp:
                 sched = cgm_jax.build_cgm_schedule(
                     trace, cfg0.t_cg, uses_sizes=uses_sizes,
                     batch_size=g0["bs"], hot_dims=hot_dims)
-                sp.set_metadata(steps=sched.nb, events=sched.B * sched.d)
-            S = len(idxs)
-            shared += S - 1
-            with obs.span("sweep.stage", lanes=S) as sp:
                 # compact-workspace cohort: repeated sweep calls over the
                 # same catalog ratchet (nb, B, d, h, W) through
                 # _COHORT_DIMS so the CGM scan compiles once per cohort,
                 # not once per call shape
                 ckey_cgm = ("cgm", n, m_srv, sched.uses_sizes)
                 dims = ej.schedule_dims(sched)
+                dims["W"] = _coarse(dims["W"])
                 cached = _COHORT_DIMS.get(ckey_cgm)
                 if cached is not None:
                     dims = {k: max(dims[k], cached[k]) for k in dims}
                 _COHORT_DIMS[ckey_cgm] = dims
                 sched = ej.pad_schedule(sched, dims)
-                carry1 = cgm_jax.init_cgm_carry(
-                    CacheState.fresh(CliquePartition.singletons(n), m_srv),
-                    None, None, n=n, m=m_srv, uses_sizes=uses_sizes,
-                    item_sizes=item_sizes, layout=self.layout,
-                    schedule=sched)
-                spec = {
-                    k: np.stack([prepared[i]["spec"][k] for i in idxs])
-                    for k in g0["spec"]
-                }
-                cspecs = [
-                    cgm_jax.cgm_spec(prepared[i]["policy"].config,
-                                     prepared[i]["policy"].config.params, n)
-                    for i in idxs
-                ]
-                cspec = {k: np.stack([np.asarray(c[k]) for c in cspecs])
-                         for k in cspecs[0]}
-                carry0 = {k: np.stack([v] * S) for k, v in carry1.items()}
-                sp.set_metadata(bytes=_nbytes(spec, cspec, carry0, sched.xs))
-                t0g = _time.perf_counter()
-                final, ofs = cgm_jax.run_cgm_schedule(
-                    sched, spec, g0["statics"], cspec, carry0, item_sizes,
-                    charge=g0["charge"], enable_split=cfg0.enable_split,
-                    enable_acm=cfg0.enable_approx_merge,
-                    seed_new=g0["seed"], block=False)
-            dev_pending.append((idxs, sched, final, ofs, t0g))
+                subs = _merge_groups(cspecs, n, sched.h,
+                                     cfg0.enable_approx_merge)
+                scap = max(sc for _, sc in subs)
+                sp.set_metadata(steps=sched.nb, events=sched.B * sched.d,
+                                h=sched.h, merge_space=scap)
+            shared += len(idxs) - 1
+            nbd = int(sched.boundary_steps.size)
+            stats["cgm_lanes"] += len(idxs)
+            stats["cgm_boundaries"] += nbd * len(idxs)
+            stats["cgm_hot"] = max(stats["cgm_hot"], sched.h)
+            stats["cgm_window_rows"] = max(stats["cgm_window_rows"],
+                                           sched.wcap)
+            stats["cgm_merge_space"] = max(stats["cgm_merge_space"], scap)
+            for sub, _ in subs:
+                lane_idx = [idxs[j] for j in sub]
+                S = len(sub)
+                with obs.span("sweep.stage", lanes=S) as sp:
+                    carry1 = cgm_jax.init_cgm_carry(
+                        CacheState.fresh(CliquePartition.singletons(n),
+                                         m_srv),
+                        None, None, n=n, m=m_srv, uses_sizes=uses_sizes,
+                        item_sizes=item_sizes, layout=self.layout,
+                        schedule=sched)
+                    spec = {
+                        k: np.stack([prepared[i]["spec"][k]
+                                     for i in lane_idx])
+                        for k in g0["spec"]
+                    }
+                    cspec = {k: np.stack([np.asarray(cspecs[j][k])
+                                          for j in sub])
+                             for k in cspecs[0]}
+                    carry0 = {k: np.stack([v] * S) for k, v in carry1.items()}
+                    sp.set_metadata(bytes=_nbytes(spec, cspec, carry0,
+                                                  sched.xs))
+                    t0g = _time.perf_counter()
+                    final, ofs, iters = cgm_jax.run_cgm_schedule(
+                        sched, spec, g0["statics"], cspec, carry0,
+                        item_sizes, charge=g0["charge"],
+                        enable_split=cfg0.enable_split,
+                        enable_acm=cfg0.enable_approx_merge,
+                        seed_new=g0["seed"], block=False)
+                dev_pending.append((lane_idx, sched, final, ofs, iters, t0g))
+        n_dev_schedules = len(dev_groups)
 
         # -- align schedule shapes so each (n, m, path) cohort compiles the
         # device scan exactly once, then dispatch every group WITHOUT
@@ -484,21 +561,24 @@ class SweepEngine:
                 pending.append((lane_idx, lane_recs, acc, t0))
             sp.set_metadata(lanes=sum(len(p[0]) for p in pending),
                             bytes=staged)
-        self.last_n_schedules = (len(schedules) + len(dev_pending)
+        self.last_n_schedules = (len(schedules) + n_dev_schedules
                                  + n_shard_schedules)
         call_span.set_metadata(
             schedules=self.last_n_schedules,
             shared=shared,
             lanes=len(prepared),
-            groups=len(dev_pending) + len(sh_pending) + len(pending))
+            groups=len(dev_pending) + len(sh_pending) + len(pending),
+            cgm_lanes=stats["cgm_lanes"],
+            cgm_host=stats["cgm_host_points"])
 
         # -- collect (blocks on the device results) -------------------------
         with obs.span("sweep.collect"):
             results: list[RunResult | None] = [None] * len(prepared)
-            for idxs, sched, final, ofs, t0g in dev_pending:
+            for idxs, sched, final, ofs, iters, t0g in dev_pending:
                 with obs.span("sweep.wait"):
                     final = {k: np.asarray(v) for k, v in final.items()}
                     ofs = np.asarray(ofs)
+                    stats["cgm_merge_iters"] += int(np.asarray(iters).sum())
                 wall = _time.perf_counter() - t0g
                 nbd = int(sched.boundary_steps.size)
                 for lane, i in enumerate(idxs):

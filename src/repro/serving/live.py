@@ -631,7 +631,7 @@ class LiveServingEngine:
                 *self._cgm_statics)
             before = cgm_jax.SCAN_TRACES
             with jax.enable_x64(True):
-                self._cgm_carry, ofs = fn(
+                self._cgm_carry, (ofs, _) = fn(
                     self._spec_j, self._cspec_j, self._cgm_carry, xs_j,
                     self._sz_j)
             self.compiles += cgm_jax.SCAN_TRACES - before

@@ -32,6 +32,7 @@ from repro.core import (
 from repro.core import cliques as cliques_mod
 from repro.core import cliques_ref as oracle
 from repro.core import cgm_jax
+from repro.core import sweep as sweep_mod
 from repro.core.crm import build_window_crm
 from repro.core.engine_jax import JaxReplayEngine, run_policy_jax
 from repro.traces import SynthConfig, Trace, synth_trace
@@ -60,7 +61,7 @@ def _kw(theta, gamma, omega, **extra):
 
 
 def _oracle_trajectory(trace, theta, gamma, omega, *, enable_split=True,
-                       enable_acm=True, t_cg=T_CG):
+                       enable_acm=True, t_cg=T_CG, top_frac=TOP_FRAC):
     """The frozen-oracle partition at every T_CG boundary, walking the
     trace exactly as ``ReplayEngine.replay`` / ``build_cgm_schedule`` do."""
     times = trace.times
@@ -75,7 +76,7 @@ def _oracle_trajectory(trace, theta, gamma, omega, *, enable_split=True,
             t = float(times[pos])
             crm = build_window_crm(
                 trace.items[win_start:pos], trace.n, theta,
-                top_frac=TOP_FRAC)
+                top_frac=top_frac)
             prev = oracle.generate_cliques(
                 prev, prev_crm, crm, trace.n, omega, gamma,
                 enable_split=enable_split, enable_approx_merge=enable_acm)
@@ -118,7 +119,7 @@ def test_device_partitions_match_oracle_fig7_grid(trace):
         uses_sizes=False, item_sizes=None, schedule=sched)
     carry0 = {k: np.stack([v] * S) for k, v in carry1.items()}
     spec = {k: np.stack([v] * S) for k, v in jeng._spec.items()}
-    final, ofs = cgm_jax.run_cgm_schedule(
+    final, ofs, _ = cgm_jax.run_cgm_schedule(
         sched, spec, jeng._statics, cspec, carry0, None)
     for lane, (th, g, om) in enumerate(combos):
         want = _oracle_trajectory(trace, th, g, om)
@@ -243,11 +244,17 @@ def test_wants_device_cgm_gating(trace, monkeypatch):
     assert cgm_jax.wants_device_cgm(pol, big, big_model)
     monkeypatch.delenv("REPRO_JAX_CGM")
     monkeypatch.undo()
-    # non-prune approximate-merge lanes still need the (2n, 2n) merge
-    # space, so they stay small-catalog only (w/o-CS ablation regime)
+    # non-prune approximate-merge lanes need the (2n, 2n) merge space:
+    # admitted while n stays within the bound the pruned (2h, 2h) space
+    # has, refused above it
     soft = get_policy("akpc", **_kw(0.2, 0.4, 4))
     soft.bind(big.n, big.m)
+    assert big.n <= cgm_jax.MAX_DEVICE_CGM_HOT
+    assert cgm_jax.wants_device_cgm(soft, big, big_model)
+    monkeypatch.setattr(cgm_jax, "MAX_DEVICE_CGM_HOT", big.n - 1)
     assert not cgm_jax.wants_device_cgm(soft, big, big_model)
+    assert cgm_jax.wants_device_cgm(pol, big, big_model)  # pruning lane
+    monkeypatch.undo()
     soft.bind(trace.n, trace.m)
     assert cgm_jax.wants_device_cgm(soft, trace, model)
 
@@ -311,7 +318,7 @@ def test_device_cgm_with_kernels_interpret(trace):
     carry0 = cgm_jax.init_cgm_carry(
         jeng.engine.state, None, None, n=trace.n, m=trace.m,
         uses_sizes=False, item_sizes=None, schedule=sched)
-    final, _ = cgm_jax.run_cgm_schedule(
+    final, _, _ = cgm_jax.run_cgm_schedule(
         sched, jeng._spec, jeng._statics, cspec, carry0, None,
         use_kernels=True)
     ref = run_policy(get_policy("akpc", **_kw(0.2, 0.85, 4)), trace)
@@ -358,7 +365,7 @@ def test_big_catalog_chained_parity_vs_oracle(big_trace):
     carry0 = cgm_jax.init_cgm_carry(
         jeng.engine.state, None, None, n=trace.n, m=trace.m,
         uses_sizes=False, item_sizes=None, schedule=sched)
-    final, ofs = cgm_jax.run_cgm_schedule(
+    final, ofs, _ = cgm_jax.run_cgm_schedule(
         sched, jeng._spec, jeng._statics, cspec, carry0, None)
     want = _oracle_trajectory(trace, 0.2, 0.85, 4, t_cg=T_CG_BIG)
     assert len(want) == sched.boundary_steps.size
@@ -432,3 +439,98 @@ def test_window_crm_f32_exact_guard():
         carry, cspec)
     assert out[3].shape == (h, h)                  # raw CRM
     assert out[5].shape == (h, h)                  # binary CRM
+
+
+# ---------------------------------------------------------------------------
+# merge lanes outside the pruning regime beside pruning lanes (DESIGN §15)
+# ---------------------------------------------------------------------------
+#: (theta, gamma, omega): two lanes outside the merge-pruning regime
+#: (gamma at the (omega - 2) / omega bar, and omega = 2) and two inside it
+MIXED_GRID = [(0.2, 0.6, 5), (0.2, 0.85, 2), (0.2, 0.85, 5), (0.1, 0.9, 4)]
+
+
+@pytest.mark.parametrize("top_frac, near_n", [(1.0, True), (0.25, False)])
+def test_sweep_runs_non_pruning_lanes_on_device(monkeypatch, top_frac,
+                                                near_n):
+    """Non-pruning merge lanes run as device-CGM lanes of one sweep call
+    beside pruning ones at n = 320: partitions equal the oracle at every
+    boundary, counters and costs equal the numpy engine, and no point
+    walks the host CGM.  Where the hot capacity h is within one bucket
+    of n, all lanes share one program at scap = 2n; where h is well
+    below n, the pruning lanes keep scap = 2h and the others run as a
+    group of their own at 2n."""
+    t_cg = 1.5
+    trace = synth_trace(SynthConfig(
+        kind="netflix", n_items=320, n_servers=6, n_requests=2400,
+        t_max=6.0, bundle_cover=1.0, bundle_zipf=0.7, seed=11))
+    n = trace.n
+
+    def kw(th, g, om):
+        return dict(params=CostParams(theta=th, gamma=g, omega=om),
+                    t_cg=t_cg, top_frac=top_frac)
+
+    runs = []
+    run = cgm_jax.run_cgm_schedule
+
+    def spy(schedule, spec, statics, cspec, *a, **k):
+        out = run(schedule, spec, statics, cspec, *a, **k)
+        runs.append((schedule, cspec, out))
+        return out
+
+    monkeypatch.setattr(cgm_jax, "run_cgm_schedule", spy)
+    # no padded dims carried over from another sweep of this catalog
+    monkeypatch.setattr(sweep_mod, "_COHORT_DIMS", {})
+    eng = SweepEngine()
+    before = cliques_mod.CGM_CALLS
+    res = eng.run([SweepPoint("akpc", trace, kw(*c)) for c in MIXED_GRID])
+    assert cliques_mod.CGM_CALLS == before          # zero host CGM calls
+    st = eng.stats
+    assert st["cgm_lanes"] == len(MIXED_GRID) and st["cgm_host_points"] == 0
+    sched = runs[0][0]
+    nbd = int(sched.boundary_steps.size)
+    assert nbd >= 2                                 # chained windows
+    assert st["cgm_boundaries"] == nbd * len(MIXED_GRID)
+    assert (st["cgm_hot"], st["cgm_window_rows"]) == (sched.h, sched.wcap)
+    assert st["cgm_merge_space"] == 2 * n
+    assert (n - sched.h <= cgm_jax.HOT_BUCKET) == near_n
+    # one program per merge space, each at the space its lanes need
+    lanes_of = []
+    for schedule, cspec, _ in runs:
+        assert schedule is sched                   # one shared schedule
+        prunes = cgm_jax.lane_prunes(cspec)
+        _, full = cgm_jax.cgm_loop_statics(
+            cspec, {"cnt": np.ones(1)}, enable_split=True, enable_acm=True)
+        if near_n:
+            assert full and not prunes.all()
+        else:
+            assert full == (not prunes.any())
+            assert prunes.all() or not prunes.any()
+        lanes_of.append(list(zip(np.asarray(cspec["gamma"]).tolist(),
+                                 np.asarray(cspec["omega"]).tolist())))
+    assert len(runs) == (1 if near_n else 2)
+    iters = 0
+    for schedule, cspec, (final, ofs, merge_iters) in runs:
+        ofs = np.asarray(ofs)
+        iters += int(np.asarray(merge_iters).sum())
+        for lane in range(ofs.shape[0]):
+            th = float(np.asarray(cspec["theta"])[lane])
+            g = float(np.asarray(cspec["gamma"])[lane])
+            om = int(np.asarray(cspec["omega"])[lane])
+            want = _oracle_trajectory(trace, th, g, om, t_cg=t_cg,
+                                      top_frac=top_frac)
+            assert len(want) == nbd
+            for w, (b, ref_of) in enumerate(
+                    zip(schedule.boundary_steps, want)):
+                assert np.array_equal(ofs[lane, int(b)], ref_of), \
+                    f"theta={th} gamma={g} omega={om} window={w}"
+    assert st["cgm_merge_iters"] == iters > 0
+    for c, got in zip(MIXED_GRID, res):
+        ref = run_policy(get_policy("akpc", **kw(*c)), trace)
+        assert got.n_windows == ref.n_windows == nbd
+        assert np.array_equal(got.clique_sizes, ref.clique_sizes)
+        a, b = ref.costs.as_dict(), got.costs.as_dict()
+        for f in ("n_requests", "n_item_requests", "n_misses", "n_hits",
+                  "items_transferred"):
+            assert a[f] == b[f], f
+        for f in ("transfer", "caching", "keepalive_rent", "total"):
+            assert np.isclose(a[f], b[f], rtol=1e-9, atol=0.0), f

@@ -184,7 +184,8 @@ def test_sweep_spans(tmp_path, how, n_sched):
     sp = _by_name(obs.read(str(tmp_path)))
     (call,) = sp["sweep.call"]
     assert call[2] == {"points": 2, "schedules": n_sched,
-                       "shared": 2 - n_sched, "lanes": 2, "groups": 1}
+                       "shared": 2 - n_sched, "lanes": 2, "groups": 1,
+                       "cgm_lanes": 0, "cgm_host": 2}
     (prep,) = sp["sweep.prepare"]
     assert prep[2] == {"points": 2}
     scheds = sp["sweep.schedule"]
@@ -201,6 +202,28 @@ def test_sweep_spans(tmp_path, how, n_sched):
     phases = [prep, *scheds, stage, collect]
     assert all(_inside(p, [call]) for p in phases)
     assert [p[0] for p in phases] == sorted(p[0] for p in phases)
+
+
+def test_device_cgm_sweep_spans(tmp_path):
+    """Points that differ in clique knobs run as device-CGM lanes: the
+    call counts them, and the one schedule gives its hot capacity and
+    merge space, as ``SweepEngine.stats`` does."""
+    tr = _trace(n_requests=800)
+    eng = SweepEngine()
+    with _recording(tmp_path):
+        eng.run([SweepPoint("akpc", tr, dict(
+            params=CostParams(theta=th), t_cg=T_CG, top_frac=1.0))
+            for th in (0.1, 0.2, 0.3)])
+    st = eng.stats
+    assert (st["cgm_lanes"], st["cgm_host_points"]) == (3, 0)
+    sp = _by_name(obs.read(str(tmp_path)))
+    (call,) = sp["sweep.call"]
+    assert (call[2]["cgm_lanes"], call[2]["cgm_host"]) == (3, 0)
+    (sched,) = sp["sweep.schedule"]
+    assert sched[2]["h"] == st["cgm_hot"] > 0
+    assert sched[2]["merge_space"] == st["cgm_merge_space"] == 2 * st[
+        "cgm_hot"]
+    assert "cgm.window" not in sp                  # no host clique walk
 
 
 # ---------------------------------------------------------------------------

@@ -479,3 +479,13 @@ def test_sweep_shard_axis_rejects_mismatched_shards():
         SweepEngine().run([SweepPoint(
             "akpc", [a, b], dict(params=PARAMS, t_cg=T_CG,
                                  top_frac=TOP_FRAC))])
+
+
+def test_device_cgm_window_buffer_is_coarsely_bucketed():
+    """The device-CGM window buffer is padded by at most a sixteenth, so
+    the busiest windows of a 2,000-item deployment's logs (21,700-22,100
+    rows with a 1,664-request step) share one compiled shape."""
+    for x in range(1, 70_000, 7):
+        c = sweep_mod._coarse(x)
+        assert x <= c <= x + x / 16
+    assert {sweep_mod._coarse(x) for x in range(21_505, 22_529)} == {22_528}
