@@ -1245,6 +1245,22 @@ def kernels_on_backend() -> bool:
 # ---------------------------------------------------------------------------
 # host seam: carry init, execution, state/policy sync
 # ---------------------------------------------------------------------------
+def _window_fields(n, m, h, wcap, dbuf) -> dict:
+    """The carry fields that an empty window and no previous CRM hold as
+    one constant each: name -> (shape, dtype, value)."""
+    return {
+        "acc": ((N_ACC,), np.float64, 0),
+        "wbuf": ((wcap, dbuf), np.int32, -1),
+        "wlen": ((), np.int32, 0),
+        "wcnt": ((n + 1,), np.int32, 0),
+        "seed": ((n + 1, m), np.int32, 0),
+        "p_idx": ((h,), np.int32, n),
+        "praw": ((h, h), np.float32, 0),
+        "pnorm": ((h, h), np.float32, 0),
+        "pbin": ((h, h), np.bool_, False),
+    }
+
+
 def init_cgm_carry(state, prev_crm, win_prefix, *, n, m, uses_sizes,
                    item_sizes, layout=None, schedule=None, h=None,
                    wcap=None, dbuf=None):
@@ -1258,7 +1274,7 @@ def init_cgm_carry(state, prev_crm, win_prefix, *, n, m, uses_sizes,
     ``schedule`` (or explicit ``h`` / ``wcap`` for the live ratchet);
     ``h`` is bumped to fit a restored previous-window CRM.
     """
-    from .engine_jax import N_ACC, state_to_device
+    from .engine_jax import state_to_device
     from .state_layout import StateLayout
 
     lay = StateLayout.resolve(layout)
@@ -1284,17 +1300,10 @@ def init_cgm_carry(state, prev_crm, win_prefix, *, n, m, uses_sizes,
     carry = {
         "E": E0,
         "anchor": a0,
-        "acc": np.zeros(N_ACC, np.float64),
         "of": of0,
         "cnt": np.bincount(of0, minlength=n + 1).astype(np.float64),
-        "wbuf": np.full((wcap, dbuf), -1, np.int32),
-        "wlen": np.zeros((), np.int32),
-        "wcnt": np.zeros(n + 1, np.int32),
-        "seed": np.zeros((n + 1, m), np.int32),
-        "p_idx": np.full(h, n, np.int32),
-        "praw": np.zeros((h, h), np.float32),
-        "pnorm": np.zeros((h, h), np.float32),
-        "pbin": np.zeros((h, h), bool),
+        **{k: np.full(shape, v, dt) for k, (shape, dt, v)
+           in _window_fields(n, m, h, wcap, dbuf).items()},
     }
     if uses_sizes:
         vol = np.zeros(n + 1, np.float64)
@@ -1335,6 +1344,32 @@ def init_cgm_carry(state, prev_crm, win_prefix, *, n, m, uses_sizes,
     return carry
 
 
+def fresh_cgm_lanes(lanes: int, *, n, m, uses_sizes, item_sizes, schedule):
+    """``lanes`` copies of the carry :func:`init_cgm_carry` gives an empty
+    cache over singleton cliques, made on the device: the fields that
+    hold one constant as fills, the per-item slot map, clique counts and
+    volumes copied once and broadcast over the lane axis.  Returns the
+    carry and the bytes copied from the host, which do not grow with
+    ``lanes``."""
+    h, wcap, dbuf = schedule.h, schedule.wcap, schedule.d
+    of = np.arange(n, dtype=np.int32)         # singleton cliques
+    per_item = {"of": of,
+                "cnt": np.bincount(of, minlength=n + 1).astype(np.float64)}
+    if uses_sizes:
+        vol = np.zeros(n + 1, np.float64)
+        vol[:n] = np.asarray(item_sizes, np.float64)
+        per_item["vol"] = vol
+    fills = {"E": ((n + 1, m), np.float64, 0),
+             "anchor": ((n + 1,), np.int32, -1),
+             **_window_fields(n, m, h, wcap, dbuf)}
+    with jax.enable_x64(True):
+        carry = {k: jnp.full((lanes, *shape), v, dt)
+                 for k, (shape, dt, v) in fills.items()}
+        carry.update({k: jnp.broadcast_to(jnp.asarray(v), (lanes, *v.shape))
+                      for k, v in per_item.items()})
+    return carry, sum(v.nbytes for v in per_item.values())
+
+
 def lane_prunes(cspec) -> np.ndarray:
     """Per lane of ``cspec``: whether its approximate merge prunes to
     groups with a live hot member (omega > 2 and gamma above the
@@ -1345,7 +1380,8 @@ def lane_prunes(cspec) -> np.ndarray:
     return (om > 2) & (gam > (omf - 2.0) / omf)
 
 
-def cgm_loop_statics(cspec, carry0, *, enable_split, enable_acm):
+def cgm_loop_statics(cspec, carry0, *, enable_split, enable_acm,
+                     cnt_max=None):
     """The two compile-time loop capacities derived from runtime spec.
 
     * ``gcap`` — member-list width for the split worklist AND the
@@ -1357,10 +1393,14 @@ def cgm_loop_statics(cspec, carry0, *, enable_split, enable_acm):
     * ``full_merge`` — True when ANY lane can run the approximate merge
       OUTSIDE the pruning regime (the w/o-CS ablation: omega = n), so
       the act space must hold all n groups (scap = 2n) instead of 2h.
+
+    ``cnt_max``, the largest clique in ``carry0`` where the caller knows
+    it, spares reading a device carry back to the host.
     """
     om = np.atleast_1d(np.asarray(cspec["omega"], np.int64))
     full_merge = bool(enable_acm) and not bool(lane_prunes(cspec).all())
-    cnt_max = int(np.asarray(carry0["cnt"]).max())
+    if cnt_max is None:
+        cnt_max = int(np.asarray(carry0["cnt"]).max())
     gcap = _bucket(max(int(om.max()), cnt_max, 2), 8, 8)
     del enable_split
     return gcap, full_merge
@@ -1368,20 +1408,23 @@ def cgm_loop_statics(cspec, carry0, *, enable_split, enable_acm):
 
 def run_cgm_schedule(schedule, spec, statics, cspec, carry0, item_sizes, *,
                      charge="requested", enable_split=True, enable_acm=True,
-                     seed_new=True, use_kernels=None, block=True):
+                     seed_new=True, use_kernels=None, block=True,
+                     cnt_max=None):
     """Execute one CGM schedule; returns the final carry, the per-step
     slot maps and the per-step approximate-merge iteration counts (0 off
     the boundary steps).
 
     ``spec``/``cspec``/``carry0`` may carry a leading scenario axis (the
     fig7 grid); the schedule and item sizes stay shared unbatched.
+    ``cnt_max`` is :func:`cgm_loop_statics`'s.
     """
     enable_compile_cache()
     if use_kernels is None:
         use_kernels = kernels_on_backend()
     vmapped = carry0["E"].ndim == 3
     gcap, full_merge = cgm_loop_statics(
-        cspec, carry0, enable_split=enable_split, enable_acm=enable_acm)
+        cspec, carry0, enable_split=enable_split, enable_acm=enable_acm,
+        cnt_max=cnt_max)
     fn = _compiled_cgm_replay(
         statics, charge, "vol" in carry0, bool(enable_split),
         bool(enable_acm), bool(seed_new), bool(use_kernels), gcap,

@@ -987,7 +987,13 @@ def run_schedules(
             jnp.zeros((E0.shape[0], N_ACC), jnp.float64),
         )
         spec_j = {k: jnp.asarray(v) for k, v in spec.items()}
-        xs_j = {k: jnp.stack([jnp.asarray(s.xs[k]) for s in schedules])
+        # lanes replaying one schedule copy its event tensors once; the
+        # lane axis is stacked on the device
+        dev = {}
+        for s in schedules:
+            if id(s) not in dev:
+                dev[id(s)] = {k: jnp.asarray(v) for k, v in s.xs.items()}
+        xs_j = {k: jnp.stack([dev[id(s)][k] for s in schedules])
                 for k in s0.xs}
         E, anchor, acc = fn(spec_j, init, xs_j)
         if not block:
@@ -1001,6 +1007,19 @@ def fresh_state_arrays(
     """Device-layout expiries + anchors, all empty (dense: (n+1, m))."""
     rows, cols = StateLayout.resolve(layout).state_dims(n, m)
     return (np.zeros((rows, cols), np.float64), np.full(rows, -1, np.int32))
+
+
+def fresh_state_lanes(lanes: int | None, rows: int, cols: int,
+                      shardings=None) -> tuple[jax.Array, jax.Array]:
+    """:func:`fresh_state_arrays` of ``rows`` x ``cols`` made on the
+    device, with a leading axis of ``lanes`` vmap lanes (``None``: none).
+    ``shardings`` places (E, anchor) where given.  Nothing crosses the
+    host link: a sweep's lanes all start from this one empty state."""
+    lead = () if lanes is None else (lanes,)
+    shE, shA = shardings or (None, None)
+    with jax.enable_x64(True):
+        return (jnp.zeros((*lead, rows, cols), jnp.float64, device=shE),
+                jnp.full((*lead, rows), -1, jnp.int32, device=shA))
 
 
 def state_to_device(

@@ -177,17 +177,25 @@ class StateLayout:
         axis is always the second-to-last of E0.  A no-op (returns the
         inputs) unless this layout actually spans > 1 device.
         """
+        sh = self.state_shardings(np.ndim(E0) - 2)
+        if sh is None:
+            return E0, anchor0
+        import jax
+
+        return jax.device_put(E0, sh[0]), jax.device_put(anchor0, sh[1])
+
+    def state_shardings(self, lead: int):
+        """The (E, anchor) placements of :meth:`place_state` for state
+        with ``lead`` leading lane axes, or None where it is a no-op."""
         if self.kind != "row_sharded" or self.mesh is None \
                 or self.row_axis not in self.mesh.axis_names \
                 or int(self.mesh.shape[self.row_axis]) <= 1:
-            return E0, anchor0
-        import jax
+            return None
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        lead = (None,) * (np.ndim(E0) - 2)
-        shE = NamedSharding(self.mesh, P(*lead, self.row_axis, None))
-        shA = NamedSharding(self.mesh, P(*lead, self.row_axis))
-        return jax.device_put(E0, shE), jax.device_put(anchor0, shA)
+        pfx = (None,) * lead
+        return (NamedSharding(self.mesh, P(*pfx, self.row_axis, None)),
+                NamedSharding(self.mesh, P(*pfx, self.row_axis)))
 
     # -- snapshot wire format ---------------------------------------------
     @property

@@ -154,9 +154,16 @@ def _coarse(x: int) -> int:
 
 
 def _nbytes(*trees) -> int:
-    """Bytes of the arrays in ``trees`` (arrays, or dicts of arrays)."""
-    return sum(int(a.nbytes) for t in trees
+    """Bytes of the arrays in ``trees`` (arrays, or dicts of arrays;
+    ``None`` counts nothing)."""
+    return sum(int(a.nbytes) for t in trees if t is not None
                for a in (t.values() if isinstance(t, dict) else (t,)))
+
+
+def _xs_nbytes(schedules) -> int:
+    """Bytes of the event tensors of the distinct ``schedules``: lanes
+    that replay one schedule copy its tensors to the device once."""
+    return _nbytes(*{id(s): s.xs for s in schedules}.values())
 
 
 def _digest(arrays, memo: dict) -> str:
@@ -212,9 +219,11 @@ def _merge_groups(cspecs: list, n: int, h: int, enable_acm: bool) -> list:
             (lanes[~prunes].tolist(), 2 * n)]
 
 
-#: what ``SweepEngine.stats`` counts of each call's device-CGM lanes
-CGM_STATS = ("cgm_lanes", "cgm_host_points", "cgm_hot", "cgm_merge_space",
-             "cgm_boundaries", "cgm_window_rows", "cgm_merge_iters")
+#: what ``SweepEngine.stats`` counts of each call: its device-CGM lanes,
+#: and the bytes it copies from the host to the device
+STATS = ("cgm_lanes", "cgm_host_points", "cgm_hot", "cgm_merge_space",
+         "cgm_boundaries", "cgm_window_rows", "cgm_merge_iters",
+         "staged_bytes")
 
 
 def _cgm_key(policy) -> tuple:
@@ -261,12 +270,13 @@ class SweepEngine:
         #: left to a host walk; of the device lanes, the largest padded
         #: hot capacity h, merge space ``scap`` and window buffer
         #: ``wcap``, boundaries x lanes, and approximate-merge iterations
-        #: summed over lanes and boundaries (a device counter)
-        self.stats = dict.fromkeys(CGM_STATS, 0)
+        #: summed over lanes and boundaries (a device counter); and the
+        #: bytes the call's ``sweep.stage`` spans copy to the device
+        self.stats = dict.fromkeys(STATS, 0)
 
     # ------------------------------------------------------------------
     def run(self, points: Sequence[SweepPoint]) -> list[RunResult]:
-        self.stats = dict.fromkeys(CGM_STATS, 0)
+        self.stats = dict.fromkeys(STATS, 0)
         with obs.span("sweep.call", points=len(points)) as sp:
             if self.backend == "numpy":
                 self.last_n_schedules = len(points)
@@ -292,7 +302,6 @@ class SweepEngine:
         from . import engine_jax as ej
         from .cliques import CliquePartition
         from .cost import CostBreakdown
-        from .engine import CacheState
 
         with obs.span("sweep.prepare", points=len(points)):
             prepared, dev_groups, groups, sh_groups = self._prepare(points)
@@ -382,13 +391,11 @@ class SweepEngine:
                                  for i in idxs for _ in range(S_sh)])
                     for k in g0["spec"]
                 }
-                L = len(lanes)
-                E0 = np.zeros((L, s0.state_rows, s0.state_cols), np.float64)
-                a0 = np.full((L, s0.state_rows), -1, np.int32)
-                sp.set_metadata(bytes=_nbytes(
-                    spec, E0, a0, *(s.xs for s in lanes)))
-                if self.mesh is not None:
-                    spec, E0, a0 = self._shard(spec, E0, a0, L)
+                staged = _nbytes(spec) + _xs_nbytes(lanes)
+                stats["staged_bytes"] += staged
+                sp.set_metadata(bytes=staged)
+                spec, E0, a0 = self._stage(
+                    spec, s0.state_rows, s0.state_cols, len(lanes))
                 t0 = _time.perf_counter()
                 _, _, acc = ej.run_schedules(
                     lanes, spec, statics, E0, a0, charge=charge,
@@ -446,12 +453,9 @@ class SweepEngine:
                 lane_idx = [idxs[j] for j in sub]
                 S = len(sub)
                 with obs.span("sweep.stage", lanes=S) as sp:
-                    carry1 = cgm_jax.init_cgm_carry(
-                        CacheState.fresh(CliquePartition.singletons(n),
-                                         m_srv),
-                        None, None, n=n, m=m_srv, uses_sizes=uses_sizes,
-                        item_sizes=item_sizes, layout=self.layout,
-                        schedule=sched)
+                    carry0, carry_bytes = cgm_jax.fresh_cgm_lanes(
+                        S, n=n, m=m_srv, uses_sizes=uses_sizes,
+                        item_sizes=item_sizes, schedule=sched)
                     spec = {
                         k: np.stack([prepared[i]["spec"][k]
                                      for i in lane_idx])
@@ -460,16 +464,17 @@ class SweepEngine:
                     cspec = {k: np.stack([np.asarray(cspecs[j][k])
                                           for j in sub])
                              for k in cspecs[0]}
-                    carry0 = {k: np.stack([v] * S) for k, v in carry1.items()}
-                    sp.set_metadata(bytes=_nbytes(spec, cspec, carry0,
-                                                  sched.xs))
+                    staged = carry_bytes + _nbytes(spec, cspec, sched.xs,
+                                                   item_sizes)
+                    stats["staged_bytes"] += staged
+                    sp.set_metadata(bytes=staged)
                     t0g = _time.perf_counter()
                     final, ofs, iters = cgm_jax.run_cgm_schedule(
                         sched, spec, g0["statics"], cspec, carry0,
                         item_sizes, charge=g0["charge"],
                         enable_split=cfg0.enable_split,
                         enable_acm=cfg0.enable_approx_merge,
-                        seed_new=g0["seed"], block=False)
+                        seed_new=g0["seed"], block=False, cnt_max=1)
                 dev_pending.append((lane_idx, sched, final, ofs, iters, t0g))
         n_dev_schedules = len(dev_groups)
 
@@ -520,16 +525,12 @@ class SweepEngine:
                         k: np.stack([prepared[i]["spec"][k] for i in idxs])
                         for k in g0["spec"]
                     }
-                    E0 = np.zeros(
-                        (S, schedule.state_rows, schedule.state_cols),
-                        np.float64)
-                    a0 = np.full((S, schedule.state_rows), -1, np.int32)
                     if S == 1:       # no vmap lane for a singleton group
                         spec = {k: v[0] for k, v in spec.items()}
-                        E0, a0 = E0[0], a0[0]
-                    staged += _nbytes(spec, E0, a0, schedule.xs)
-                    if self.mesh is not None:
-                        spec, E0, a0 = self._shard(spec, E0, a0, S)
+                    staged += _nbytes(spec, schedule.xs)
+                    spec, E0, a0 = self._stage(
+                        spec, schedule.state_rows, schedule.state_cols,
+                        S if S > 1 else None)
                     t0 = _time.perf_counter()
                     _, _, acc = ej.run_schedule(
                         schedule, spec, statics, E0, a0, charge=charge,
@@ -547,13 +548,10 @@ class SweepEngine:
                     k: np.stack([prepared[i]["spec"][k] for i in lane_idx])
                     for k in g0["spec"]
                 }
-                L = len(lanes)
-                s0 = lanes[0]
-                E0 = np.zeros((L, s0.state_rows, s0.state_cols), np.float64)
-                a0 = np.full((L, s0.state_rows), -1, np.int32)
-                staged += _nbytes(spec, E0, a0, *(s.xs for s in lanes))
-                if self.mesh is not None:
-                    spec, E0, a0 = self._shard(spec, E0, a0, L)
+                staged += _nbytes(spec) + _xs_nbytes(lanes)
+                spec, E0, a0 = self._stage(
+                    spec, lanes[0].state_rows, lanes[0].state_cols,
+                    len(lanes))
                 t0 = _time.perf_counter()
                 _, _, acc = ej.run_schedules(
                     lanes, spec, statics, E0, a0, charge=charge,
@@ -561,6 +559,7 @@ class SweepEngine:
                 pending.append((lane_idx, lane_recs, acc, t0))
             sp.set_metadata(lanes=sum(len(p[0]) for p in pending),
                             bytes=staged)
+            stats["staged_bytes"] += staged
         self.last_n_schedules = (len(schedules) + n_dev_schedules
                                  + n_shard_schedules)
         call_span.set_metadata(
@@ -735,34 +734,42 @@ class SweepEngine:
         return prepared, dev_groups, groups, sh_groups
 
     # ------------------------------------------------------------------
-    def _shard(self, spec, E0, a0, S):
-        """Spread the lanes over ``self.mesh``: the scenario axis over the
-        mesh's first axis (no-op if it does not divide evenly or the mesh
-        axis has one device) and, under a row-sharded layout, the STATE
-        ROWS over the mesh's ``state_row`` axis — the two compose on a
-        2-D ``make_sweep_mesh(..., state_rows=)`` mesh."""
+    def _stage(self, spec, rows, cols, lanes):
+        """The spec of ``lanes`` vmap lanes (``None``: one point, no lane
+        axis) and their fresh (E, anchor) state, made on the device.
+
+        With ``self.mesh`` the lanes spread over it: the scenario axis
+        over the mesh's first axis (not where it does not divide evenly or
+        the mesh axis has one device) and, under a row-sharded layout, the
+        STATE ROWS over the mesh's ``state_row`` axis; the two compose on
+        a 2-D ``make_sweep_mesh(..., state_rows=)`` mesh.  Otherwise the
+        state takes the layout's own placement."""
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        mesh = self.mesh
-        axis = mesh.axis_names[0]
-        lead = E0.ndim - 2               # 1 with a scenario axis, 0 squeezed
-        n_sc = int(mesh.shape[axis])
-        sc = axis if (lead and n_sc > 1 and S % n_sc == 0) else None
-        lay = self.layout
-        row = (lay.row_axis
-               if lay.kind == "row_sharded" and lay.mesh is mesh
-               and lay.row_axis in mesh.axis_names
-               and int(mesh.shape[lay.row_axis]) > 1 else None)
+        from . import engine_jax as ej
+
+        lead = 0 if lanes is None else 1
+        mesh, lay = self.mesh, self.layout
+        sc = row = None
+        if mesh is not None:
+            axis = mesh.axis_names[0]
+            n_sc = int(mesh.shape[axis])
+            sc = axis if (lead and n_sc > 1 and lanes % n_sc == 0) else None
+            row = (lay.row_axis
+                   if lay.kind == "row_sharded" and lay.mesh is mesh
+                   and lay.row_axis in mesh.axis_names
+                   and int(mesh.shape[lay.row_axis]) > 1 else None)
         if sc is None and row is None:
-            return spec, E0, a0
+            return (spec, *ej.fresh_state_lanes(
+                lanes, rows, cols, lay.state_shardings(lead)))
         pfx = (sc,) * lead
         sh = NamedSharding(mesh, P(*pfx))
-        shE = NamedSharding(mesh, P(*pfx, row, None))
-        shA = NamedSharding(mesh, P(*pfx, row))
-        with jax.enable_x64(True):  # keep f64 spec/state across the put
+        with jax.enable_x64(True):  # keep the f64 spec across the put
             spec = {k: jax.device_put(v, sh) for k, v in spec.items()}
-            return spec, jax.device_put(E0, shE), jax.device_put(a0, shA)
+        return (spec, *ej.fresh_state_lanes(
+            lanes, rows, cols, (NamedSharding(mesh, P(*pfx, row, None)),
+                                NamedSharding(mesh, P(*pfx, row)))))
 
 
 def sweep_points(

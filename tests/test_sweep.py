@@ -489,3 +489,158 @@ def test_device_cgm_window_buffer_is_coarsely_bucketed():
         c = sweep_mod._coarse(x)
         assert x <= c <= x + x / 16
     assert {sweep_mod._coarse(x) for x in range(21_505, 22_529)} == {22_528}
+
+
+# ---------------------------------------------------------------------------
+# staging: every lane's fresh state is made on the device
+# ---------------------------------------------------------------------------
+def _staging_points(path, n_alpha):
+    """``n_alpha`` points on the alpha axis per schedule, shaped to take
+    one staging path of ``SweepEngine``; returns (points, lanes)."""
+    alphas = np.linspace(0.6, 1.0, n_alpha).tolist()
+    kw = dict(t_cg=T_CG, top_frac=TOP_FRAC)
+    tr = _trace(n_requests=1200)
+    if path == "single":                # one schedule, S spec lanes
+        return [SweepPoint("akpc", tr, dict(
+            params=CostParams(alpha=a), **kw)) for a in alphas], n_alpha
+    if path == "cohort":                # two schedules stacked as lanes
+        logs = (tr, _trace(n_requests=1200, seed=4))
+        return [SweepPoint("akpc", lg, dict(
+            params=CostParams(alpha=a), **kw))
+            for lg in logs for a in alphas], 2 * n_alpha
+    if path == "shards":                # scenarios x trace shards
+        shards = [tr, _trace(n_requests=1200, seed=4)]
+        return [SweepPoint("akpc", shards, dict(
+            params=CostParams(alpha=a), **kw)) for a in alphas], 2 * n_alpha
+    return [SweepPoint("akpc", tr, dict(      # device-CGM lanes
+        params=CostParams(alpha=a, theta=th), **kw))
+        for th in (0.1, 0.3) for a in alphas], 2 * n_alpha
+
+
+def _lane_spec_nbytes(eng, pt, cgm):
+    """Host bytes of one lane's cost spec, and of its clique-generation
+    spec where it is a device-CGM lane."""
+    from repro.core import cgm_jax
+
+    pr = eng._prepare([pt])[0][0]
+    specs = list(pr["spec"].values())
+    if cgm:
+        cfg = pr["policy"].config
+        specs += cgm_jax.cgm_spec(cfg, cfg.params, pt.trace.n).values()
+    return sum(np.asarray(v).nbytes for v in specs)
+
+
+@pytest.mark.parametrize("path", ["single", "cohort", "shards", "cgm"])
+def test_sweep_stages_lane_state_on_device(path, monkeypatch, tmp_path):
+    """Each staging path makes the lanes' fresh state on the device: the
+    bytes a call copies from the host grow with the lanes by the per-lane
+    spec alone, ``stats["staged_bytes"]`` equals the ``sweep.stage``
+    spans' ``bytes``, a repeated call compiles no scan, and every point
+    equals its serial replay."""
+    from repro import obs
+    from repro.core import cgm_jax
+    from repro.core import engine_jax as ej
+
+    monkeypatch.setattr(sweep_mod, "_COHORT_DIMS", {})
+    entry = {"single": "run_schedule", "cohort": "run_schedules",
+             "shards": "run_schedules", "cgm": "run_cgm_schedule"}[path]
+    mod = cgm_jax if path == "cgm" else ej
+    real, seen = getattr(mod, entry), []
+
+    def spy(*a, **k):
+        state = a[4] if path == "cgm" else a[3:5]
+        seen.append(all(isinstance(v, jax.Array) for v in (
+            state.values() if path == "cgm" else state)))
+        return real(*a, **k)
+
+    monkeypatch.setattr(mod, entry, spy)
+    eng = SweepEngine()
+    pts, lanes = _staging_points(path, 2)
+    eng.run(pts)                                    # compiles
+    traces = ej.SCAN_TRACES + cgm_jax.SCAN_TRACES
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        res = eng.run(pts)
+    finally:
+        jax.profiler.stop_trace()
+    assert ej.SCAN_TRACES + cgm_jax.SCAN_TRACES == traces
+    staged = eng.stats["staged_bytes"]
+    stage = [st for name, _, _, st in obs.read(str(tmp_path))
+             if name == obs.PREFIX + "sweep.stage"]
+    assert staged == sum(st["bytes"] for st in stage) > 0
+    assert seen and all(seen)                       # device state only
+    assert (eng.stats["cgm_lanes"] > 0) == (path == "cgm")
+
+    pts2, lanes2 = _staging_points(path, 4)
+    eng.run(pts2)
+    per_lane = _lane_spec_nbytes(eng, pts[0], path == "cgm")
+    assert eng.stats["staged_bytes"] - staged == (lanes2 - lanes) * per_lane
+
+    for pt, got in zip(pts, res):
+        if path == "shards":
+            subs = [run_policy(get_policy(pt.policy, **pt.policy_kwargs),
+                               tr) for tr in pt.trace]
+            merged = {f: sum(s.costs.as_dict()[f] for s in subs)
+                      for f in INT_FIELDS + FLOAT_FIELDS}
+            assert_same_costs(merged, got.costs)
+            continue
+        ref = run_policy(get_policy(pt.policy, **pt.policy_kwargs),
+                         pt.trace)
+        assert_same_run(ref, got)
+
+
+def test_sweep_one_device_mesh_matches_no_mesh(trace, monkeypatch):
+    """Through ``SweepEngine(mesh=...)`` on a one-device sweep mesh the
+    lanes' state is still made on the device, and every point equals the
+    engine without a mesh, counters and costs."""
+    from repro.core import engine_jax as ej
+    from repro.launch.mesh import make_sweep_mesh
+
+    real, seen = ej.run_schedule, []
+
+    def spy(*a, **k):
+        seen.append(all(isinstance(v, jax.Array) for v in a[3:5]))
+        return real(*a, **k)
+
+    monkeypatch.setattr(ej, "run_schedule", spy)
+    pts = [SweepPoint("akpc", trace, dict(
+        params=CostParams(alpha=a, rho=r), t_cg=T_CG, top_frac=TOP_FRAC))
+        for r in (0.5, 2.0) for a in (0.6, 1.0)]
+    got = SweepEngine(mesh=make_sweep_mesh(n_devices=1)).run(pts)
+    want = SweepEngine().run(pts)
+    assert seen == [True, True]
+    for w, g in zip(want, got):
+        assert w.costs.as_dict() == g.costs.as_dict()
+        assert np.array_equal(w.clique_sizes, g.clique_sizes)
+
+
+@pytest.mark.parametrize("uses_sizes", [False, True])
+def test_fresh_cgm_lanes_equal_stacked_host_carry(sized_trace, uses_sizes):
+    """The device-made device-CGM lanes hold, field for field and bit for
+    bit, S copies of the carry ``init_cgm_carry`` builds on the host for
+    an empty cache over singleton cliques; the host copies one lane's
+    per-item fields only."""
+    from repro.core import cgm_jax
+    from repro.core.cliques import CliquePartition
+    from repro.core.engine import CacheState
+
+    tr = sized_trace
+    sched = cgm_jax.build_cgm_schedule(tr, T_CG, uses_sizes=uses_sizes,
+                                       hot_dims=[(TOP_FRAC, False)])
+    sizes = tr.sizes if uses_sizes else None
+    one = cgm_jax.init_cgm_carry(
+        CacheState.fresh(CliquePartition.singletons(tr.n), tr.m), None, None,
+        n=tr.n, m=tr.m, uses_sizes=uses_sizes, item_sizes=sizes,
+        schedule=sched)
+    lanes, nbytes = cgm_jax.fresh_cgm_lanes(
+        3, n=tr.n, m=tr.m, uses_sizes=uses_sizes, item_sizes=sizes,
+        schedule=sched)
+    assert sorted(lanes) == sorted(one)
+    for k, v in one.items():
+        got = np.asarray(lanes[k])
+        assert got.dtype == v.dtype, k
+        assert np.array_equal(got, np.stack([v] * 3)), k
+    assert nbytes == sum(one[k].nbytes for k in ("of", "cnt", "vol")
+                         if k in one)
