@@ -22,7 +22,6 @@ def main() -> None:
         integration_bench,
         kernel_bench,
         replay_bench,
-        roofline_report,
         sweep_bench,
         table1_cost_model,
     )
@@ -39,7 +38,6 @@ def main() -> None:
         ("fig10", fig10_heterogeneous),
         ("kernels", kernel_bench),
         ("integration", integration_bench),
-        ("roofline", roofline_report),
     ]
     only = sys.argv[1] if len(sys.argv) > 1 else None
     print("name,us_per_call,derived")

@@ -140,7 +140,7 @@ def bench_mesh(trace, n_alphas: int, n_rhos: int) -> list[dict]:
     """Devices x points scaling rows on 1/2/4-device sub-meshes."""
     import jax
 
-    from repro.launch.mesh import make_sweep_mesh
+    from repro.core.state_layout import make_sweep_mesh
 
     pts = build_grid(trace, n_alphas, n_rhos)
     rows = []

@@ -296,7 +296,7 @@ def phase_catalog(n_requests: int = CATALOG_REQUESTS) -> dict:
 
 @phase("4-chip row-sharded catalog")
 def phase_row_sharded(n_requests: int = CATALOG_REQUESTS) -> dict:
-    from repro.launch.mesh import make_sweep_mesh
+    from repro.core.state_layout import make_sweep_mesh
 
     tr = catalog_trace(n_requests)
     params = CostParams()
@@ -328,7 +328,7 @@ def phase_row_sharded(n_requests: int = CATALOG_REQUESTS) -> dict:
 def phase_scenario_mesh(n_requests: int = 150_000, n_alphas: int = 64,
                         n_rhos: int = 4) -> dict:
     from benchmarks.sweep_bench import build_grid
-    from repro.launch.mesh import make_sweep_mesh
+    from repro.core.state_layout import make_sweep_mesh
 
     tr = paper_trace("netflix", n_requests=n_requests, seed=0)
     pts = build_grid(tr, n_alphas, n_rhos)

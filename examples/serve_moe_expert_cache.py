@@ -1,42 +1,47 @@
-"""Serve a (reduced) MoE model with batched requests; the AKPC expert cache
-observes routing outcomes, packs co-activated experts into cliques and
+"""AKPC as a MoE expert cache: seeded, skewed top-k routings stream into
+``ExpertCacheManager``, which packs co-activated experts into cliques and
 reports the transfer-cost saving vs per-expert fetching.
+
+Routing is synthetic: experts fall into topic groups, each decode step
+draws its tokens' topics from a Zipf popularity that drifts over time, and
+a token's top-k mixes experts of its topic with a few globally popular
+ones.  Same seed, same routings.
 
     PYTHONPATH=src python examples/serve_moe_expert_cache.py
 """
-import jax
-import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import get_smoke_config
-from repro.models.api import build_model
-from repro.serving import BatchedServer, ExpertCacheManager, Request
+from repro.serving import ExpertCacheManager
+
+N_EXPERTS, TOP_K, N_HOSTS = 40, 8, 2
+GROUP = 5                      # experts per topic group (= omega)
+TOKENS, STEPS, DRIFT = 4, 240, 60
+
+
+def routings(seed: int = 0):
+    """Yield (host, (TOKENS, TOP_K) expert ids) per decode step."""
+    rng = np.random.default_rng(seed)
+    groups = rng.permutation(N_EXPERTS).reshape(-1, GROUP)
+    zipf = 1.0 / np.arange(1, len(groups) + 1) ** 1.2
+    glob = 1.0 / np.arange(1, N_EXPERTS + 1) ** 0.8
+    glob /= glob.sum()
+    for step in range(STEPS):
+        if step % DRIFT == 0:              # topic drift: reshuffle popularity
+            pop = rng.permutation(zipf / zipf.sum())
+        topk = np.empty((TOKENS, TOP_K), np.int64)
+        for t in range(TOKENS):
+            own = groups[rng.choice(len(groups), p=pop)]
+            rest = np.setdiff1d(np.arange(N_EXPERTS), own)
+            p = glob[rest] / glob[rest].sum()
+            topk[t] = np.concatenate(
+                [own, rng.choice(rest, TOP_K - GROUP, replace=False, p=p)])
+        yield int(rng.integers(0, N_HOSTS)), topk
 
 
 def main():
-    cfg = get_smoke_config("granite_moe_3b_a800m")
-    model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
-    mgr = ExpertCacheManager(n_experts=cfg.moe.n_experts, n_hosts=2, t_cg=24.0)
-
-    # routing tap: recompute the router's top-k for the served tokens
-    router0 = np.asarray(params["layers"]["mlp"]["router"][0], np.float32)
-    embed = np.asarray(params["embed"], np.float32)
-
-    def tap(p, tokens):
-        x = embed[tokens[:, 0]]
-        logits = x @ router0
-        topk = np.argsort(-logits, axis=-1)[:, : cfg.moe.top_k]
-        mgr.observe(topk, host=0)
-
-    srv = BatchedServer(model, params, batch_size=4, cache_len=64,
-                        routing_tap=tap)
-    rng = np.random.default_rng(0)
-    for rid in range(24):
-        prompt = rng.integers(0, cfg.vocab, size=rng.integers(2, 6)).tolist()
-        srv.submit(Request(rid=rid, prompt=prompt, max_new=8))
-    done = srv.run(max_steps=500)
-    print(f"served {len(done)} requests in {srv.steps} decode steps")
+    mgr = ExpertCacheManager(n_experts=N_EXPERTS, n_hosts=N_HOSTS, t_cg=24.0)
+    for host, topk in routings(seed=0):
+        mgr.observe(topk, host=host)
 
     stats = mgr.stats()
     print(f"expert-cache: {stats.n_observations} routing observations, "
@@ -45,9 +50,9 @@ def main():
           f"{stats.nopack_total:.1f}  ->  {stats.saving_pct:.1f}% saved")
 
     # pack the expert weights per clique for single-DMA gathers
-    wi0 = np.asarray(params["layers"]["mlp"]["wi"][0], np.float32)
-    table, where = mgr.packed_tables(wi0.reshape(wi0.shape[0], -1))
-    print(f"packed table: {table.shape} (cliques x omega x flattened expert)")
+    w = np.random.default_rng(1).normal(size=(N_EXPERTS, 64)).astype(np.float32)
+    table, where = mgr.packed_tables(w)
+    print(f"packed table: {table.shape} (cliques x omega x expert row)")
 
 
 if __name__ == "__main__":

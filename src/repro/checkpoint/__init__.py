@@ -1,5 +1,4 @@
 from .checkpoint import (
-    CheckpointManager,
     latest_step,
     load_checkpoint_tree,
     restore_checkpoint,
@@ -7,7 +6,6 @@ from .checkpoint import (
 )
 
 __all__ = [
-    "CheckpointManager",
     "latest_step",
     "load_checkpoint_tree",
     "restore_checkpoint",

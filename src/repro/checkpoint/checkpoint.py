@@ -1,5 +1,5 @@
-"""Sharded checkpointing: save/restore arbitrary pytrees, async writer,
-elastic re-shard on restore.
+"""Checkpointing: save/restore arbitrary pytrees, elastic re-shard on
+restore.
 
 Layout per step:  <dir>/step_<N>/
     manifest.msgpack   tree structure, leaf paths, shapes, dtypes, meta
@@ -8,16 +8,14 @@ Layout per step:  <dir>/step_<N>/
 
 Restore accepts a ``shardings`` pytree: leaves are ``jax.device_put`` onto
 it — so a checkpoint written on one mesh restores onto ANY mesh/device
-count (elastic scaling).  Saves run synchronously or on a background thread
-(``CheckpointManager(async_save=True)``); the commit marker guarantees a
-crashed writer never publishes a torn checkpoint, and restart picks the
-newest committed step.
+count (elastic scaling).  The commit marker guarantees a crashed writer
+never publishes a torn checkpoint, and ``latest_step`` picks the newest
+committed step.
 """
 from __future__ import annotations
 
 import os
 import shutil
-import threading
 
 import ml_dtypes  # numpy dtype extensions (bf16 etc.) — ships with jax
 import msgpack
@@ -129,52 +127,3 @@ def restore_checkpoint(directory: str, step: int, like, shardings=None):
         a = a.astype(l.dtype) if hasattr(l, "dtype") else a
         out.append(jax.device_put(a, s) if s is not None else a)
     return jax.tree_util.tree_unflatten(treedef, out), manifest["meta"]
-
-
-class CheckpointManager:
-    """Keeps the last ``keep`` checkpoints; optional async background save."""
-
-    def __init__(self, directory: str, keep: int = 3, async_save: bool = False):
-        self.directory = directory
-        self.keep = keep
-        self.async_save = async_save
-        self._thread: threading.Thread | None = None
-        os.makedirs(directory, exist_ok=True)
-
-    def wait(self) -> None:
-        if self._thread is not None:
-            self._thread.join()
-            self._thread = None
-
-    def _gc(self) -> None:
-        steps = sorted(
-            int(n.split("_")[1])
-            for n in os.listdir(self.directory)
-            if n.startswith("step_") and not n.endswith(".tmp")
-        )
-        for s in steps[: -self.keep]:
-            shutil.rmtree(os.path.join(self.directory, f"step_{s:09d}"),
-                          ignore_errors=True)
-
-    def save(self, step: int, tree, meta: dict | None = None) -> None:
-        # snapshot to host BEFORE backgrounding (donated buffers may die)
-        host_tree = jax.tree.map(np.asarray, tree)
-        if self.async_save:
-            self.wait()
-            self._thread = threading.Thread(
-                target=self._save_sync, args=(step, host_tree, meta), daemon=True
-            )
-            self._thread.start()
-        else:
-            self._save_sync(step, host_tree, meta)
-
-    def _save_sync(self, step, tree, meta) -> None:
-        save_checkpoint(self.directory, step, tree, meta)
-        self._gc()
-
-    def restore_latest(self, like, shardings=None):
-        step = latest_step(self.directory)
-        if step is None:
-            return None, None, None
-        tree, meta = restore_checkpoint(self.directory, step, like, shardings)
-        return step, tree, meta

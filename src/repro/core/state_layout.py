@@ -217,3 +217,38 @@ class StateLayout:
 
 #: the bitwise-default layout every ``layout=None`` resolves to
 DENSE = StateLayout()
+
+
+def _auto(n_axes: int) -> dict:
+    import jax
+
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
+
+
+def make_sweep_mesh(n_devices: int | None = None, state_rows: int = 1):
+    """Mesh for SweepEngine grid sharding (repro.core.sweep).
+
+    Default: 1-D ("scenario",) — each device replays a slice of the
+    stacked scenario axis.  ``state_rows > 1`` splits the devices into a
+    2-D ("scenario", "state_row") grid whose second axis carries the
+    row-sharded StateLayout: the (n+1, m) expiry/anchor rows of every
+    lane are distributed over ``state_rows`` devices — catalogs one chip
+    can't hold.  ``state_rows`` must divide the device count.
+    ``n_devices`` takes the first that many of ``jax.devices()`` (a
+    sub-mesh of the host); on a single-device host this is a trivial
+    mesh and sweeps stay local."""
+    import jax
+
+    devs = jax.devices()
+    n = n_devices or len(devs)
+    if n > len(devs):
+        raise ValueError(f"n_devices={n} exceeds the {len(devs)} devices")
+    devs = devs[:n]
+    if state_rows <= 1:
+        return jax.make_mesh((n,), ("scenario",), devices=devs, **_auto(1))
+    if n % state_rows:
+        raise ValueError(
+            f"state_rows={state_rows} must divide the device count {n}")
+    return jax.make_mesh((n // state_rows, state_rows),
+                         ("scenario", "state_row"), devices=devs,
+                         **_auto(2))

@@ -26,7 +26,8 @@ point serially.  :class:`SweepEngine` makes the SCENARIO the batch axis:
 baseline ``benchmarks/sweep_bench.py`` times against, and the fallback
 for cost models the JAX backend cannot express).  ``mesh=`` optionally
 shards the scenario axis of each stacked group over a device mesh
-(``repro.launch.mesh.make_sweep_mesh``) — a no-op on single-device hosts.
+(``repro.core.state_layout.make_sweep_mesh``) — a no-op on single-device
+hosts.
 """
 from __future__ import annotations
 

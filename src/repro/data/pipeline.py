@@ -10,8 +10,9 @@ prefetched as packed bundles at discounted transfer cost, and whole-clique
 TTL extension keeps hot domains resident.
 
 The pipeline is deterministic (seeded), checkpointable (``state_dict`` /
-``load_state_dict``) and reports the cache-cost telemetry per epoch so
-training logs expose the AKPC savings (see examples/train_lm.py).
+``load_state_dict``) and reports the cache-cost telemetry per epoch
+(``PackedDataPipeline.telemetry``; ``benchmarks/integration_bench.py``
+prints it beside per-shard fetching).
 """
 from __future__ import annotations
 

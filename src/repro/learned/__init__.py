@@ -8,7 +8,7 @@ Three layers over the existing caching core:
   twin implementations;
 * :mod:`train` — hindsight-labeled windows (:mod:`labels`) replayed
   through one jit'd AdamW training scan over a small MLP
-  (``models/mlp.py`` + ``optim/adamw.py``), ``train_policy(trace, env,
+  (``model.py`` + ``adamw.py``), ``train_policy(trace, env,
   cfg) -> LearnedParams``, checkpointable via :mod:`repro.checkpoint`;
 * :mod:`policy` — the ``learned`` keep-or-not :class:`CachePolicy`
   (registered in ``repro.core.policy``) serving the trained scorer

@@ -1,9 +1,6 @@
-"""AdamW from scratch (no optax): fp32 moments, global-norm clipping,
-decoupled weight decay, cosine schedule with linear warmup.
-
-Moments are plain pytrees mirroring the params, so pjit shards them exactly
-like the parameters (ZeRO-style: with params 2-D sharded over
-(data x model), optimizer state is too — no replicated optimizer memory).
+"""AdamW from scratch (no optax) for the learned scorer's trainer: fp32
+moments, global-norm clipping, decoupled weight decay, cosine schedule
+with linear warmup.  Moments are plain pytrees mirroring the params.
 """
 from __future__ import annotations
 

@@ -2,9 +2,8 @@
 
     score(x) = z @ w_lin + b + mlp(z @ w_in) @ w_out,   z = (x - mu) / sd
 
-where ``mlp`` is one SwiGLU block built with the seed model stack
-(``models.mlp.init_mlp`` / ``mlp_forward``).  An item is KEPT iff its
-score is >= 0.
+where ``mlp`` is one SwiGLU block (:func:`init_mlp` /
+:func:`mlp_forward`).  An item is KEPT iff its score is >= 0.
 
 Two forwards over the same parameter tree:
 
@@ -27,8 +26,50 @@ import numpy as np
 
 from .featurize import FEATURE_NAMES, FEATURE_SCHEMA_VERSION
 
-#: trunk activation — SwiGLU, matching the seed model zoo's default
+#: trunk activation — SwiGLU
 ACTIVATION = "silu"
+
+
+class KeyGen:
+    """Deterministic fresh-key generator for building param trees."""
+
+    def __init__(self, key):
+        self._key = key
+
+    def __call__(self):
+        import jax
+
+        self._key, sub = jax.random.split(self._key)
+        return sub
+
+
+def dense_init(key, shape, dtype, fan_in: int | None = None):
+    import jax
+    import jax.numpy as jnp
+
+    fan = fan_in if fan_in is not None else shape[-2] if len(shape) >= 2 else shape[-1]
+    scale = 1.0 / np.sqrt(max(fan, 1))
+    return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
+
+
+def init_mlp(kg: KeyGen, d: int, d_ff: int, L: int, dtype, activation: str) -> dict:
+    """``L`` stacked gated-MLP layers: ``wi``, ``wo`` and, for SwiGLU, ``wg``."""
+    p = {
+        "wi": dense_init(kg(), (L, d, d_ff), dtype, fan_in=d),
+        "wo": dense_init(kg(), (L, d_ff, d), dtype, fan_in=d_ff),
+    }
+    if activation == "silu":                      # SwiGLU gate
+        p["wg"] = dense_init(kg(), (L, d, d_ff), dtype, fan_in=d)
+    return p
+
+
+def mlp_forward(p, x, activation: str):
+    import jax
+
+    h = {"silu": jax.nn.silu, "gelu": jax.nn.gelu}[activation](x @ p["wi"])
+    if "wg" in p:
+        h = h * (x @ p["wg"])
+    return h @ p["wo"]
 
 
 @dataclasses.dataclass
@@ -78,16 +119,13 @@ def init_params(seed: int = 0, d: int = 8, d_ff: int = 16,
                 n_features: int | None = None) -> LearnedParams:
     """Fresh scorer parameters (zero linear part, ``init_mlp`` trunk).
 
-    The trunk comes from the seed stack's ``models.mlp.init_mlp`` (one
-    stacked layer, SwiGLU); the output head ``w_out`` starts at ZERO so
+    The trunk comes from :func:`init_mlp` (one stacked layer,
+    SwiGLU); the output head ``w_out`` starts at ZERO so
     a fresh scorer is exactly its linear part — see :func:`warm_params`.
     All leaves are cast to numpy float64 (the serving dtype).
     """
     import jax
     import jax.numpy as jnp
-
-    from ..models.common import KeyGen, dense_init
-    from ..models.mlp import init_mlp
 
     F = n_features if n_features is not None else len(FEATURE_NAMES)
     kg = KeyGen(jax.random.PRNGKey(seed))
@@ -161,8 +199,6 @@ def forward_jnp(w: dict, mu, sd, x):
     differentiate through it; the trunk runs through ``mlp_forward``
     verbatim.  Matches the numpy path to f64 round-off under x64.
     """
-    from ..models.mlp import mlp_forward
-
     z = (x - mu) / sd
     h = z @ w["w_in"]
     t = {k: v[0] for k, v in w["trunk"].items()}

@@ -70,7 +70,7 @@ def _trainer(n_pad: int, n_feat: int, steps: int, batch: int, acfg):
     import jax
     import jax.numpy as jnp
 
-    from ..optim.adamw import adamw_init, adamw_update
+    from .adamw import adamw_init, adamw_update
     from .model import forward_jnp
 
     del n_pad, n_feat, steps, batch  # shape key only; shapes ride the args
@@ -113,7 +113,7 @@ def train_policy(trace, env: CacheEnvironment | None = None,
     """
     import jax
 
-    from ..optim.adamw import AdamWConfig
+    from .adamw import AdamWConfig
 
     cfg = cfg or TrainConfig()
     params = params or (env.params if env is not None else CostParams())

@@ -1,11 +1,8 @@
 from .expert_cache import ExpertCacheManager
 from .live import LiveServingEngine, ServeFuture
-from .server import BatchedServer, Request
 
 __all__ = [
     "ExpertCacheManager",
     "LiveServingEngine",
     "ServeFuture",
-    "BatchedServer",
-    "Request",
 ]

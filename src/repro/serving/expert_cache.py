@@ -15,7 +15,8 @@ Servers   = serving hosts; fetching an expert's weights from a peer host or
 Routing outcomes stream through a :class:`repro.core.session.CacheSession`
 (the AKPC policy from the registry): ``observe`` feeds them online, T_CG
 windowing/regeneration happens inside the session, and ``snapshot``/
-``restore`` checkpoint the live cache state together with the server.
+``restore`` checkpoint the live cache state together with the manager's
+clock and routing history.
 ``backend="live"`` swaps the session for a device-resident
 :class:`repro.serving.live.LiveServingEngine` — observations buffer into
 asynchronously dispatched device chunks and the cache state stays on the

@@ -1,12 +1,8 @@
 """Data pipeline determinism/resume + AKPC expert-cache integration."""
-import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.data import PackedDataPipeline, ShardStore
-from repro.serving import BatchedServer, ExpertCacheManager, Request
-from repro.configs import get_smoke_config
-from repro.models.api import build_model
+from repro.serving import ExpertCacheManager
 
 
 def test_pipeline_deterministic_and_resumable():
@@ -69,18 +65,6 @@ def test_packed_tables_layout():
     for e in range(8):
         ci, slot = where[e]
         np.testing.assert_array_equal(table[ci, slot], w[e])
-
-
-def test_batched_server_generates():
-    cfg = get_smoke_config("qwen2_5_3b")
-    model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
-    srv = BatchedServer(model, params, batch_size=2, cache_len=64)
-    for i in range(3):
-        srv.submit(Request(rid=i, prompt=[1 + i, 2, 3], max_new=4))
-    done = srv.run(max_steps=200)
-    assert len(done) == 3
-    assert all(len(r.out) == 4 or r.out[-1] == srv.eos for r in done)
 
 
 # ---------------------------------------------------------------------------

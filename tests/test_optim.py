@@ -1,18 +1,15 @@
-"""AdamW vs numpy reference; schedule; int8 error-feedback compression."""
+"""AdamW vs numpy reference; schedule."""
 import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.optim import (
+from repro.learned.adamw import (
     AdamWConfig,
     adamw_init,
     adamw_update,
-    compress_gradients,
     cosine_schedule,
-    decompress_gradients,
-    ef_init,
 )
 
 
@@ -93,19 +90,3 @@ def test_cosine_schedule_warmup_floor_semantics():
     for s in (10, 55, 100):
         assert float(cosine_schedule(cfg, jnp.array(s))) == float(
             cosine_schedule(base, jnp.array(s)))
-
-
-def test_error_feedback_compression_reduces_error():
-    rng = np.random.default_rng(0)
-    g_true = {"w": jnp.array(rng.normal(size=(64,)), jnp.float32)}
-    ef = ef_init(g_true)
-    acc_q = np.zeros(64)
-    acc_t = np.zeros(64)
-    for _ in range(50):
-        q, ef = compress_gradients(g_true, ef)
-        deq = decompress_gradients(q, g_true)
-        acc_q += np.asarray(deq["w"])
-        acc_t += np.asarray(g_true["w"])
-    # error feedback: accumulated quantised gradient tracks the true sum
-    rel = np.abs(acc_q - acc_t).max() / np.abs(acc_t).max()
-    assert rel < 0.01

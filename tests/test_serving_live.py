@@ -112,17 +112,6 @@ def test_live_matches_offline(trace, ref, ref_session, chunk_size):
     assert eng.in_flight == 0 and eng.pending == 0
 
 
-def test_live_ttl_policy_matches_offline(trace):
-    """Keep-or-not baseline through the live path: the device boundary
-    evictions and the numpy engine's keep mask must stay in sync."""
-    ref = run_policy(_policy("ttl"), trace)
-    eng = LiveServingEngine(_policy("ttl"), trace.n, trace.m,
-                            chunk_size=512)
-    _stream(eng, trace)
-    eng.drain()
-    assert_same_costs(ref.costs, eng.costs)
-
-
 # ---------------------------------------------------------------------------
 # compile accounting
 # ---------------------------------------------------------------------------

@@ -272,7 +272,7 @@ needs_4_devices = pytest.mark.skipif(
 
 @needs_4_devices
 def test_make_sweep_mesh_state_row_axis():
-    from repro.launch.mesh import make_sweep_mesh
+    from repro.core.state_layout import make_sweep_mesh
 
     mesh = make_sweep_mesh(state_rows=2)
     assert mesh.axis_names == ("scenario", "state_row")
@@ -285,7 +285,7 @@ def test_make_sweep_mesh_state_row_axis():
 def test_row_sharded_state_spans_devices():
     import jax
 
-    from repro.launch.mesh import make_sweep_mesh
+    from repro.core.state_layout import make_sweep_mesh
 
     mesh = make_sweep_mesh(n_devices=4, state_rows=4)
     lay = StateLayout(kind="row_sharded", mesh=mesh)
@@ -301,7 +301,7 @@ def test_row_sharded_state_spans_devices():
 def test_row_sharded_parity_on_mesh():
     """The acceptance gate: the row-sharded layout passes parity on a
     4-virtual-device CPU mesh (state rows spread over ``state_row``)."""
-    from repro.launch.mesh import make_sweep_mesh
+    from repro.core.state_layout import make_sweep_mesh
 
     mesh = make_sweep_mesh(n_devices=4, state_rows=4)
     lay = StateLayout(kind="row_sharded", mesh=mesh)
@@ -314,7 +314,7 @@ def test_row_sharded_parity_on_mesh():
 
 @needs_4_devices
 def test_sweep_engine_mesh_row_sharded():
-    from repro.launch.mesh import make_sweep_mesh
+    from repro.core.state_layout import make_sweep_mesh
 
     mesh = make_sweep_mesh(n_devices=4, state_rows=2)
     lay = StateLayout(kind="row_sharded", mesh=mesh)

@@ -596,7 +596,7 @@ def test_sweep_one_device_mesh_matches_no_mesh(trace, monkeypatch):
     lanes' state is still made on the device, and every point equals the
     engine without a mesh, counters and costs."""
     from repro.core import engine_jax as ej
-    from repro.launch.mesh import make_sweep_mesh
+    from repro.core.state_layout import make_sweep_mesh
 
     real, seen = ej.run_schedule, []
 
