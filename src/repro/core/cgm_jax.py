@@ -1520,7 +1520,7 @@ def replay_cgm(jeng, policy, trace, *, t_cg, batch_size=None, next_cg0=None,
     return eng.costs
 
 
-def wants_device_cgm(policy, trace, model) -> bool:
+def wants_device_cgm(policy, trace, model, wmax=None) -> bool:
     """Eligibility gate for the device-resident CGM path.
 
     ``REPRO_JAX_CGM`` = ``force`` / ``off`` / ``auto`` (default).  Auto
@@ -1536,6 +1536,10 @@ def wants_device_cgm(policy, trace, model) -> bool:
     a (2n, 2n) merge space instead of (2h, 2h), so auto admits it while
     n stays under ``MAX_DEVICE_CGM_HOT``: its 2n then stays within the
     bound the pruned space 2h already has.
+
+    ``wmax`` is ``_max_window_requests(trace, policy.t_cg)`` where the
+    caller holds it already (a sweep gates many points over one log);
+    ``None`` computes it here.
     """
     mode = os.environ.get("REPRO_JAX_CGM", "auto").strip().lower()
     if mode in ("off", "0"):
@@ -1559,7 +1563,8 @@ def wants_device_cgm(policy, trace, model) -> bool:
         return False
     if mode in ("force", "1"):
         return True
-    wmax = _max_window_requests(trace, t_cg)
+    if wmax is None:
+        wmax = _max_window_requests(trace, t_cg)
     if wmax + NE_TARGET >= _F32_EXACT:
         return False
     d_max = max(1, int(getattr(trace, "d_max", 1)))

@@ -304,8 +304,10 @@ class SweepEngine:
         from .cliques import CliquePartition
         from .cost import CostBreakdown
 
-        with obs.span("sweep.prepare", points=len(points)):
-            prepared, dev_groups, groups, sh_groups = self._prepare(points)
+        with obs.span("sweep.prepare", points=len(points)) as sp:
+            prepared, dev_groups, groups, sh_groups, gate = self._prepare(
+                points)
+            sp.set_metadata(**gate)
         stats = self.stats
         stats["cgm_host_points"] = sum(
             _walks_cgm(prepared[i]["policy"])
@@ -649,9 +651,11 @@ class SweepEngine:
         return results  # type: ignore[return-value]
 
     def _prepare(self, points):
-        """Per-point policy, environment and cost spec, and the groups the
-        points fall into: device-CGM super-groups, host-schedule groups
-        and trace-shard groups (index lists into the prepared points)."""
+        """Per-point policy, environment and cost spec, the groups the
+        points fall into (device-CGM super-groups, host-schedule groups
+        and trace-shard groups: index lists into the prepared points), and
+        the device-CGM gate's counts: ``gated``, the points that reach it
+        with a window bound, and ``window_bounds``, the bounds computed."""
         from . import cgm_jax
         from . import engine_jax as ej
 
@@ -700,7 +704,11 @@ class SweepEngine:
         # and vmap the clique generation itself — zero host CGM calls.
         # A group needs >= 2 distinct CGM keys to beat the host path
         # (with one key the host builds one shared schedule anyway).
+        # The gate's window bound depends on the log's times and t_cg
+        # alone, so it is computed once per (log content, t_cg) a call.
         dev_groups: dict = {}
+        bounds: dict = {}
+        gated = 0
         for i, pr in enumerate(prepared):
             pt, policy = pr["pt"], pr["policy"]
             cfg = getattr(policy, "config", None)
@@ -710,9 +718,18 @@ class SweepEngine:
                     # the session layout — only row-sharded state (which
                     # splits the slot maps across devices) falls back
                     or not self.layout.supports_device_cgm(
-                        pt.trace.n, pt.trace.m)
-                    or not cgm_jax.wants_device_cgm(
-                        policy, pt.trace, pr["model"])):
+                        pt.trace.n, pt.trace.m)):
+                continue
+            wmax = None
+            if policy.t_cg is not None:
+                gated += 1
+                bkey = (pr["tid"], policy.t_cg)
+                wmax = bounds.get(bkey)
+                if wmax is None:
+                    wmax = bounds[bkey] = cgm_jax._max_window_requests(
+                        pt.trace, policy.t_cg)
+            if not cgm_jax.wants_device_cgm(
+                    policy, pt.trace, pr["model"], wmax=wmax):
                 continue
             dkey = (pr["tid"], cfg.t_cg, pr["bs"], pr["statics"],
                     pr["charge"], pr["model"].uses_sizes, pr["sizes_fp"],
@@ -732,7 +749,8 @@ class SweepEngine:
             dst = sh_groups if pr["shards"] is not None else groups
             dst.setdefault((pr["skey"], pr["statics"], pr["charge"]),
                            []).append(i)
-        return prepared, dev_groups, groups, sh_groups
+        gate = {"gated": gated, "window_bounds": len(bounds)}
+        return prepared, dev_groups, groups, sh_groups, gate
 
     # ------------------------------------------------------------------
     def _stage(self, spec, rows, cols, lanes):

@@ -259,6 +259,44 @@ def test_wants_device_cgm_gating(trace, monkeypatch):
     assert cgm_jax.wants_device_cgm(soft, trace, model)
 
 
+@pytest.mark.parametrize("case, admitted", [
+    ("akpc", True), ("crm_hook", False), ("big", True), ("big_hot", False),
+    ("soft", True), ("soft_big", True), ("soft_big_hot", False)])
+def test_wants_device_cgm_passed_wmax_is_own_answer(trace, monkeypatch,
+                                                    case, admitted):
+    """A caller that passes the window bound gets the gate's own answer,
+    and the gate computes no bound of its own."""
+    from repro.core.cost import get_cost_model
+
+    kw = dict(crm_matmul=lambda H: H.T @ H) if case == "crm_hook" else {}
+    gamma = 0.4 if case.startswith("soft") else 0.85
+    pol = get_policy("akpc", **_kw(0.2, gamma, 4, **kw))
+    tr = trace
+    if "big" in case:
+        tr = synth_trace(SynthConfig(
+            kind="netflix", n_items=4 * 256 + 8, n_servers=4,
+            n_requests=40, t_max=2.0, seed=0))
+    pol.bind(tr.n, tr.m)
+    model = get_cost_model(
+        "table1", CacheEnvironment.resolve(None, tr, pol.params))
+    if case == "big_hot":
+        monkeypatch.setattr(cgm_jax, "MAX_DEVICE_CGM_HOT", 8)
+    if case == "soft_big_hot":
+        monkeypatch.setattr(cgm_jax, "MAX_DEVICE_CGM_HOT", tr.n - 1)
+    own = cgm_jax.wants_device_cgm(pol, tr, model)
+    assert own is admitted
+    wmax = cgm_jax._max_window_requests(tr, pol.t_cg)
+
+    def refuse(*a):
+        raise AssertionError("the gate recomputed a passed bound")
+
+    monkeypatch.setattr(cgm_jax, "_max_window_requests", refuse)
+    assert cgm_jax.wants_device_cgm(pol, tr, model, wmax=wmax) is own
+    # the passed bound is the one the gate checks
+    assert not cgm_jax.wants_device_cgm(
+        pol, tr, model, wmax=cgm_jax._F32_EXACT)
+
+
 def test_merge_density_kernel_matches_jnp_interpret():
     import jax
     import jax.numpy as jnp

@@ -187,7 +187,7 @@ def test_sweep_spans(tmp_path, how, n_sched):
                        "shared": 2 - n_sched, "lanes": 2, "groups": 1,
                        "cgm_lanes": 0, "cgm_host": 2}
     (prep,) = sp["sweep.prepare"]
-    assert prep[2] == {"points": 2}
+    assert prep[2] == {"points": 2, "gated": 2, "window_bounds": n_sched}
     scheds = sp["sweep.schedule"]
     assert len(scheds) == n_sched
     assert all(s[2]["steps"] > 0 and s[2]["events"] > 0 for s in scheds)

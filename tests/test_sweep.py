@@ -329,6 +329,40 @@ def test_sweep_digests_each_array_set_once(trace, monkeypatch):
     assert len(calls) == 4                  # the memo lasts one call
 
 
+def test_sweep_bounds_each_log_window_once(trace, monkeypatch):
+    """The device-CGM gate's window bound is computed once per distinct
+    (log content, t_cg) of a call, however many points and ``Trace``
+    objects share it, and every lane still matches its serial replay."""
+    from repro.core import cgm_jax
+
+    calls = []
+    real = cgm_jax._max_window_requests
+
+    def counting(tr, t_cg):
+        calls.append(t_cg)
+        return real(tr, t_cg)
+
+    monkeypatch.setattr(cgm_jax, "_max_window_requests", counting)
+    logs = (trace, _copy_trace(trace), _one_change(trace, "time"))
+    pts = [
+        SweepPoint("akpc", tr, dict(params=CostParams(alpha=a, theta=th),
+                                    t_cg=t_cg, top_frac=TOP_FRAC))
+        for tr in logs for t_cg in (T_CG, 1.1)
+        for a in (0.6, 1.0) for th in (0.1, 0.3)
+    ]
+    eng = SweepEngine()
+    res = eng.run(pts)
+    assert len(calls) == 4          # two contents x two t_cg, 24 points
+    assert eng.stats["cgm_lanes"] == len(pts)
+    assert eng._prepare(pts)[4] == {"gated": len(pts), "window_bounds": 4}
+    calls.clear()
+    eng.run(pts)
+    assert len(calls) == 4                  # the memo lasts one call
+    for pt, got in zip(pts, res):
+        ref = run_policy(get_policy(pt.policy, **pt.policy_kwargs), pt.trace)
+        assert_same_run(ref, got)
+
+
 def test_sweep_numpy_backend_and_convenience(trace):
     grid = [dict(policy="no_packing", trace=trace,
                  policy_kwargs={"params": PARAMS})]
